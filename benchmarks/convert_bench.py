@@ -25,10 +25,9 @@ Byte-identity is asserted across all three: every study tar (UIDs seeded
 per slide) must be identical bit-for-bit, so the speedups cannot come from
 computing something different.
 
-On this CPU container the numbers are ref/interpret-mode numbers (the
-Pallas kernels lower natively only with ``REPRO_PALLAS_COMPILE=1``); the
-batched transform dispatches to the jnp oracle, the per-tile baseline runs
-the seed path unchanged.
+On the CPU the kernels' ``auto`` implementation is the jnp oracle (on a
+TPU it is the native Pallas kernel), so CPU numbers time the oracle: the
+batched transform and the per-tile baseline both run jnp graphs.
 
 Writes ``BENCH_convert.json`` into the working directory and prints a CSV
 summary (same format as the other benchmark modules). ``--fast`` shrinks
@@ -45,6 +44,7 @@ import numpy as np
 
 from repro.core import ConversionPipeline, RealScheduler
 from repro.kernels import jpeg_transform
+from repro.launch.cache import enable_compile_cache
 from repro.wsi.convert import (TRANSFER_STATS, ConvertOptions,
                                convert_wsi_to_dicom)
 from repro.wsi.dicom import TS_JPEG_BASELINE, new_uid, write_part10
@@ -312,6 +312,7 @@ def main(argv: list[str] | None = None) -> None:
                     help="CI smoke: smaller slides, fewer reps, same "
                          "byte-identity assertions")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     slide = 512 if args.fast else SLIDE
     reps = 1 if args.fast else 3
     n_slides = 3 if args.fast else 4

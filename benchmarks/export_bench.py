@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import SimScheduler
 from repro.core.storage import ObjectStore
 from repro.kernels import jpeg_transform
+from repro.launch.cache import enable_compile_cache
 from repro.wsi.convert import ConvertOptions, convert_wsi_to_dicom
 from repro.wsi.export import ExportService
 from repro.wsi.formats import open_slide
@@ -197,6 +198,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: smaller level/slide, same assertions")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     decode_hw = 2048 if args.fast else 4096
     scaling_ns = [16, 64] if args.fast else [16, 64, 256]
     slide_hw = 512 if args.fast else 1024

@@ -45,6 +45,7 @@ from benchmarks import fig3_autoscaling as fig3
 from repro.analysis import lockdep, racedep
 from repro.core import (ConversionPipeline, DeliveryFaults, SimScheduler,
                         dashboard, tracing)
+from repro.launch.cache import enable_compile_cache
 
 TAU = 90.0          # paper: ~90 s per gigapixel conversion on a 16-vCPU VM
 COLD = 12.0         # paper: Cloud Run cold start
@@ -517,6 +518,7 @@ def main(argv: list[str] | None = None) -> None:
                     help="CI smoke: smaller gauntlet, no wall-clock "
                          "calibration, same assertions")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     result = {
         "config": {"tau_s": TAU, "cold_start_s": COLD,

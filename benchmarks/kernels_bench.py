@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -50,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import jpeg_inverse, jpeg_transform, ref
+from repro.launch.cache import enable_compile_cache
 from repro.roofline import derive_terms
 from repro.roofline.terms import HW
 from repro.wsi.jpeg import encode_tile
@@ -128,8 +130,11 @@ def _roofline_section(device_counts: list[int]) -> dict:
     out: dict[str, dict[str, dict]] = {k: {} for k in KERNELS}
     for d in device_counts:
         prog = _roofline_prog(d, ROOFLINE_N, TILE)
+        # the child analyses forced host devices: it must never reach for
+        # the chip this process holds
         res = subprocess.run([sys.executable, "-c", prog],
-                             capture_output=True, text=True, timeout=600)
+                             capture_output=True, text=True, timeout=600,
+                             env={**os.environ, "JAX_PLATFORMS": "cpu"})
         line = next((ln for ln in res.stdout.splitlines()
                      if ln.startswith("ROOFLINE-JSON ")), None)
         assert line is not None, \
@@ -245,24 +250,6 @@ def _micro_rows(reps: int) -> list[tuple[str, float, str]]:
          "256x256"),
     ]
 
-    # fused rwkv6 wkv chunk kernel vs unfused chunked XLA path
-    from repro.kernels.wkv_chunk import wkv_chunk_pallas
-    from repro.models.rwkv6 import wkv_chunked
-    B, S, H, K = 1, 256, 2, 64
-    rr, kk, vv = (jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32)
-                  for _ in range(3))
-    lw = -jnp.asarray(rng.uniform(0.01, 2.0, (B, S, H, K)), jnp.float32)
-    uu = jnp.asarray(rng.normal(size=(H, K)), jnp.float32)
-    st0 = jnp.zeros((B, H, K, K), jnp.float32)
-    rows.append(("wkv_chunked_xla",
-                 _time(jax.jit(lambda *a: wkv_chunked(*a)[0]),
-                       rr, kk, vv, lw, uu, st0, reps=reps) * 1e6,
-                 f"B{B} S{S} H{H}"))
-    rows.append(("wkv_chunk_pallas_interp",
-                 _time(lambda *a: wkv_chunk_pallas(*a), rr, kk, vv, lw, uu,
-                       reps=1) * 1e6,
-                 "interpret-mode"))
-
     # end-to-end tile encode, slide opened through the format sniffer
     psv = SyntheticScanner(seed=0).scan(TILE, TILE, TILE)
     t = open_slide(psv).read_tile(0, 0)
@@ -283,6 +270,7 @@ def main(argv: list[str] | None = None) -> None:
                     help="CI smoke: fewer device counts / batch sizes, "
                          "same monotonicity gate")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     device_counts = [1, 4] if args.fast else [1, 4, 8]
     scaling_ns = [16, 64] if args.fast else [16, 64, 256]
     reps = 2 if args.fast else 3

@@ -31,6 +31,7 @@ import time
 
 from repro.core import SimScheduler
 from repro.core.storage import ObjectStore
+from repro.launch.cache import enable_compile_cache
 from repro.wsi.convert import ConvertOptions, convert_wsi_to_dicom
 from repro.wsi.dicom import Part10Index, read_part10, write_part10
 from repro.wsi.jpeg import encode_tile
@@ -180,6 +181,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: fewer fetches/studies, same assertions")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     fetches = 64 if args.fast else N_FRAMES
     reps = 1 if args.fast else 3
     n_studies = 3 if args.fast else 8

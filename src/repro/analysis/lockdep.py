@@ -66,25 +66,29 @@ def _site(skip: int = 2) -> str:
     return f"{f.filename}:{f.lineno} in {f.name}"
 
 
-_TRACE_CLEAN = None  # jax.core.trace_state_clean, resolved once jax exists
+_TOP_LEVEL = None  # jax.core.trace_ctx.is_top_level, resolved once jax exists
 
 
 def _in_jit_trace() -> bool:
-    """True while jax is tracing (jit/pmap/scan…). Never imports jax."""
-    global _TRACE_CLEAN
-    fn = _TRACE_CLEAN
+    """True while jax is tracing (jit/pmap/scan…). Never imports jax.
+
+    Raises ``RuntimeError`` when the imported jax offers no way to ask —
+    a probe that quietly answered False would switch the check off.
+    """
+    global _TOP_LEVEL
+    fn = _TOP_LEVEL
     if fn is None:
         jax = sys.modules.get("jax")
-        if jax is None:
-            return False
+        if jax is None or getattr(jax.__spec__, "_initializing", False):
+            return False  # not imported yet (maybe mid-import elsewhere)
         try:
-            fn = _TRACE_CLEAN = jax.core.trace_state_clean
+            fn = _TOP_LEVEL = jax.core.trace_ctx.is_top_level
         except AttributeError:
-            return False
-    try:
-        return not fn()
-    except Exception:
-        return False
+            raise RuntimeError(
+                f"lockdep: jax {jax.__version__} has no "
+                "jax.core.trace_ctx.is_top_level — the acquired-in-jit "
+                "check cannot probe trace state") from None
+    return not fn()
 
 
 @dataclasses.dataclass

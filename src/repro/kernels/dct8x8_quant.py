@@ -1,65 +1,53 @@
 """Fused 8×8 blockwise DCT-II + quantization as a Pallas TPU kernel.
 
-The JPEG transform stage, re-blocked for the MXU: the separable 2-D DCT is
-two 8×8 constant-matrix contractions. Each grid step loads an (8, 128) VMEM
-block (= 16 DCT blocks side by side), reshapes to (16, 8, 8), and runs
+The JPEG transform stage, re-blocked for the MXU: each grid step loads an
+(8, 128) VMEM block (= 16 DCT blocks side by side) and runs the separable
+2-D DCT of every block as two plain matmuls on the strip,
 
-    Y = C · X · Cᵀ   →   einsum over the batched 16-block axis (MXU dots)
+    Y = C · X · (I₁₆ ⊗ Cᵀ)      (``ref.strip_transform``)
 
-then fuses the divide-by-Q rounding. The quant table rides along as a second
-(8, 128)-tiled operand (Q repeated 16×) so everything stays in VMEM.
+then fuses the divide-by-Q rounding. The quant table (Q repeated 16×) and
+both DCT matrices ride along as operands mapped to block (0, 0), so they
+stay resident in VMEM across the grid.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels.ref import (STRIP, qtable_strip, strip_dct_matrices,
+                               strip_transform)
 
 __all__ = ["dct8x8_quant_pallas"]
 
-_BH, _BW = 8, 128
-_NB = _BW // 8  # DCT blocks per VMEM block
+_BH, _BW = 8, STRIP
 
 
-def _dct_mat():
-    """Orthonormal 8×8 DCT-II matrix, built in-kernel (iota → cos) so the
-    kernel captures no host constants."""
-    m = jax.lax.broadcasted_iota(jnp.float32, (8, 8), 0)  # row index k
-    n = jax.lax.broadcasted_iota(jnp.float32, (8, 8), 1)  # col index
-    C = jnp.cos((2.0 * n + 1.0) * m * (jnp.pi / 16.0)) * jnp.sqrt(2.0 / 8.0)
-    scale = jnp.where(m == 0, 1.0 / jnp.sqrt(2.0), 1.0)
-    return C * scale
+def _kernel(x_ref, q_ref, l_ref, r_ref, o_ref):
+    y = strip_transform(x_ref[...], l_ref[...], r_ref[...])
+    o_ref[...] = jnp.round(y / q_ref[...]).astype(jnp.int32)
 
 
-def _kernel(x_ref, q_ref, o_ref):
-    C = _dct_mat()
-    x = x_ref[...].astype(jnp.float32)  # (8, 128)
-    xb = x.reshape(8, _NB, 8).transpose(1, 0, 2)  # (16, 8, 8)
-    y = jnp.einsum("ij,bjk,lk->bil", C, xb, C,
-                   preferred_element_type=jnp.float32)
-    q = q_ref[...].reshape(8, _NB, 8).transpose(1, 0, 2)
-    out = jnp.round(y / q)
-    o_ref[...] = out.transpose(1, 0, 2).reshape(8, _BW).astype(jnp.int32)
-
-
-def dct8x8_quant_pallas(plane, qtable, *, interpret: bool = True):
+def dct8x8_quant_pallas(plane, qtable, *, interpret: bool):
     """plane: (H, W) float32 level-shifted; qtable: (8, 8).
 
     H % 8 == 0, W % 128 == 0. Returns (H, W) int32 quantized coefficients.
     """
     H, W = plane.shape
     assert H % _BH == 0 and W % _BW == 0, plane.shape
-    qwide = jnp.tile(jnp.asarray(qtable, jnp.float32), (1, _NB))
-    grid = (H // _BH, W // _BW)
+    left, right = strip_dct_matrices()
+    const = lambda i, j: (0, 0)  # noqa: E731
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(H // _BH, W // _BW),
         in_specs=[
             pl.BlockSpec((_BH, _BW), lambda i, j: (i, j)),
-            pl.BlockSpec((_BH, _BW), lambda i, j: (0, 0)),
+            pl.BlockSpec((_BH, _BW), const),
+            pl.BlockSpec((8, 8), const),
+            pl.BlockSpec((_BW, _BW), const),
         ],
         out_specs=pl.BlockSpec((_BH, _BW), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((H, W), jnp.int32),
         interpret=interpret,
-    )(plane, qwide)
+    )(plane.astype(jnp.float32), qtable_strip(qtable), left, right)

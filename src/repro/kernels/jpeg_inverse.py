@@ -14,17 +14,15 @@ Grid: ``(N, T/8, T/128)``. Each step loads one (1, 3, 8, 128) VMEM block —
 an 8×128 strip of all three coefficient channels of one tile (16 DCT
 blocks side by side) — multiplies by the per-channel quantization tables
 (riding along as a single resident (3, 8, 128) operand, exactly as in the
-forward kernel), runs the batched 8×8 inverse DCT contractions on the MXU,
-then applies the YCbCr→RGB polynomials + level unshift on the VPU and
-rounds/clips to [0, 255].
+forward kernel), runs each channel's block iDCT as two strip matmuls on
+the MXU (``Cᵀ · X · (I₁₆ ⊗ C)``), then applies the YCbCr→RGB polynomials
++ level unshift on the VPU and rounds/clips to [0, 255].
 
-Bit-exactness contract: the inverse contraction lives in
-``ref.idct_dequant_blocks`` and the color polynomials in
-``ref.ycbcr_inverse_polynomials`` — a single copy each, shared between
-this kernel body and the jnp oracle (the contraction is two chained
-fixed-order dots precisely so the association order cannot drift between
-operand shapes), so the fused path produces the same RGB samples and the
-batched and per-tile JPEG decode paths emit pixel-identical tiles.
+Bit-exactness contract: the inverse contraction is ``ref.strip_transform``
+and the color polynomials ``ref.ycbcr_inverse_polynomials`` — a single
+copy each, shared between this kernel body and the jnp oracle, so the
+fused path produces the same RGB samples and the batched and per-tile JPEG
+decode paths emit pixel-identical tiles.
 
 The output is int32, not uint8: 8-bit outputs would need (32, 128)-tiled
 blocks on real hardware, and the public wrapper (``ops.jpeg_inverse``)
@@ -36,29 +34,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import (dct_matrix, idct_dequant_blocks,
-                               ycbcr_inverse_polynomials)
+from repro.kernels.ref import (STRIP, qtable_strip, strip_dct_matrices,
+                               strip_transform, ycbcr_inverse_polynomials)
 
 __all__ = ["jpeg_inverse_pallas"]
 
-_BH, _BW = 8, 128
-_NB = _BW // 8  # DCT blocks per VMEM strip
+_BH, _BW = 8, STRIP
 
 
-def _kernel(c_ref, q_ref, dctm_ref, o_ref):
-    C = dctm_ref[:, :8]  # the host-built DCT matrix (see ref docstring)
-    planes = []
-    for ci in range(3):
-        xb = c_ref[0, ci].reshape(8, _NB, 8).transpose(1, 0, 2)  # (16, 8, 8)
-        y = idct_dequant_blocks(
-            xb, q_ref[ci].reshape(8, _NB, 8).transpose(1, 0, 2), C)
-        planes.append(y.transpose(1, 0, 2).reshape(8, _BW))
-    r, g, b = ycbcr_inverse_polynomials(*planes)
-    for ci, chan in enumerate((r, g, b)):
+def _kernel(c_ref, q_ref, l_ref, r_ref, o_ref):
+    left, right = l_ref[...], r_ref[...]
+    planes = [strip_transform(c_ref[0, ci].astype(jnp.float32) * q_ref[ci],
+                              left, right)
+              for ci in range(3)]
+    for ci, chan in enumerate(ycbcr_inverse_polynomials(*planes)):
         o_ref[0, ci] = jnp.clip(jnp.round(chan), 0, 255).astype(jnp.int32)
 
 
-def jpeg_inverse_pallas(coef, qluma, qchroma, *, interpret: bool = True):
+def jpeg_inverse_pallas(coef, qluma, qchroma, *, interpret: bool):
     """coef: (N, 3, H, W) int32 quantized coefficients; q*: (8, 8) tables.
 
     H % 8 == 0, W % 128 == 0. Returns (N, 3, H, W) int32 RGB samples in
@@ -67,24 +60,18 @@ def jpeg_inverse_pallas(coef, qluma, qchroma, *, interpret: bool = True):
     """
     N, C, H, W = coef.shape
     assert C == 3 and H % _BH == 0 and W % _BW == 0, coef.shape
-    qwide = jnp.stack([
-        jnp.tile(jnp.asarray(q, jnp.float32), (1, _NB))
-        for q in (qluma, qchroma, qchroma)
-    ])  # (3, 8, 128): per-channel tables, resident across the grid
-    # the DCT matrix rides along (8, 128)-tiled, sliced back to (8, 8) in
-    # the kernel: the oracle uses the numpy-built matrix, and rebuilding it
-    # in-kernel (iota→cos) drifts the last ULP — see idct_dequant_blocks
-    dctm = jnp.tile(jnp.asarray(dct_matrix()), (1, _NB))
-    grid = (N, H // _BH, W // _BW)
+    qwide = jnp.stack([qtable_strip(q) for q in (qluma, qchroma, qchroma)])
+    left, right = strip_dct_matrices(inverse=True)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(N, H // _BH, W // _BW),
         in_specs=[
             pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
             pl.BlockSpec((3, _BH, _BW), lambda n, i, j: (0, 0, 0)),
-            pl.BlockSpec((_BH, _BW), lambda n, i, j: (0, 0)),
+            pl.BlockSpec((8, 8), lambda n, i, j: (0, 0)),
+            pl.BlockSpec((_BW, _BW), lambda n, i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((N, 3, H, W), jnp.int32),
         interpret=interpret,
-    )(coef, qwide, dctm)
+    )(coef.astype(jnp.int32), qwide, left, right)

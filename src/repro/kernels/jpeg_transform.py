@@ -8,19 +8,18 @@ in a single dispatch, versus the 4 per-tile dispatches of the unfused path
 dispatch reduction (see DESIGN.md, "Whole-level batched dispatch").
 
 Grid: ``(N, T/8, T/128)``. Each step loads one (1, 3, 8, 128) VMEM block —
-an 8×128 strip of all three channels of one tile (8×128 = one VREG tile per
-channel, 16 DCT blocks side by side) — converts to level-shifted YCbCr on
-the VPU, then runs the per-channel batched 8×8 DCT contractions on the MXU
-and fuses the divide-by-Q rounding. Both quantization tables ride along as a
-single (3, 8, 128) operand (luma, chroma, chroma — each Q tiled 16× along
-the lane dim) mapped to block (0, 0, 0) so they stay resident in VMEM across
-the whole grid.
+an 8×128 strip of all three channels of one tile (16 DCT blocks side by
+side) — converts to level-shifted YCbCr on the VPU, runs each channel's
+block DCT as two strip matmuls on the MXU (``ref.strip_transform``) and
+fuses the divide-by-Q rounding. The quantization tables ride along as one
+(3, 8, 128) operand (luma, chroma, chroma) and the DCT matrices as two
+more, all mapped to block 0 so they stay resident in VMEM.
 
 Bit-exactness contract: the per-channel math is expression-identical to the
-unfused ``rgb2ycbcr`` / ``dct8x8_quant`` kernels (same (16, 8, 8) einsum
-shape, shared ``ref.ycbcr_polynomials``), so the fused path produces the
-same int32 coefficients — the batched and per-tile JPEG byte streams match
-exactly.
+unfused ``rgb2ycbcr`` / ``dct8x8_quant`` kernels (shared
+``ref.ycbcr_polynomials`` and ``ref.strip_transform`` on the same (8, 128)
+strips), so the fused path produces the same int32 coefficients — the
+batched and per-tile JPEG byte streams match exactly.
 """
 from __future__ import annotations
 
@@ -28,29 +27,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.dct8x8_quant import _dct_mat
-from repro.kernels.ref import ycbcr_polynomials
+from repro.kernels.ref import (STRIP, qtable_strip, strip_dct_matrices,
+                               strip_transform, ycbcr_polynomials)
 
 __all__ = ["jpeg_transform_pallas"]
 
-_BH, _BW = 8, 128
-_NB = _BW // 8  # DCT blocks per VMEM strip
+_BH, _BW = 8, STRIP
 
 
-def _kernel(x_ref, q_ref, o_ref):
-    x = x_ref[0].astype(jnp.float32)  # (3, 8, 128)
-    y, cb, cr = ycbcr_polynomials(x[0], x[1], x[2])
-    C = _dct_mat()
-    for ci, plane in enumerate((y, cb, cr)):
-        xb = plane.reshape(8, _NB, 8).transpose(1, 0, 2)  # (16, 8, 8)
-        yc = jnp.einsum("ij,bjk,lk->bil", C, xb, C,
-                        preferred_element_type=jnp.float32)
-        q = q_ref[ci].reshape(8, _NB, 8).transpose(1, 0, 2)
-        out = jnp.round(yc / q)
-        o_ref[0, ci] = out.transpose(1, 0, 2).reshape(8, _BW).astype(jnp.int32)
+def _kernel(x_ref, q_ref, l_ref, r_ref, o_ref):
+    left, right = l_ref[...], r_ref[...]
+    planes = ycbcr_polynomials(x_ref[0, 0], x_ref[0, 1], x_ref[0, 2])
+    for ci, plane in enumerate(planes):
+        y = strip_transform(plane, left, right)
+        o_ref[0, ci] = jnp.round(y / q_ref[ci]).astype(jnp.int32)
 
 
-def jpeg_transform_pallas(tiles, qluma, qchroma, *, interpret: bool = True):
+def jpeg_transform_pallas(tiles, qluma, qchroma, *, interpret: bool):
     """tiles: (N, 3, H, W) uint8/float RGB; q*: (8, 8) tables.
 
     H % 8 == 0, W % 128 == 0. Returns (N, 3, H, W) int32 quantized YCbCr
@@ -58,19 +51,18 @@ def jpeg_transform_pallas(tiles, qluma, qchroma, *, interpret: bool = True):
     """
     N, C, H, W = tiles.shape
     assert C == 3 and H % _BH == 0 and W % _BW == 0, tiles.shape
-    qwide = jnp.stack([
-        jnp.tile(jnp.asarray(q, jnp.float32), (1, _NB))
-        for q in (qluma, qchroma, qchroma)
-    ])  # (3, 8, 128): per-channel tables, resident across the grid
-    grid = (N, H // _BH, W // _BW)
+    qwide = jnp.stack([qtable_strip(q) for q in (qluma, qchroma, qchroma)])
+    left, right = strip_dct_matrices()
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(N, H // _BH, W // _BW),
         in_specs=[
             pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
             pl.BlockSpec((3, _BH, _BW), lambda n, i, j: (0, 0, 0)),
+            pl.BlockSpec((8, 8), lambda n, i, j: (0, 0)),
+            pl.BlockSpec((_BW, _BW), lambda n, i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((N, 3, H, W), jnp.int32),
         interpret=interpret,
-    )(tiles, qwide)
+    )(tiles.astype(jnp.float32), qwide, left, right)

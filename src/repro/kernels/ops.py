@@ -1,26 +1,27 @@
 """Public wrappers over the Pallas kernels: impl dispatch, batch sharding,
 size-bucketed jit.
 
-``impl`` selects the backend per call:
+``impl`` selects the implementation per call; the platform
+(``jax.default_backend()``) decides what it means:
 
-- ``"auto"`` (default) — the fastest *correct* implementation for this
-  environment: on a real TPU (``REPRO_PALLAS_COMPILE=1``) the Pallas kernel
-  lowered natively; otherwise the pure-jnp oracle. Non-lane-aligned shapes
-  run the kernel through an explicit pad-to-aligned + slice path on TPU and
-  fall back to the oracle on CPU.
+- ``"auto"`` (default) — on a TPU, the Pallas kernel compiled natively;
+  on the CPU, the pure-jnp oracle. Shapes the kernel's blocks do not tile
+  run the kernel through an explicit pad-to-aligned + slice path, so on a
+  TPU no ``auto`` call ends in the oracle.
 - ``"ref"`` — the pure-jnp oracle, unconditionally.
-- ``"pallas"`` — the Pallas kernel, unconditionally; in this environment
-  that means ``interpret=True`` (the kernel body executes in Python,
-  validating the BlockSpec tiling). Unaligned shapes take the padded path
-  (pad + kernel + slice), used by the ragged-shape differential tests.
+- ``"pallas"`` — the Pallas kernel: native on a TPU, ``interpret=True``
+  on the CPU (the kernel body executes op by op, validating the BlockSpec
+  tiling). Unaligned shapes take the padded path, as under ``auto``.
 
-Interpret mode is a correctness harness, not an execution path — it is
-orders of magnitude slower than the oracle and must never be what ``auto``
-picks. Keeping every ``auto`` caller on one backend per environment also
-preserves the byte-identity contract between the batched and per-tile JPEG
-paths (DESIGN.md, "Bit-exactness contract"): expression-identical float
-math compiled through *different* machinery (plain XLA vs the interpreter)
-can differ in the last ULP and flip a round-at-half quantization.
+Interpret mode is a correctness harness for the CPU, never an execution
+path on the chip, and never what ``auto`` picks: it is orders of
+magnitude slower than the oracle. Keeping every ``auto`` caller on one
+implementation per platform also preserves the byte-identity contract
+between the batched and per-tile JPEG paths (DESIGN.md, "Bit-exactness
+contract"): expression-identical float math compiled through *different*
+machinery (plain XLA vs the interpreter) can differ in the last ULP and
+flip a round-at-half quantization. The choice is resolved before the jit
+cache, so it keys the compiled executables.
 
 **Mesh sharding + bucketing** (DESIGN.md, "Kernel roofline & sharding"):
 the whole-level batched kernels ``jpeg_transform``/``jpeg_inverse`` carry
@@ -31,16 +32,16 @@ of bucketed executables instead of one per level geometry — the
 small-batch recompile fix), lay the batch out over the ambient mesh's
 ``data`` axis with ``jax.sharding.NamedSharding``, and slice the result
 back; calls from inside an enclosing trace (the fused pyramid chain in
-``wsi/convert.py``) keep their static shapes and get a
-``with_sharding_constraint`` instead. Pad tiles are all-zero and sliced
-away, and the per-tile math is batch-size independent (asserted by tests),
-so sharded, bucketed and single-device dispatches all produce bit-identical
-tiles. The ambient mesh defaults to ``make_local_mesh()`` over every
-visible device; ``use_mesh`` scopes an explicit one.
+``wsi/convert.py``) keep their static shapes. Either way the kernel runs
+under ``shard_map`` over the mesh: a batch that splits evenly gives each
+device its own slice of tiles, one that does not runs replicated. Pad tiles are all-zero and sliced away, and the
+per-tile math is batch-size independent (asserted by tests), so sharded,
+bucketed and single-device dispatches all produce bit-identical tiles.
+The ambient mesh defaults to ``make_local_mesh()`` over every visible
+device; ``use_mesh`` scopes an explicit one.
 """
 from __future__ import annotations
 
-import os
 import threading
 from contextlib import contextmanager
 from functools import partial
@@ -59,10 +60,6 @@ from repro.kernels.rgb2ycbcr import rgb2ycbcr_pallas
 __all__ = ["rgb2ycbcr", "downsample2x2", "dct8x8_quant", "idct8x8_dequant",
            "jpeg_transform", "jpeg_inverse", "default_mesh", "use_mesh",
            "data_sharding"]
-
-
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 def _aligned(n: int, m: int) -> bool:
@@ -120,49 +117,60 @@ def _bucket(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def _batched_call(x, core, *args):
+def _batched_call(x, core):
     """Shared batch policy for the (N, 3, T, T) kernels.
 
-    Traced operands (the fused pyramid chain) keep their static shape and
-    get a sharding constraint; concrete operands are bucket-padded to the
-    next power of two, laid out over the mesh's data axis, dispatched, and
-    sliced back. Pad tiles are zeros; per-tile math is batch-independent
-    (tested), so the sliced result is bit-identical to the unpadded call.
+    ``core(x, mesh)`` runs the kernel over the ambient mesh. Traced
+    operands (the fused pyramid chain) keep their static shape; concrete
+    operands are bucket-padded to the next power of two, laid out over the
+    mesh's data axis, dispatched, and sliced back. Pad tiles are zeros;
+    per-tile math is batch-independent (tested), so the sliced result is
+    bit-identical to the unpadded call.
     """
+    mesh = default_mesh()
     if isinstance(x, jax.core.Tracer):
-        n = x.shape[0]
-        sh = data_sharding(n)
-        if sh.spec:  # only constrain when actually split over devices
-            x = jax.lax.with_sharding_constraint(x, sh)
-        return core(x, *args)
+        return core(x, mesh)
     x = jnp.asarray(x)
     n = x.shape[0]
     if n == 0:
-        return core(x, *args)
+        return core(x, mesh)
     nb = _bucket(n)
     if nb != n:
         x = jnp.concatenate(
             [x, jnp.zeros((nb - n,) + x.shape[1:], x.dtype)])
-    x = jax.device_put(x, data_sharding(nb))
-    out = core(x, *args)
+    x = jax.device_put(x, data_sharding(nb, mesh))
+    out = core(x, mesh)
     return out[:n] if nb != n else out
 
 
-def _dispatch(impl: str, aligned: bool, pallas_fn, ref_fn, padded_fn=None):
-    """The shared impl policy (see module docstring)."""
+def _on_mesh(run, mesh, spec, x, *consts):
+    """``run(x, *consts)`` under ``shard_map``: ``x`` laid out by ``spec``
+    over ``mesh``, so each device runs the kernel on its own slice; the
+    constants are replicated. A Mosaic kernel in a program that spans
+    several devices compiles only inside ``shard_map``."""
+    return jax.shard_map(
+        run, mesh=mesh, in_specs=(spec,) + (P(),) * len(consts),
+        out_specs=spec, check_vma=False)(x, *consts)
+
+
+def _mode(impl: str) -> str:
+    """Resolve the public ``impl`` choice against the platform (module
+    docstring): ``"ref"``, ``"native"`` or ``"interpret"``."""
     if impl not in ("auto", "ref", "pallas"):
         raise ValueError(f"impl must be 'auto', 'ref' or 'pallas': {impl!r}")
-    if impl == "pallas":
-        if aligned or padded_fn is None:
-            return pallas_fn(interpret=_interpret())
-        return padded_fn(interpret=_interpret())
-    if impl == "ref" or _interpret():
+    if impl == "ref":
+        return "ref"
+    if jax.default_backend() == "tpu":
+        return "native"
+    return "ref" if impl == "auto" else "interpret"
+
+
+def _dispatch(mode: str, aligned: bool, pallas_fn, ref_fn, padded_fn):
+    """Run the oracle, or the kernel on aligned / padded operands."""
+    if mode == "ref":
         return ref_fn()
-    if aligned:
-        return pallas_fn(interpret=False)
-    if padded_fn is None:
-        return ref_fn()
-    return padded_fn(interpret=False)
+    return (pallas_fn if aligned else padded_fn)(
+        interpret=mode == "interpret")
 
 
 def _pad_hw(x, mh: int, mw: int):
@@ -173,12 +181,16 @@ def _pad_hw(x, mh: int, mw: int):
     return jnp.pad(x, cfg)
 
 
-@partial(jax.jit, static_argnames=("impl",))
 def rgb2ycbcr(img, impl: str = "auto"):
     """(3, H, W) → (3, H, W) f32 level-shifted YCbCr."""
+    return _rgb2ycbcr(img, _mode(impl))
+
+
+@partial(jax.jit, static_argnames=("mode",))
+def _rgb2ycbcr(img, mode: str):
     H, W = img.shape[1], img.shape[2]
     return _dispatch(
-        impl, _aligned(H, 8) and _aligned(W, 128),
+        mode, _aligned(H, 8) and _aligned(W, 128),
         partial(rgb2ycbcr_pallas, img),
         lambda: ref.rgb2ycbcr_ref(img),
         # elementwise → padding is invisible to the retained region
@@ -186,25 +198,38 @@ def rgb2ycbcr(img, impl: str = "auto"):
                                       **kw)[:, :H, :W])
 
 
-@partial(jax.jit, static_argnames=("impl",))
 def downsample2x2(img, impl: str = "auto"):
-    """(C, H, W) → (C, H//2, W//2) f32 box-filtered."""
-    H, W = img.shape[1], img.shape[2]
-    return _dispatch(
-        impl, _aligned(H, 16) and _aligned(W, 256),
-        partial(downsample2x2_pallas, img),
-        lambda: ref.downsample2x2_ref(img),
-        # 2×2 boxes are independent; the pad only fills boxes sliced away
-        lambda **kw: downsample2x2_pallas(_pad_hw(img, 16, 256),
-                                          **kw)[:, :H // 2, :W // 2])
+    """(C, H, W) → (C, H//2, W//2) f32 box-filtered, rows split over the
+    ambient mesh's data axis."""
+    return _downsample2x2(img, _mode(impl), default_mesh())
 
 
-@partial(jax.jit, static_argnames=("impl",))
+@partial(jax.jit, static_argnames=("mode", "mesh"))
+def _downsample2x2(img, mode: str, mesh):
+    def run(x):
+        H, W = x.shape[1], x.shape[2]
+        return _dispatch(
+            mode, _aligned(H, 16) and _aligned(W, 256),
+            partial(downsample2x2_pallas, x),
+            lambda: ref.downsample2x2_ref(x),
+            # 2×2 boxes are independent; the pad only fills boxes sliced away
+            lambda **kw: downsample2x2_pallas(_pad_hw(x, 16, 256),
+                                              **kw)[:, :H // 2, :W // 2])
+    # row bands of even height keep every 2×2 box on one device
+    even = img.shape[1] % (2 * mesh.devices.size) == 0
+    return _on_mesh(run, mesh, P(None, "data") if even else P(), img)
+
+
 def dct8x8_quant(plane, qtable, impl: str = "auto"):
     """(H, W) f32 → (H, W) i32 quantized DCT coefficients."""
+    return _dct8x8_quant(plane, qtable, _mode(impl))
+
+
+@partial(jax.jit, static_argnames=("mode",))
+def _dct8x8_quant(plane, qtable, mode: str):
     H, W = plane.shape
     return _dispatch(
-        impl, _aligned(H, 8) and _aligned(W, 128),
+        mode, _aligned(H, 8) and _aligned(W, 128),
         partial(dct8x8_quant_pallas, plane, qtable),
         lambda: ref.dct8x8_quant_ref(plane, qtable),
         # 8×8 blocks are independent; padding adds all-zero blocks only
@@ -212,28 +237,34 @@ def dct8x8_quant(plane, qtable, impl: str = "auto"):
                                          **kw)[:H, :W])
 
 
-@partial(jax.jit, static_argnames=("impl",))
-def _jpeg_transform_core(tiles, qluma, qchroma, impl: str = "auto"):
-    H, W = tiles.shape[2], tiles.shape[3]
-    return _dispatch(
-        impl, _aligned(H, 8) and _aligned(W, 128),
-        partial(jpeg_transform_pallas, tiles, qluma, qchroma),
-        lambda: ref.jpeg_transform_ref(tiles, qluma, qchroma),
-        lambda **kw: jpeg_transform_pallas(_pad_hw(tiles, 8, 128), qluma,
-                                           qchroma, **kw)[:, :, :H, :W])
+@partial(jax.jit, static_argnames=("mode", "mesh"))
+def _jpeg_transform_core(tiles, qluma, qchroma, mode: str, mesh):
+    def run(x, ql, qc):
+        H, W = x.shape[2], x.shape[3]
+        return _dispatch(
+            mode, _aligned(H, 8) and _aligned(W, 128),
+            partial(jpeg_transform_pallas, x, ql, qc),
+            lambda: ref.jpeg_transform_ref(x, ql, qc),
+            lambda **kw: jpeg_transform_pallas(_pad_hw(x, 8, 128), ql, qc,
+                                               **kw)[:, :, :H, :W])
+    return _on_mesh(run, mesh, data_sharding(tiles.shape[0], mesh).spec,
+                    tiles, qluma, qchroma)
 
 
-@partial(jax.jit, static_argnames=("impl",))
-def _jpeg_inverse_core(coef, qluma, qchroma, impl: str = "auto"):
-    H, W = coef.shape[2], coef.shape[3]
-    return _dispatch(
-        impl, _aligned(H, 8) and _aligned(W, 128),
-        lambda **kw: jpeg_inverse_pallas(
-            coef, qluma, qchroma, **kw).astype(jnp.uint8),
-        lambda: ref.jpeg_inverse_ref(coef, qluma, qchroma),
-        lambda **kw: jpeg_inverse_pallas(
-            _pad_hw(coef, 8, 128), qluma, qchroma,
-            **kw).astype(jnp.uint8)[:, :, :H, :W])
+@partial(jax.jit, static_argnames=("mode", "mesh"))
+def _jpeg_inverse_core(coef, qluma, qchroma, mode: str, mesh):
+    def run(x, ql, qc):
+        H, W = x.shape[2], x.shape[3]
+        return _dispatch(
+            mode, _aligned(H, 8) and _aligned(W, 128),
+            lambda **kw: jpeg_inverse_pallas(
+                x, ql, qc, **kw).astype(jnp.uint8),
+            lambda: ref.jpeg_inverse_ref(x, ql, qc),
+            lambda **kw: jpeg_inverse_pallas(
+                _pad_hw(x, 8, 128), ql, qc,
+                **kw).astype(jnp.uint8)[:, :, :H, :W])
+    return _on_mesh(run, mesh, data_sharding(coef.shape[0], mesh).spec,
+                    coef, qluma, qchroma)
 
 
 def jpeg_transform(tiles, qluma=None, qchroma=None, impl: str = "auto"):
@@ -245,11 +276,12 @@ def jpeg_transform(tiles, qluma=None, qchroma=None, impl: str = "auto"):
     and laid out over the ambient mesh's ``data`` axis (see module
     docstring) — bit-identical to the unsharded, unpadded call.
     """
-    qluma = jnp.asarray(ref.JPEG_LUMA_Q) if qluma is None else qluma
-    qchroma = jnp.asarray(ref.JPEG_CHROMA_Q) if qchroma is None else qchroma
+    mode = _mode(impl)
+    qluma = ref.JPEG_LUMA_Q if qluma is None else qluma
+    qchroma = ref.JPEG_CHROMA_Q if qchroma is None else qchroma
     return _batched_call(
-        tiles, lambda x, ql, qc: _jpeg_transform_core(x, ql, qc, impl),
-        qluma, qchroma)
+        tiles, lambda x, mesh: _jpeg_transform_core(x, qluma, qchroma,
+                                                   mode, mesh))
 
 
 def jpeg_inverse(coef, qluma=None, qchroma=None, impl: str = "auto"):
@@ -261,14 +293,15 @@ def jpeg_inverse(coef, qluma=None, qchroma=None, impl: str = "auto"):
     export path's JPEG decoder. Bucketed and mesh-sharded exactly like
     :func:`jpeg_transform`.
     """
-    qluma = jnp.asarray(ref.JPEG_LUMA_Q) if qluma is None else qluma
-    qchroma = jnp.asarray(ref.JPEG_CHROMA_Q) if qchroma is None else qchroma
+    mode = _mode(impl)
+    qluma = ref.JPEG_LUMA_Q if qluma is None else qluma
+    qchroma = ref.JPEG_CHROMA_Q if qchroma is None else qchroma
     return _batched_call(
-        coef, lambda x, ql, qc: _jpeg_inverse_core(x, ql, qc, impl),
-        qluma, qchroma)
+        coef, lambda x, mesh: _jpeg_inverse_core(x, qluma, qchroma,
+                                                mode, mesh))
 
 
 @jax.jit
 def idct8x8_dequant(coef, qtable):
-    """Decoder-side inverse (jnp only; used by tests and the JPEG decoder)."""
+    """Decoder-side inverse of ``dct8x8_quant`` (jnp only; a test reference)."""
     return ref.idct8x8_dequant_ref(coef, qtable)

@@ -8,8 +8,9 @@ import numpy as np
 __all__ = [
     "rgb2ycbcr_ref", "downsample2x2_ref", "dct8x8_quant_ref",
     "idct8x8_dequant_ref", "jpeg_transform_ref", "jpeg_inverse_ref",
-    "idct_dequant_blocks", "ycbcr_polynomials", "ycbcr_inverse_polynomials",
-    "dct_matrix", "JPEG_LUMA_Q", "JPEG_CHROMA_Q",
+    "strip_transform", "strip_dct_matrices", "qtable_strip",
+    "ycbcr_polynomials", "ycbcr_inverse_polynomials", "dct_matrix",
+    "JPEG_LUMA_Q", "JPEG_CHROMA_Q", "STRIP",
 ]
 
 # ITU-T81 Annex K quantization tables (quality 50)
@@ -35,6 +36,13 @@ JPEG_CHROMA_Q = np.array([
     [99, 99, 99, 99, 99, 99, 99, 99],
 ], np.float32)
 
+#: lanes per DCT strip: 16 8×8 blocks side by side, one (8, 128) f32 tile
+STRIP = 128
+
+# f32 matmuls on a TPU default to reduced-precision passes; every DCT and
+# pooling contraction asks for full f32 so the chip agrees with the CPU
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def dct_matrix() -> np.ndarray:
     """Orthonormal 8×8 DCT-II matrix C (DCT: C·X·Cᵀ)."""
@@ -43,6 +51,60 @@ def dct_matrix() -> np.ndarray:
     C *= np.sqrt(2.0 / 8.0)
     C[0] *= 1.0 / np.sqrt(2.0)
     return C.astype(np.float32)
+
+
+def strip_dct_matrices(inverse: bool = False) -> tuple[np.ndarray, np.ndarray]:
+    """(L, R) with ``L·X·R`` = the 8×8 (i)DCT of every block of a strip.
+
+    X is an (8, 128) strip of 16 blocks side by side. The forward DCT is
+    ``L = C`` and ``R = I₁₆ ⊗ Cᵀ`` (block diagonal, 128×128); the inverse
+    is ``L = Cᵀ`` and ``R = I₁₆ ⊗ C``. Two plain 2-D matmuls, no reshape
+    of the strip: the form the TPU's kernel compiler accepts. The matrices
+    are built here with numpy and passed to the kernels as operands —
+    XLA's float32 cosine differs from numpy's in the last ULP.
+    """
+    C = dct_matrix()
+    left, right = (C.T, C) if inverse else (C, C.T)
+    eye = np.eye(STRIP // 8, dtype=np.float32)
+    return np.ascontiguousarray(left), np.kron(eye, right)
+
+
+def qtable_strip(q) -> jnp.ndarray:
+    """An (8, 8) quantization table repeated across one (8, 128) strip."""
+    return jnp.tile(jnp.asarray(q, jnp.float32), (1, STRIP // 8))
+
+
+def strip_transform(x, left, right):
+    """(…, 8, 128) strips → ``left·X·right`` per strip.
+
+    The single copy of the block-transform contraction, shared by the
+    Pallas kernel bodies (``dct8x8_quant``, ``jpeg_transform``,
+    ``jpeg_inverse``) and the oracles below. Both sides must run this one
+    expression with the same operand shapes: a reassociated contraction
+    drifts the last ULPs, which flips a round-at-half quantization or
+    pixel round and breaks the batched/per-tile byte-identity contract.
+    """
+    t = jnp.matmul(left, x, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
+    return jnp.matmul(t, right, precision=HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _to_strips(x):
+    """(…, H, W) → (…, H/8, ⌈W/128⌉, 8, 128); W is zero-padded (a zero
+    block transforms to zeros and never mixes with its neighbours)."""
+    *lead, H, W = x.shape
+    assert H % 8 == 0 and W % 8 == 0, x.shape
+    pad = -W % STRIP
+    x = jnp.pad(x, [(0, 0)] * (len(lead) + 1) + [(0, pad)])
+    x = x.reshape(*lead, H // 8, 8, (W + pad) // STRIP, STRIP)
+    return jnp.swapaxes(x, -3, -2)
+
+
+def _from_strips(s, W: int):
+    """Inverse of :func:`_to_strips` (drops the lane padding)."""
+    *lead, bh, bw, _, _ = s.shape
+    return jnp.swapaxes(s, -3, -2).reshape(*lead, bh * 8, bw * STRIP)[..., :W]
 
 
 def ycbcr_polynomials(r, g, b):
@@ -88,28 +150,29 @@ def rgb2ycbcr_ref(img):
 
 
 def downsample2x2_ref(img):
-    """2×2 box filter, stride 2. img: (C, H, W) → (C, H//2, W//2) float32."""
+    """2×2 box filter, stride 2. img: (C, H, W) → (C, H//2, W//2) float32.
+
+    Row pairs first, then column pairs: the association order of the
+    kernel's two pooling matmuls (its 0.25 weights scale exactly).
+    """
     x = img.astype(jnp.float32)
     C, H, W = x.shape
     x = x[:, : H - H % 2, : W - W % 2]
-    return 0.25 * (x[:, 0::2, 0::2] + x[:, 1::2, 0::2]
-                   + x[:, 0::2, 1::2] + x[:, 1::2, 1::2])
+    rows = x[:, 0::2] + x[:, 1::2]
+    return 0.25 * (rows[:, :, 0::2] + rows[:, :, 1::2])
 
 
 def dct8x8_quant_ref(plane, qtable):
     """Blockwise 8×8 DCT-II + quantization (round(X̂/Q)).
 
-    plane: (H, W) float32 level-shifted; qtable: (8, 8).
-    Returns int32 coefficients, same (H, W) layout (blocks in place).
+    plane: (…, H, W) float32 level-shifted; qtable: (8, 8).
+    Returns int32 coefficients, same layout (blocks in place).
     """
-    H, W = plane.shape
-    assert H % 8 == 0 and W % 8 == 0
-    C = jnp.asarray(dct_matrix())
-    x = plane.astype(jnp.float32).reshape(H // 8, 8, W // 8, 8)
-    x = x.transpose(0, 2, 1, 3)  # (bh, bw, 8, 8)
-    y = jnp.einsum("ij,bcjk,lk->bcil", C, x, C)
-    q = jnp.round(y / qtable[None, None]).astype(jnp.int32)
-    return q.transpose(0, 2, 1, 3).reshape(H, W)
+    W = plane.shape[-1]
+    left, right = strip_dct_matrices()
+    y = strip_transform(_to_strips(plane.astype(jnp.float32)), left, right)
+    q = jnp.round(y / qtable_strip(qtable)).astype(jnp.int32)
+    return _from_strips(q, W)
 
 
 def jpeg_transform_ref(tiles, qluma=None, qchroma=None):
@@ -122,67 +185,31 @@ def jpeg_transform_ref(tiles, qluma=None, qchroma=None):
     qchroma = JPEG_CHROMA_Q if qchroma is None else qchroma
     ycc = jax.vmap(rgb2ycbcr_ref)(tiles)  # (N, 3, H, W) f32 level-shifted
     qs = (qluma, qchroma, qchroma)
-    planes = [
-        jax.vmap(lambda p, q=jnp.asarray(qs[c]): dct8x8_quant_ref(p, q))(
-            ycc[:, c]
-        )
-        for c in range(3)
-    ]
-    return jnp.stack(planes, axis=1)
+    return jnp.stack([dct8x8_quant_ref(ycc[:, c], qs[c]) for c in range(3)],
+                     axis=1)
 
 
 def idct8x8_dequant_ref(coef, qtable):
-    """Inverse of ``dct8x8_quant_ref`` (decoder path / PSNR tests)."""
-    H, W = coef.shape
-    C = jnp.asarray(dct_matrix())
-    x = coef.astype(jnp.float32).reshape(H // 8, 8, W // 8, 8)
-    x = x.transpose(0, 2, 1, 3) * qtable[None, None]
-    y = jnp.einsum("ji,bcjk,kl->bcil", C, x, C)  # Cᵀ·X·C
-    return y.transpose(0, 2, 1, 3).reshape(H, W)
-
-
-def idct_dequant_blocks(xb, qtable, C=None):
-    """(…, 8, 8) quantized coefficient blocks → (…, 8, 8) spatial samples.
-
-    The single copy of the inverse-transform contraction, shared by the
-    fused Pallas kernel body (``jpeg_inverse_pallas``) and the batched
-    oracle below — the decoder-side twin of ``ycbcr_polynomials``'s
-    contract, with two extra bit-exactness guards the forward path's
-    quantization rounding forgives but a pixel round does not:
-
-    * the iDCT is **two chained fixed-order contractions** (Cᵀ·X, then ·C)
-      rather than one triple einsum — a triple einsum lets the backend pick
-      the association order per operand shape, and the two orders differ in
-      the last ULPs;
-    * the kernel passes the host-built ``dct_matrix()`` in as an operand
-      (``C``) instead of rebuilding it in-kernel with iota→cos — XLA's
-      float32 cosine differs from numpy's in the last ULP.
-    """
-    if C is None:
-        C = jnp.asarray(dct_matrix())
-    x = xb.astype(jnp.float32) * qtable
-    t = jnp.einsum("ji,...jk->...ik", C, x)
-    return jnp.einsum("...ik,kl->...il", t, C)
+    """Inverse of ``dct8x8_quant_ref``: (…, H, W) quantized coefficients →
+    (…, H, W) f32 spatial samples (dequantize, then the strip iDCT)."""
+    W = coef.shape[-1]
+    left, right = strip_dct_matrices(inverse=True)
+    x = _to_strips(coef.astype(jnp.float32)) * qtable_strip(qtable)
+    return _from_strips(strip_transform(x, left, right), W)
 
 
 def jpeg_inverse_ref(coef, qluma=None, qchroma=None):
     """Oracle for the fused whole-level inverse JPEG transform kernel.
 
     coef: (N, 3, H, W) int quantized YCbCr DCT coefficients (blocks in
-    place) → (N, 3, H, W) uint8 RGB (idct_dequant_blocks per channel +
+    place) → (N, 3, H, W) uint8 RGB (dequant + strip iDCT per channel +
     ycbcr_inverse_polynomials + round/clip, batched) — the inverse of
     :func:`jpeg_transform_ref` up to quantization loss.
     """
     qluma = JPEG_LUMA_Q if qluma is None else qluma
     qchroma = JPEG_CHROMA_Q if qchroma is None else qchroma
-    N, _, H, W = coef.shape
     qs = (qluma, qchroma, qchroma)
-    planes = []
-    for c in range(3):
-        x = (coef[:, c].reshape(N, H // 8, 8, W // 8, 8)
-             .transpose(0, 1, 3, 2, 4))
-        y = idct_dequant_blocks(x, jnp.asarray(qs[c]))
-        planes.append(y.transpose(0, 1, 3, 2, 4).reshape(N, H, W))
+    planes = [idct8x8_dequant_ref(coef[:, c], qs[c]) for c in range(3)]
     r, g, b = ycbcr_inverse_polynomials(*planes)
     rgb = jnp.stack([r, g, b], axis=1)
     return jnp.clip(jnp.round(rgb), 0, 255).astype(jnp.uint8)

@@ -19,14 +19,13 @@ _BH, _BW = 8, 128
 
 
 def _kernel(x_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)  # (3, BH, BW)
-    y, cb, cr = ycbcr_polynomials(x[0], x[1], x[2])
+    y, cb, cr = ycbcr_polynomials(x_ref[0], x_ref[1], x_ref[2])
     o_ref[0, :, :] = y
     o_ref[1, :, :] = cb
     o_ref[2, :, :] = cr
 
 
-def rgb2ycbcr_pallas(img, *, interpret: bool = True):
+def rgb2ycbcr_pallas(img, *, interpret: bool):
     """img: (3, H, W) uint8/float, H % 8 == 0, W % 128 == 0 → (3, H, W) f32."""
     C, H, W = img.shape
     assert C == 3 and H % _BH == 0 and W % _BW == 0, img.shape
@@ -38,4 +37,4 @@ def rgb2ycbcr_pallas(img, *, interpret: bool = True):
         out_specs=pl.BlockSpec((3, _BH, _BW), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((3, H, W), jnp.float32),
         interpret=interpret,
-    )(img)
+    )(img.astype(jnp.float32))

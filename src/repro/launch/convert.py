@@ -22,8 +22,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.core import ConversionPipeline, RealScheduler
+    from repro.launch.cache import enable_compile_cache
     from repro.wsi import SyntheticScanner, convert_wsi_to_dicom
 
+    enable_compile_cache()
     sched = RealScheduler(workers=max(args.max_instances * 2, 4))
     pipe = ConversionPipeline(
         sched,

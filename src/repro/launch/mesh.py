@@ -6,27 +6,20 @@ touches jax device state; the dry-run sets XLA_FLAGS before first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
-
-
-def _axis_types_kwargs(n: int) -> dict:
-    # jax < 0.5 has neither jax.sharding.AxisType nor the axis_types kwarg
-    # on jax.make_mesh; Auto is the default there, so omitting it is exact.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Whatever devices exist, as a (data,) mesh — smoke tests / examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",), **_axis_types_kwargs(1))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

@@ -69,6 +69,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import tracing
 from repro.kernels import downsample2x2, jpeg_transform, ops as kernel_ops
@@ -195,18 +196,24 @@ class TransferStats:
 TRANSFER_STATS = TransferStats()
 
 
-def _upload_level0(rd: SlideReader) -> jnp.ndarray:
-    """Stream level 0 to the device one tile row at a time.
+def _upload_level0(rd: SlideReader, mesh) -> jnp.ndarray:
+    """Stream level 0 to the mesh one tile row at a time.
 
     Each row strip is handed to ``jax.device_put`` as soon as its tiles are
     inflated, so the host↔device copy of row r overlaps the zlib inflation
     of row r+1; the full-resolution ``(H, W, 3)`` host array of the sync
     path is never materialized. The strips hold exact uint8 values in
     float32, so the device concatenation is bit-identical to a whole-level
-    upload.
+    upload. When the tile rows divide evenly over the mesh's devices, each
+    device receives its own band of rows (``P(None, "data")``, the layout
+    the chain's downsample and tile batches split on); otherwise every
+    device receives the whole level.
     """
     tile, W = rd.tile, rd.W
     bh, bw = rd.grid
+    devices = list(mesh.devices.flat)
+    per = bh // len(devices) if bh % len(devices) == 0 else None
+    replicated = NamedSharding(mesh, P())
     TRANSFER_STATS.uploads += 1
     strips = []
     for r in range(bh):
@@ -214,8 +221,14 @@ def _upload_level0(rd: SlideReader) -> jnp.ndarray:
         for c in range(bw):
             row[:, :, c * tile:(c + 1) * tile] = \
                 np.transpose(rd.read_tile(r, c), (2, 0, 1))
-        strips.append(jax.device_put(row))
-    return strips[0] if len(strips) == 1 else jnp.concatenate(strips, axis=1)
+        strips.append(jax.device_put(
+            row, replicated if per is None else devices[r // per]))
+    if per is None:
+        return jnp.concatenate(strips, axis=1)
+    bands = [jnp.concatenate(strips[d * per:(d + 1) * per], axis=1)
+             for d in range(len(devices))]
+    return jax.make_array_from_single_device_arrays(
+        (3, rd.H, W), NamedSharding(mesh, P(None, "data")), bands)
 
 
 def _wrap_level(opt: ConvertOptions, li: int, frames: list[bytes], ts: str,
@@ -263,7 +276,7 @@ def _pyramid_dims(H: int, W: int,
 
 @lru_cache(maxsize=None)
 def _pyramid_chain(n_levels: int, needed: tuple[int, ...], tile: int,
-                   donate: bool, mesh=None):
+                   donate: bool, mesh):
     """One jitted dispatch for the whole pyramid.
 
     The traced graph chains ``downsample2x2`` level to level and emits
@@ -274,8 +287,8 @@ def _pyramid_chain(n_levels: int, needed: tuple[int, ...], tile: int,
     per-level dispatch + fetch round trips collapse to a single launch.
     ``donate=True`` (accelerators only; CPU warns and cannot donate) lets
     XLA retire the level-0 pixel buffer into the chain's scratch space.
-    ``mesh`` only keys the cache: sharding constraints are baked into the
-    trace from the ambient mesh, so distinct meshes need distinct jits.
+    ``mesh`` (the ambient one) only keys the cache: the kernels' shard_map
+    layouts are baked into the trace, so distinct meshes need distinct jits.
     """
     def chain(dev):
         outs = []
@@ -322,13 +335,14 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
     if not needed:
         return n_levels
 
+    mesh = kernel_ops.default_mesh()
     with tracing.span("convert.upload"):
-        dev = _upload_level0(rd)
+        dev = _upload_level0(rd, mesh)
     donate = jax.default_backend() != "cpu"
     with tracing.span("convert.dispatch", levels=len(needed)):
         # async dispatch: the span covers trace/launch, not device time —
         # device work overlaps the per-level entropy spans below
-        outs = _pyramid_chain(n_levels, needed, tile, donate, opt.mesh)(dev)
+        outs = _pyramid_chain(n_levels, needed, tile, donate, mesh)(dev)
     TRANSFER_STATS.dispatches += 1
     del dev  # donated / retired: the chain owns the pixel pyramid now
     for coef in outs:

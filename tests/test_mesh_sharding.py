@@ -1,7 +1,10 @@
 """Mesh-sharded level batches + padded kernel dispatch.
 
-Three layers:
+Four layers:
 
+- the platform dispatch policy: on the CPU ``auto`` is the oracle and
+  ``pallas`` is interpret mode; with the backend steered to ``tpu``, both
+  pick the native kernel, on padded operands where the shape is ragged;
 - mesh context (``ops.default_mesh`` / ``use_mesh`` / ``data_sharding``)
   and the pow2 bucket padding of the batched kernel wrappers (byte-exact
   vs unpadded, N=0 passthrough);
@@ -27,6 +30,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jcore
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops, ref
@@ -37,6 +41,77 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 RNG = np.random.default_rng(7)
 
 UIDS = json.dumps(["1.2.826.0.1.3680043.2.1", "1.2.826.0.1.3680043.2.2"])
+
+
+# --------------------------------------------------------------------------
+# dispatch policy: what each impl runs on each platform
+# --------------------------------------------------------------------------
+#: kernel → (call, aligned shape, ragged shape, input dtype)
+KERNEL_CALLS = {
+    "rgb2ycbcr": (ops.rgb2ycbcr, (3, 16, 256), (3, 20, 100), jnp.float32),
+    "downsample2x2": (ops.downsample2x2, (3, 16, 256), (3, 20, 100),
+                      jnp.float32),
+    "dct8x8_quant": (lambda x, impl: ops.dct8x8_quant(
+        x, ref.JPEG_LUMA_Q, impl=impl), (16, 256), (24, 72), jnp.float32),
+    "jpeg_transform": (jpeg_transform, (2, 3, 16, 128), (2, 3, 24, 72),
+                       jnp.float32),
+    "jpeg_inverse": (jpeg_inverse, (2, 3, 16, 128), (2, 3, 24, 72),
+                     jnp.int32),
+}
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested jaxprs (jit, shard_map) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def _traced(name: str, impl: str, shape) -> list:
+    call, _, _, dtype = KERNEL_CALLS[name]
+    x = jnp.zeros(shape, dtype)
+    return list(_eqns(jax.make_jaxpr(
+        lambda a: call(a, impl=impl))(x).jaxpr))
+
+
+def _kernel_calls(eqns) -> list:
+    return [e for e in eqns if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CALLS))
+def test_cpu_auto_is_oracle_and_pallas_interprets(name):
+    aligned = KERNEL_CALLS[name][1]
+    assert jax.default_backend() == "cpu"
+    assert ops._mode("auto") == "ref" and ops._mode("pallas") == "interpret"
+    assert not _kernel_calls(_traced(name, "auto", aligned))
+    calls = _kernel_calls(_traced(name, "pallas", aligned))
+    assert calls and all(e.params["interpret"] for e in calls)
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CALLS))
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
+@pytest.mark.parametrize("ragged", [False, True])
+def test_tpu_runs_native_kernel(monkeypatch, name, impl, ragged):
+    """On a TPU no call ends in the oracle or in interpret mode; shapes the
+    blocks do not tile run the kernel on zero-padded operands."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, aligned, unaligned, _ = KERNEL_CALLS[name]
+    shape = unaligned if ragged else aligned
+    eqns = _traced(name, impl, shape)
+    calls = _kernel_calls(eqns)
+    assert len(calls) == 1
+    assert not calls[0].params["interpret"]
+    operand = calls[0].invars[0].aval.shape
+    if ragged:
+        assert any(e.primitive.name == "pad" for e in eqns)
+        assert operand[-1] % 128 == 0 and operand[-1] > shape[-1]
+    else:
+        assert operand == shape
 
 
 # --------------------------------------------------------------------------
@@ -183,6 +258,40 @@ def test_sharded_kernels_bit_exact_multidevice_subprocess():
     """) % SRC
     out = _run(prog)
     assert "SHARDED-KERNELS-OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_level0_upload_lands_in_row_bands_multidevice_subprocess():
+    """Level 0 goes to the mesh one band of tile rows per device (or whole
+    to every device when the rows do not divide), never all to device 0;
+    the assembled array equals the scanner's pixels."""
+    prog = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax, numpy as np, sys
+        sys.path.insert(0, %r)
+        from jax.sharding import PartitionSpec as P
+        from repro.kernels.ops import default_mesh
+        from repro.wsi.convert import _upload_level0
+        from repro.wsi.formats import open_slide
+        from repro.wsi.slide import SyntheticScanner
+
+        mesh = default_mesh()
+        for rows, spec in ((1024, P(None, "data")), (768, P())):
+            rd = open_slide(SyntheticScanner(seed=3).scan(rows, 512, 256))
+            dev = _upload_level0(rd, mesh)
+            assert dev.sharding.spec == spec, dev.sharding
+            shards = dev.addressable_shards
+            assert {s.device for s in shards} == set(mesh.devices.flat)
+            want = np.concatenate(
+                [np.concatenate([rd.read_tile(r, c) for c in range(2)], 1)
+                 for r in range(rows // 256)]).transpose(2, 0, 1)
+            assert (np.asarray(dev) == want).all()
+            band = rows // 4 if spec else rows
+            assert all(s.data.shape == (3, band, 512) for s in shards)
+        print("LEVEL0-BANDS-OK")
+    """) % SRC
+    out = _run(prog)
+    assert "LEVEL0-BANDS-OK" in out.stdout, out.stderr[-2000:]
 
 
 def _single_device_circle() -> tuple[str, str]:

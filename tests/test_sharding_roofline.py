@@ -110,8 +110,8 @@ def test_collectives_counted_in_multidevice_subprocess():
         import sys
         sys.path.insert(0, %r)
         from repro.roofline import analyze_hlo
-        from repro.launch.mesh import _axis_types_kwargs
-        mesh = jax.make_mesh((8,), ("data",), **_axis_types_kwargs(1))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         xs = jax.ShapeDtypeStruct((64, 256), jnp.float32)
         ws = jax.ShapeDtypeStruct((256, 128), jnp.float32)
         f = lambda x, w: jnp.sum(x @ w)
@@ -138,8 +138,8 @@ def test_compressed_psum_multidevice_subprocess():
         import sys
         sys.path.insert(0, %r)
         from repro.comms.compress import compressed_psum
-        from repro.launch.mesh import _axis_types_kwargs
-        mesh = jax.make_mesh((8,), ("data",), **_axis_types_kwargs(1))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 128)),
                         jnp.float32)
         from jax.experimental.shard_map import shard_map
