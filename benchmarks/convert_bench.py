@@ -42,11 +42,10 @@ import time
 
 import numpy as np
 
-from repro.core import ConversionPipeline, RealScheduler
+from repro.core import ConversionPipeline, RealScheduler, tracing
 from repro.kernels import jpeg_transform
 from repro.launch.cache import enable_compile_cache
-from repro.wsi.convert import (TRANSFER_STATS, ConvertOptions,
-                               convert_wsi_to_dicom)
+from repro.wsi.convert import ConvertOptions, convert_wsi_to_dicom
 from repro.wsi.dicom import TS_JPEG_BASELINE, new_uid, write_part10
 from repro.wsi.jpeg import encode_coef_batch, encode_tile, encode_tiles_batch
 from repro.wsi.slide import PSVReader, SyntheticScanner
@@ -124,12 +123,14 @@ def _single_slide(slide: int, reps: int) -> dict:
 
     # the fused-pyramid round-trip gate: one streamed upload and one
     # jitted dispatch per slide — the whole pixel pyramid stays on device
-    TRANSFER_STATS.reset()
-    convert_wsi_to_dicom(psv, options=ConvertOptions(pipelined=True))
-    transfers = {"uploads": TRANSFER_STATS.uploads,
-                 "dispatches": TRANSFER_STATS.dispatches,
-                 "coef_fetches": TRANSFER_STATS.fetches}
-    assert TRANSFER_STATS.uploads == 1 and TRANSFER_STATS.dispatches == 1, \
+    # (counted by the convert.slide span from its own child spans)
+    with tracing.capture() as tracer:
+        convert_wsi_to_dicom(psv, options=ConvertOptions(pipelined=True))
+    (slide_span,) = tracer.spans_named("convert.slide")
+    transfers = {"uploads": slide_span.attrs["uploads"],
+                 "dispatches": slide_span.attrs["dispatches"],
+                 "coef_fetches": slide_span.attrs["fetches"]}
+    assert transfers["uploads"] == 1 and transfers["dispatches"] == 1, \
         f"fused engine issued extra host↔device round trips: {transfers}"
 
     return {

@@ -20,6 +20,15 @@ branch per site. Arming is explicit — :func:`arm`/:func:`disarm` or the
 batch, schedule exploration). The fleet benchmark gates the disarmed
 overhead at <10% (``tracing_overhead`` in ``BENCH_fleet.json``).
 
+One clock with the device profiler: a tracer armed with ``annotate=``
+(a context-manager factory taking the span's name, such as
+``jax.profiler.TraceAnnotation``) enters it for the lifetime of every
+:func:`span` block, on the thread that runs the block. Armed with the
+profiler's annotation while a JAX profile is taken, every program span
+also lands in the trace's host plane, on the clock of the device's events
+(``bench/hostplane.py`` reads them there). This module imports nothing of
+JAX; the factory is the caller's.
+
 Determinism: span/trace ids come from a per-tracer ``itertools.count`` (no
 ``random``, no wall-clock ids), and a tracer armed with ``now=sched.now``
 under :class:`~repro.core.clock.SimScheduler` produces bit-stable span
@@ -43,7 +52,7 @@ from repro.core.clock import monotonic
 __all__ = [
     "Span", "Tracer", "arm", "disarm", "capture", "current",
     "start_span", "end_span", "add_event", "span", "use_span",
-    "current_span", "inject", "extract",
+    "current_span", "inject", "extract", "descendants",
 ]
 
 # the single module-global read on the disarmed fast path
@@ -92,10 +101,14 @@ class Span:
 class Tracer:
     """Span store. The lock is a leaf (nothing is called while held) —
     safe to take under broker/service locks, same discipline as
-    ``Metrics._lock``."""
+    ``Metrics._lock``.
 
-    def __init__(self, now=None):
+    ``annotate``, where given, is entered as ``annotate(name)`` around
+    every :func:`span` block (see the module docstring)."""
+
+    def __init__(self, now=None, annotate=None):
         self._now = now if now is not None else monotonic
+        self.annotate = annotate
         self._lock = TrackedLock("Tracer._lock")
         self._ids = itertools.count(1)
         self.spans: list[Span] = []
@@ -153,15 +166,30 @@ class Tracer:
         with self._lock:
             return [sp.to_dict() for sp in self.spans]
 
+    def descendants(self, root: Span) -> list[Span]:
+        """Every span under ``root``, in start order. A parent starts
+        before its children, so one pass from ``root`` on finds them all,
+        whatever other threads started in between."""
+        with self._lock:
+            spans = list(self.spans)
+        ids = {root.span_id}
+        out = []
+        for sp in spans[spans.index(root) + 1:]:
+            if sp.parent_id in ids:
+                ids.add(sp.span_id)
+                out.append(sp)
+        return out
+
 
 # ---- arming --------------------------------------------------------------
-def arm(now=None) -> Tracer:
+def arm(now=None, annotate=None) -> Tracer:
     """Install a fresh tracer; ``now`` overrides the clock (pass
-    ``sched.now`` for deterministic sim-time spans)."""
+    ``sched.now`` for deterministic sim-time spans); ``annotate`` is the
+    span annotation hook (see :class:`Tracer`)."""
     global _TRACER
     if _TRACER is not None:
         raise RuntimeError("tracing already armed")
-    _TRACER = Tracer(now=now)
+    _TRACER = Tracer(now=now, annotate=annotate)
     return _TRACER
 
 
@@ -238,10 +266,23 @@ class _UseCtx:
 
 class _SpanCtx(_UseCtx):
     """Lifecycle + ambient: ends the span on exit, status ``error`` if the
-    block raised."""
-    __slots__ = ()
+    block raised. Holds the tracer's annotation, if any, open inside the
+    span's lifetime."""
+    __slots__ = ("_ann",)
+
+    def __init__(self, sp: Span, annotate):
+        super().__init__(sp)
+        self._ann = None if annotate is None else annotate(sp.name)
+
+    def __enter__(self) -> Span:
+        sp = super().__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
+        return sp
 
     def __exit__(self, etype, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
         _AMBIENT.stack.pop()
         tr = _TRACER
         if tr is not None:
@@ -264,7 +305,7 @@ def span(name: str, **attrs):
         return _NULL
     st = getattr(_AMBIENT, "stack", None)
     parent = st[-1] if st else None
-    return _SpanCtx(tr.start(name, parent=parent, attrs=attrs))
+    return _SpanCtx(tr.start(name, parent=parent, attrs=attrs), tr.annotate)
 
 
 # ---- instrumentation entry points ---------------------------------------
@@ -303,6 +344,14 @@ def add_event(sp: Span | None, name: str, **attrs):
             return
         sp = st[-1]
     tr.event(sp, name, attrs or None)
+
+
+def descendants(sp: Span | None) -> list[Span]:
+    """The spans under ``sp`` in the armed tracer (empty when disarmed)."""
+    tr = _TRACER
+    if tr is None or sp is None:
+        return []
+    return tr.descendants(sp)
 
 
 def inject(attributes: dict, sp: Span | None = None):

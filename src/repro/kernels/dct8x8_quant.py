@@ -50,4 +50,5 @@ def dct8x8_quant_pallas(plane, qtable, *, interpret: bool):
         out_specs=pl.BlockSpec((_BH, _BW), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((H, W), jnp.int32),
         interpret=interpret,
+        name="dct8x8_quant",
     )(plane.astype(jnp.float32), qtable_strip(qtable), left, right)

@@ -55,4 +55,5 @@ def downsample2x2_pallas(img, *, interpret: bool):
         out_specs=pl.BlockSpec((1, _BH, _BW), lambda c, i, j: (c, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, H // 2, W // 2), jnp.float32),
         interpret=interpret,
+        name="downsample2x2",
     )(img.astype(jnp.float32), rows, cols)

@@ -74,4 +74,5 @@ def jpeg_inverse_pallas(coef, qluma, qchroma, *, interpret: bool):
         out_specs=pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((N, 3, H, W), jnp.int32),
         interpret=interpret,
+        name="jpeg_inverse",
     )(coef.astype(jnp.int32), qwide, left, right)

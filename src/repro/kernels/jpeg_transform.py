@@ -65,4 +65,5 @@ def jpeg_transform_pallas(tiles, qluma, qchroma, *, interpret: bool):
         out_specs=pl.BlockSpec((1, 3, _BH, _BW), lambda n, i, j: (n, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((N, 3, H, W), jnp.int32),
         interpret=interpret,
+        name="jpeg_transform",
     )(tiles.astype(jnp.float32), qwide, left, right)

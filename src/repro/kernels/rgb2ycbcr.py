@@ -37,4 +37,5 @@ def rgb2ycbcr_pallas(img, *, interpret: bool):
         out_specs=pl.BlockSpec((3, _BH, _BW), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((3, H, W), jnp.float32),
         interpret=interpret,
+        name="rgb2ycbcr",
     )(img.astype(jnp.float32))

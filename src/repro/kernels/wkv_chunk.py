@@ -109,6 +109,7 @@ def wkv_chunk_pallas(r, k, v, logw, u, *, chunk: int = 64, sub: int = 16,
         out_shape=jax.ShapeDtypeStruct((B, S, H, K), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
+        name="wkv_chunk",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ) if not interpret else None,

@@ -21,8 +21,9 @@ Three compute paths (see DESIGN.md, "Whole-level batched dispatch" and
   accelerators). The host consumes per-level coefficients behind async
   fetches (``copy_to_host_async``), entropy-coding level N while the
   device is still transforming levels > N. Exactly one host→device upload
-  and one dispatch per slide (counted by ``TRANSFER_STATS``, asserted in
-  the conversion bench).
+  and one dispatch per slide (the ``convert.slide`` span counts its
+  ``convert.upload``/``convert.dispatch``/``convert.fetch`` spans; the
+  conversion bench asserts the counts).
 - **batched sync** (``ConvertOptions(pipelined=False)``): level 0 is
   uploaded once; every further level is produced by chaining
   ``downsample2x2`` on device, and all tiles of a level are transform-coded
@@ -62,6 +63,7 @@ from __future__ import annotations
 import io
 import json
 import tarfile
+from collections import Counter
 from contextlib import nullcontext
 from functools import lru_cache
 
@@ -171,31 +173,6 @@ def _tile_batch(dev: jnp.ndarray, tile: int) -> jnp.ndarray:
             .transpose(1, 3, 0, 2, 4).reshape(bh * bw, 3, tile, tile))
 
 
-class TransferStats:
-    """Host↔device traffic ledger for the fused engine.
-
-    ``uploads`` counts streamed level-0 uploads (one per slide — the strip
-    ``device_put`` calls of a single slide are one logical transfer),
-    ``dispatches`` counts jitted pyramid-chain launches, and ``fetches``
-    counts per-level coefficient downloads. The conversion bench resets
-    this, converts a slide, and asserts ``uploads == 1`` and
-    ``dispatches == 1`` — the "≤1 host↔device round trip per slide"
-    acceptance gate. Counters are advisory (not thread-synchronized);
-    reset + assert from a single thread.
-    """
-
-    def __init__(self):
-        self.reset()
-
-    def reset(self) -> None:
-        self.uploads = 0
-        self.dispatches = 0
-        self.fetches = 0
-
-
-TRANSFER_STATS = TransferStats()
-
-
 def _upload_level0(rd: SlideReader, mesh) -> jnp.ndarray:
     """Stream level 0 to the mesh one tile row at a time.
 
@@ -214,7 +191,6 @@ def _upload_level0(rd: SlideReader, mesh) -> jnp.ndarray:
     devices = list(mesh.devices.flat)
     per = bh // len(devices) if bh % len(devices) == 0 else None
     replicated = NamedSharding(mesh, P())
-    TRANSFER_STATS.uploads += 1
     strips = []
     for r in range(bh):
         row = np.empty((3, tile, W), np.float32)
@@ -292,11 +268,12 @@ def _pyramid_chain(n_levels: int, needed: tuple[int, ...], tile: int,
     """
     def chain(dev):
         outs = []
-        for li in range(n_levels):
-            if li in needed:
-                outs.append(jpeg_transform(_tile_batch(dev, tile)))
-            if li + 1 < n_levels:
-                dev = jnp.clip(jnp.round(downsample2x2(dev)), 0, 255)
+        with jax.named_scope("pyramid"):
+            for li in range(n_levels):
+                if li in needed:
+                    outs.append(jpeg_transform(_tile_batch(dev, tile)))
+                if li + 1 < n_levels:
+                    dev = jnp.clip(jnp.round(downsample2x2(dev)), 0, 255)
         return outs
     kw = {"donate_argnums": (0,)} if donate else {}
     return jax.jit(chain, **kw)
@@ -343,7 +320,6 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
         # async dispatch: the span covers trace/launch, not device time —
         # device work overlaps the per-level entropy spans below
         outs = _pyramid_chain(n_levels, needed, tile, donate, mesh)(dev)
-    TRANSFER_STATS.dispatches += 1
     del dev  # donated / retired: the chain owns the pixel pyramid now
     for coef in outs:
         if hasattr(coef, "copy_to_host_async"):
@@ -352,16 +328,25 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
     for li, coef_dev in zip(needed, outs):
         H, W = dims[li]
         with tracing.span("convert.entropy", level=li):
-            coef = np.asarray(coef_dev)
-            TRANSFER_STATS.fetches += 1
+            # the host blocked on the device: the rest of the chain up to
+            # this level, then its device-to-host copy
+            with tracing.span("convert.fetch", level=li) as sp:
+                coef = np.asarray(coef_dev)
+                if sp is not None:
+                    sp.attrs["bytes"] = coef.nbytes
             bh, bw = H // tile, W // tile
             chunks = [coef] if (bh == 0 or bw == 0) \
                 else _level_chunks(coef, bh, bw)
             frames: list[bytes] = []
-            for ch in chunks:
-                frames += encode_coef_batch(np.asarray(ch))
-            _wrap_level(opt, li, frames, TS_JPEG_BASELINE, tile, H, W,
-                        metadata, study_uid, series_uid)
+            with tracing.span("convert.encode") as sp:
+                for ch in chunks:
+                    frames += encode_coef_batch(np.asarray(ch))
+                if sp is not None:
+                    sp.attrs.update(frames=len(frames),
+                                    bytes_out=sum(map(len, frames)))
+            with tracing.span("convert.wrap"):
+                _wrap_level(opt, li, frames, TS_JPEG_BASELINE, tile, H, W,
+                            metadata, study_uid, series_uid)
             tracing.add_event(None, "convert.checkpoint", level=li,
                               frames=len(frames))
     return n_levels
@@ -457,8 +442,6 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
     study_uid, series_uid = _study_uids(opt)
     ctx = kernel_ops.use_mesh(opt.mesh) if opt.mesh is not None \
         else nullcontext()
-    stats0 = (TRANSFER_STATS.uploads, TRANSFER_STATS.dispatches,
-              TRANSFER_STATS.fetches)
     with tracing.span("convert.slide",
                       slide=(metadata or {}).get("slide_id")) as sp:
         with ctx:
@@ -471,14 +454,12 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
         with tracing.span("convert.pack", levels=n_levels):
             out = _pack_study(opt, n_levels, study_uid, rd.tile)
         if sp is not None:
-            # TRANSFER_STATS is advisory (not thread-synced): under
-            # concurrent conversions the deltas may include a neighbour's
-            # transfers — they annotate, they don't assert
-            sp.attrs.update(
-                levels=n_levels,
-                uploads=TRANSFER_STATS.uploads - stats0[0],
-                dispatches=TRANSFER_STATS.dispatches - stats0[1],
-                fetches=TRANSFER_STATS.fetches - stats0[2])
+            # counted from this slide's own spans: exact under concurrent
+            # conversions
+            n = Counter(d.name for d in tracing.descendants(sp))
+            sp.attrs.update(levels=n_levels, uploads=n["convert.upload"],
+                            dispatches=n["convert.dispatch"],
+                            fetches=n["convert.fetch"])
     return out
 
 

@@ -14,7 +14,7 @@ gathers/elementwise work instead of an interpreter sweep, so the batched
 decode path stays ahead of the per-tile loop at **every** batch size — the
 ``batch_scaling`` acceptance gate in ``benchmarks/export_bench.py``.
 
-Contract with the numpy engine (``jpeg._entropy_decode_batch``, which
+Contract with the numpy engine (``jpeg._run_packed``, which
 remains the differential oracle and still serves tiny batches where a
 compile would dominate):
 
@@ -41,7 +41,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["decode_scans"]
+__all__ = ["pack_scans", "run_packed"]
 
 _ERR_INVALID, _ERR_RUN, _ERR_TRUNC = 1, 2, 3
 
@@ -120,7 +120,8 @@ def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
 
     state = (pos0, u0, jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
              jnp.zeros(total, jnp.int32))
-    pos, u, k, err, zzf = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("lockstep_decode"):
+        pos, u, k, err, zzf = jax.lax.while_loop(cond, body, state)
     return err, zzf
 
 
@@ -146,17 +147,16 @@ def _pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def decode_scans(scans: list[np.ndarray], H: int, W: int) -> np.ndarray:
-    """N unstuffed scans → (N, nb, 3, 64) int32 zigzag coefficients.
+def pack_scans(scans: list[np.ndarray], H: int, W: int) -> tuple:
+    """The host half: N unstuffed scans → one guarded, power-of-two padded
+    byte buffer with each lane's start bit, end bit and first unit.
 
-    Drop-in twin of the numpy lockstep engine: same output (DC slots
-    integrated), same error strings. Lane count and buffer length are
-    padded to powers of two so the jit cache stays small; pad lanes start
-    exhausted (``u = nu``) and can neither write nor flag errors.
+    Lane count and buffer length are padded to powers of two so the jit
+    cache stays small; pad lanes start exhausted (``u = nu``) and can
+    neither write nor flag errors.
     """
     N = len(scans)
-    nb = (H // 8) * (W // 8)
-    nu = nb * 3
+    nu = (H // 8) * (W // 8) * 3
     npad = _pow2(N)
 
     offs = np.zeros(npad, np.int64)
@@ -175,9 +175,19 @@ def decode_scans(scans: list[np.ndarray], H: int, W: int) -> np.ndarray:
 
     u0 = np.full(npad, nu, np.int32)
     u0[:N] = 0
+    return buf, offs * 8, ends, u0, N
+
+
+def run_packed(packed: tuple, H: int, W: int) -> np.ndarray:
+    """The device half: the lockstep loop over a packed buffer, its error
+    flags and coefficients fetched back → (N, nb, 3, 64) int32 zigzag
+    coefficients, the DC slots holding differentials."""
+    buf, bit0, ends, u0, N = packed
+    nb = (H // 8) * (W // 8)
+    nu = nb * 3
     t = _device_tables()
     err, zzf = _lockstep(
-        jnp.asarray(buf), jnp.asarray(offs * 8, jnp.int32),
+        jnp.asarray(buf), jnp.asarray(bit0, jnp.int32),
         jnp.asarray(ends, jnp.int32), jnp.asarray(u0),
         t["sym"], t["len"], t["half"], t["ext"], nu=nu)
     err = np.asarray(err)
@@ -188,7 +198,5 @@ def decode_scans(scans: list[np.ndarray], H: int, W: int) -> np.ndarray:
     if (err == _ERR_TRUNC).any():
         raise ValueError("corrupt JPEG stream: truncated scan data")
 
-    zz = np.array(zzf).reshape(npad, nu * 64)[:N].reshape(N, nb, 3, 64)
-    # integrate the DC differentials (predictor resets at tile boundaries)
-    zz[:, :, :, 0] = np.cumsum(zz[:, :, :, 0], axis=1)
-    return zz
+    npad = u0.shape[0]
+    return np.array(zzf).reshape(npad, nu * 64)[:N].reshape(N, nb, 3, 64)

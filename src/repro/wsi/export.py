@@ -119,7 +119,8 @@ class ExportService:
         """
         self.metrics.inc("pipeline.export.requests")
         with tracing.span("export.study", study=study_uid):
-            metas = self.store.search_instances(study_uid)
+            with tracing.span("export.query"):
+                metas = self.store.search_instances(study_uid)
             if not metas:
                 raise KeyError(f"unknown study {study_uid}")
             keys = []
@@ -143,51 +144,63 @@ class ExportService:
         level = li if meta["instance_number"] is None \
             else meta["instance_number"] - 1
         key = f"{study_uid}/level_{level}.tiff"
-        if skip_unchanged and self.derived.exists(key) and \
-                self.derived.get(key).metadata.get("source_generation") \
-                == meta["generation"]:
-            # the derived TIFF already reflects these instance bytes and
-            # the export is deterministic — nothing to re-derive
-            self.metrics.inc("pipeline.export.levels_unchanged")
-            return key
         tile, cols = meta["rows"] or 0, meta["columns"] or 0
         total_rows, total_cols = meta["total_rows"] or 0, \
             meta["total_cols"] or 0
-        n = self.store.frame_index(sop).n_frames
-        if n == 0:
-            # a level smaller than one tile stores no full frames — there
-            # are no pixels to export (the converter's per-tile path agrees)
-            self.metrics.inc("pipeline.export.levels_skipped")
-            return None
-        if tile <= 0 or tile != cols:
-            raise ValueError(
-                f"unsupported WSM instance {sop}: non-square "
-                f"{tile}x{cols} tiles")
-        bh, bw = total_rows // tile, total_cols // tile
-        if bh * bw != n:
-            raise ValueError(
-                f"corrupt WSM instance {sop}: {n} frames for a "
-                f"{bh}x{bw} tile grid")
+        with tracing.span("export.level", level=level) as sp:
+            if sp is not None:
+                sp.attrs["px"] = total_rows * total_cols
+            if skip_unchanged and self.derived.exists(key) and \
+                    self.derived.get(key).metadata.get(
+                        "source_generation") == meta["generation"]:
+                # the derived TIFF already reflects these instance bytes
+                # and the export is deterministic — nothing to re-derive
+                self.metrics.inc("pipeline.export.levels_unchanged")
+                return key
+            with tracing.span("export.wado") as wado:
+                n = self.store.frame_index(sop).n_frames
+                if sp is not None:
+                    sp.attrs["frames"] = n
+                if n == 0:
+                    # a level smaller than one tile stores no full
+                    # frames — there are no pixels to export (the
+                    # converter's per-tile path agrees)
+                    self.metrics.inc("pipeline.export.levels_skipped")
+                    return None
+                if tile <= 0 or tile != cols:
+                    raise ValueError(
+                        f"unsupported WSM instance {sop}: non-square "
+                        f"{tile}x{cols} tiles")
+                bh, bw = total_rows // tile, total_cols // tile
+                if bh * bw != n:
+                    raise ValueError(
+                        f"corrupt WSM instance {sop}: {n} frames for a "
+                        f"{bh}x{bw} tile grid")
+                frames = [self.store.retrieve_frame(sop, i)
+                          for i in range(n)]
+                if wado is not None:
+                    wado.attrs["bytes"] = sum(map(len, frames))
+            try:
+                rgb = decode_frames(
+                    frames, transfer_syntax=meta["transfer_syntax"],
+                    rows=tile, cols=tile)
+            except ValueError as exc:
+                raise ValueError(f"instance {sop}: {exc}") from None
+            self.metrics.inc("pipeline.export.frames_decoded", n)
 
-        frames = [self.store.retrieve_frame(sop, i) for i in range(n)]
-        try:
-            rgb = decode_frames(frames,
-                                transfer_syntax=meta["transfer_syntax"],
-                                rows=tile, cols=tile)
-        except ValueError as exc:
-            raise ValueError(f"instance {sop}: {exc}") from None
-        self.metrics.inc("pipeline.export.frames_decoded", n)
-
-        tiles = {(r, c): rgb[r * bw + c]
-                 for r in range(bh) for c in range(bw)}
-        desc = (f"repro-dicom2tiff|study = {study_uid}"
-                f"|series = {meta['series_uid']}|sop = {sop}"
-                f"|level = {level}|total_rows = {total_rows}"
-                f"|total_cols = {total_cols}"
-                f"|source_generation = {meta['generation']}")
-        tif = write_tiff(tiles, bh * tile, bw * tile, tile, description=desc)
-        self.derived.put(key, tif, metadata={
-            "study_uid": study_uid, "sop_instance_uid": sop,
-            "source_generation": meta["generation"]})
-        self.metrics.inc("pipeline.export.bytes_written", len(tif))
-        return key
+            tiles = {(r, c): rgb[r * bw + c]
+                     for r in range(bh) for c in range(bw)}
+            desc = (f"repro-dicom2tiff|study = {study_uid}"
+                    f"|series = {meta['series_uid']}|sop = {sop}"
+                    f"|level = {level}|total_rows = {total_rows}"
+                    f"|total_cols = {total_cols}"
+                    f"|source_generation = {meta['generation']}")
+            with tracing.span("export.tiff"):
+                tif = write_tiff(tiles, bh * tile, bw * tile, tile,
+                                 description=desc)
+            with tracing.span("export.put"):
+                self.derived.put(key, tif, metadata={
+                    "study_uid": study_uid, "sop_instance_uid": sop,
+                    "source_generation": meta["generation"]})
+            self.metrics.inc("pipeline.export.bytes_written", len(tif))
+            return key

@@ -48,7 +48,7 @@ import struct
 import numpy as np
 
 from repro.analysis.lockdep import TrackedLock
-
+from repro.core import tracing
 from repro.kernels import (dct8x8_quant, jpeg_inverse, jpeg_transform,
                            rgb2ycbcr)
 from repro.kernels.ref import JPEG_CHROMA_Q, JPEG_LUMA_Q
@@ -565,9 +565,10 @@ _JAX_MIN_UNITS = 4096
 _JAX_MAX_BYTES = 1 << 27
 
 
-def _entropy_decode_batch(scans: list[np.ndarray], H: int, W: int,
-                          engine: str = "auto") -> np.ndarray:
-    """Lockstep twin of ``_decode_blocks`` over N independent scans.
+def _pack_scans(scans: list[np.ndarray], H: int, W: int,
+                engine: str = "auto") -> tuple[str, tuple]:
+    """The host half of a lockstep decode over N independent scans: the
+    engine that decodes them, and the scans laid out for it.
 
     Every tile of a level is its own bitstream (one scan per tile, DC
     predictors reset at tile boundaries), which is the vectorization axis
@@ -588,23 +589,19 @@ def _entropy_decode_batch(scans: list[np.ndarray], H: int, W: int,
       and the numpy engine for tiny batches where a compile would
       dominate.
 
-    DC slots hold differentials during the loop and are integrated with
-    one cumsum at the end. Returns (N, nb, 3, 64) int32 zigzag
-    coefficients, exactly the symbols the per-tile reference loop decodes.
+    ``_run_packed`` runs the result.
     """
-    N = len(scans)
-    nb = (H // 8) * (W // 8)
-    nu = nb * 3  # block-component units per tile, in bitstream order
-
     if engine not in ("auto", "numpy", "jax"):
         raise ValueError(f"engine must be 'auto', 'numpy' or 'jax': "
                          f"{engine!r}")
+    nu = (H // 8) * (W // 8) * 3
     total_bytes = sum(s.size for s in scans)
-    if engine == "jax" or (engine == "auto" and N * nu >= _JAX_MIN_UNITS
+    if engine == "jax" or (engine == "auto"
+                           and len(scans) * nu >= _JAX_MIN_UNITS
                            and total_bytes < _JAX_MAX_BYTES):
-        from repro.wsi.entropy_jax import decode_scans
-        return decode_scans(scans, H, W)
-
+        from repro.wsi.entropy_jax import pack_scans
+        return "jax", pack_scans(scans, H, W)
+    N = len(scans)
     offs = np.zeros(N, np.int64)
     ends = np.zeros(N, np.int64)  # exclusive bit end of each tile's stream
     parts, cur = [], 0
@@ -613,7 +610,21 @@ def _entropy_decode_batch(scans: list[np.ndarray], H: int, W: int,
         ends[i] = (cur + scan.size) * 8
         parts += [scan, np.zeros(_GUARD, np.uint8)]
         cur += scan.size + _GUARD
-    w64 = _window64(np.concatenate(parts))
+    return "numpy", (_window64(np.concatenate(parts)), offs, ends)
+
+
+def _run_packed(engine: str, packed: tuple, H: int, W: int) -> np.ndarray:
+    """The lockstep decode of ``_pack_scans``' result → (N, nb, 3, 64)
+    int32 zigzag coefficients, exactly the symbols the per-tile reference
+    loop decodes, with the DC slots holding differentials
+    (``_coef_planes`` integrates them)."""
+    if engine == "jax":
+        from repro.wsi.entropy_jax import run_packed
+        return run_packed(packed, H, W)
+    w64, offs, ends = packed
+    N = offs.size
+    nb = (H // 8) * (W // 8)
+    nu = nb * 3  # block-component units per tile, in bitstream order
 
     pos = offs * 8
     u = np.zeros(N, np.int64)  # unit index: block * 3 + component
@@ -671,10 +682,20 @@ def _entropy_decode_batch(scans: list[np.ndarray], H: int, W: int,
         if (active & (pos > ends)).any():
             raise ValueError("corrupt JPEG stream: truncated scan data")
 
-    zz = zzf.reshape(N, nb, 3, 64)
+    return zzf.reshape(N, nb, 3, 64)
+
+
+def _coef_planes(zz: np.ndarray, H: int, W: int) -> np.ndarray:
+    """(N, nb, 3, 64) zigzag coefficients, DC slots holding differentials
+    → (N, 3, H, W) coefficient planes."""
+    N, nb = zz.shape[:2]
     # integrate the DC differentials (predictor resets at tile boundaries)
     zz[:, :, :, 0] = np.cumsum(zz[:, :, :, 0], axis=1)
-    return zz
+    out = np.empty((N, 3, H * W), np.int32)
+    # scatter back through the encoder's zigzag gather index (its inverse)
+    out[:, :, _zigzag_gather_index(H, W)] = \
+        zz.transpose(0, 2, 1, 3).reshape(N, 3, nb * 64)
+    return out.reshape(N, 3, H, W)
 
 
 def _parse_jfif(jpg: bytes) -> tuple[int, int, int, int]:
@@ -739,21 +760,20 @@ def decode_coef_batch(jpgs: list[bytes]) -> np.ndarray:
     jpgs = list(jpgs)
     if not jpgs:
         return np.zeros((0, 3, 0, 0), np.int32)
-    geom = [_parse_jfif(j) for j in jpgs]
-    H, W = geom[0][:2]
-    if any((h, w) != (H, W) for h, w, _, _ in geom):
-        raise ValueError(
-            "corrupt JPEG stream: mixed tile geometries in one batch "
-            f"({sorted({(h, w) for h, w, _, _ in geom})})")
-    scans = [_unstuff(np.frombuffer(jpg, np.uint8, end - start, start))
-             for jpg, (_, _, start, end) in zip(jpgs, geom)]
-    zz = _entropy_decode_batch(scans, H, W)  # (N, nb, 3, 64)
-    N, nb = zz.shape[:2]
-    out = np.empty((N, 3, H * W), np.int32)
-    # scatter back through the encoder's zigzag gather index (its inverse)
-    out[:, :, _zigzag_gather_index(H, W)] = \
-        zz.transpose(0, 2, 1, 3).reshape(N, 3, nb * 64)
-    return out.reshape(N, 3, H, W)
+    with tracing.span("decode.parse", frames=len(jpgs)):
+        geom = [_parse_jfif(j) for j in jpgs]
+        H, W = geom[0][:2]
+        if any((h, w) != (H, W) for h, w, _, _ in geom):
+            raise ValueError(
+                "corrupt JPEG stream: mixed tile geometries in one batch "
+                f"({sorted({(h, w) for h, w, _, _ in geom})})")
+        scans = [_unstuff(np.frombuffer(jpg, np.uint8, end - start, start))
+                 for jpg, (_, _, start, end) in zip(jpgs, geom)]
+        engine, packed = _pack_scans(scans, H, W)
+    with tracing.span("decode.entropy", engine=engine):
+        zz = _run_packed(engine, packed, H, W)
+    with tracing.span("decode.scatter"):
+        return _coef_planes(zz, H, W)
 
 
 def decode_tiles_batch(jpgs: list[bytes]) -> np.ndarray:
@@ -768,7 +788,8 @@ def decode_tiles_batch(jpgs: list[bytes]) -> np.ndarray:
     coef = decode_coef_batch(jpgs)
     if coef.shape[0] == 0:
         return np.zeros((0, 0, 0, 3), np.uint8)
-    rgb = np.asarray(jpeg_inverse(coef))
+    with tracing.span("decode.inverse"):
+        rgb = np.asarray(jpeg_inverse(coef))
     return np.ascontiguousarray(rgb.transpose(0, 2, 3, 1))
 
 
@@ -908,11 +929,14 @@ def decode_tile(jpg: bytes) -> np.ndarray:
     the A/B baseline for ``decode_tiles_batch`` (pixel-identical output).
     Truncated/garbage input raises ``ValueError("corrupt JPEG …")``.
     """
-    H, W, data_start, data_end = _parse_jfif(jpg)
-    br = _BitReader(jpg[data_start:data_end])
-    planes = _decode_blocks(br, H, W)
-    coef = np.stack(planes)[None].astype(np.int32)  # (1, 3, H, W)
-    rgb = np.asarray(jpeg_inverse(coef))[0]
+    with tracing.span("decode.parse", frames=1):
+        H, W, data_start, data_end = _parse_jfif(jpg)
+    with tracing.span("decode.entropy", engine="python"):
+        br = _BitReader(jpg[data_start:data_end])
+        planes = _decode_blocks(br, H, W)
+        coef = np.stack(planes)[None].astype(np.int32)  # (1, 3, H, W)
+    with tracing.span("decode.inverse"):
+        rgb = np.asarray(jpeg_inverse(coef))[0]
     return np.ascontiguousarray(rgb.transpose(1, 2, 0))
 
 

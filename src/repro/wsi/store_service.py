@@ -69,7 +69,7 @@ class DicomStoreService:
     # ---- STOW ---------------------------------------------------------------
     def store_study_archive(self, key: str, archive: bytes) -> list[str]:
         """Ingest a converted study tar (one .dcm per pyramid level)."""
-        with tracing.span("stow.archive", key=key):
+        with tracing.span("stow.archive", archive=key):
             stored = []
             for name, blob in study_levels(archive).items():
                 if not name.endswith(".dcm"):
@@ -411,7 +411,8 @@ class ShardedDicomStore:
                                                     _index=idx)
 
     def store_study_archive(self, key: str, archive: bytes) -> list[str]:
-        with tracing.span("stow.archive", key=key, shards=self.n_shards):
+        with tracing.span("stow.archive", archive=key,
+                          shards=self.n_shards):
             stored, touched = [], set()
             for name, blob in study_levels(archive).items():
                 if not name.endswith(".dcm"):

@@ -49,15 +49,22 @@ class ValidationService:
 
     def _handle(self, msg: Message, ctx: DeliveryCtx):
         sop = msg.data["sop_instance_uid"]
-        try:
-            blob = self.store.read_blob(msg.data["key"])
-        except KeyError:
+        reason = None
+        with tracing.span("validate.verify"):
+            try:
+                blob = self.store.read_blob(msg.data["key"])
+            except KeyError:
+                blob = None
+            if blob is not None:
+                try:
+                    Part10Index(blob).verify()
+                except ValueError as exc:
+                    reason = str(exc)
+        if blob is None:
             ctx.ack()  # already deleted/quarantined — nothing to validate
             return
-        try:
-            Part10Index(blob).verify()
-        except ValueError as exc:
-            self._quarantine(sop, blob, str(exc))
+        if reason is not None:
+            self._quarantine(sop, blob, reason)
         else:
             with self._lock:
                 self.checked.append(sop)
@@ -124,18 +131,24 @@ class InferenceSubscriber:
     def _handle(self, msg: Message, ctx: DeliveryCtx):
         sop = msg.data["sop_instance_uid"]
         try:
-            # clamp to the *indexed* frame count, not the declared one — an
-            # instance over-declaring (0028,0008) must not burn redeliveries
-            idx = self.store.frame_index(sop)
-            n = min(idx.n_frames, self.max_frames)
-            frames = [self.store.retrieve_frame(sop, i) for i in range(n)]
-            # the shared store-consumer dispatch: batched decode path when
-            # more than one frame is pulled, per-tile decoder otherwise
-            pixels = decode_frames(
-                frames, transfer_syntax=msg.data.get("transfer_syntax"),
-                rows=msg.data.get("rows") or 0,
-                cols=msg.data.get("columns") or 0)
-            stats = [self.frame_stats(pixels[i]) for i in range(n)]
+            with tracing.span("inference.score") as sp:
+                # clamp to the *indexed* frame count, not the declared one —
+                # an instance over-declaring (0028,0008) must not burn
+                # redeliveries
+                idx = self.store.frame_index(sop)
+                n = min(idx.n_frames, self.max_frames)
+                if sp is not None:
+                    sp.attrs["frames"] = n
+                frames = [self.store.retrieve_frame(sop, i)
+                          for i in range(n)]
+                # the shared store-consumer dispatch: batched decode path
+                # when more than one frame is pulled, per-tile decoder
+                # otherwise
+                pixels = decode_frames(
+                    frames, transfer_syntax=msg.data.get("transfer_syntax"),
+                    rows=msg.data.get("rows") or 0,
+                    cols=msg.data.get("columns") or 0)
+                stats = [self.frame_stats(pixels[i]) for i in range(n)]
         except (KeyError, ValueError):
             # quarantined/deleted before we ran, rotted since storing, or
             # undecodable ("corrupt JPEG …") — the validation subscriber

@@ -65,6 +65,61 @@ def test_exported_pixels_match_wado_frame_decode():
                                           decode_tile(frame))
 
 
+def test_export_levels_are_spans_with_their_stages_as_children():
+    from repro.core import tracing
+
+    _, svc, store, study = _stored_study()
+    exporter = ExportService(svc, store.bucket("derived"))
+    with tracing.capture() as tracer:
+        exporter.export_study(study)
+    index = {sp.span_id: sp for sp in tracer.spans}
+    (top,) = tracer.spans_named("export.study")
+    (query,) = tracer.spans_named("export.query")
+    assert query.parent_id == top.span_id
+    levels = tracer.spans_named("export.level")
+    assert [sp.parent_id for sp in levels] == [top.span_id] * 2
+    assert [(sp.attrs["level"], sp.attrs["px"], sp.attrs["frames"])
+            for sp in levels] == [(0, 512 * 512, 4), (1, 256 * 256, 1)]
+    for sp in levels:
+        kids = [k for k in tracer.spans if k.parent_id == sp.span_id]
+        # the per-tile decoder of a single frame integrates as it decodes
+        scatter = ["decode.scatter"] if sp.attrs["frames"] > 1 else []
+        assert [k.name for k in kids] == [
+            "export.wado", "decode.parse", "decode.entropy", *scatter,
+            "decode.inverse", "export.tiff", "export.put"]
+        assert all(k.status == "ok" for k in kids)
+        wado = kids[0]
+        sop = svc.search_instances(study)[sp.attrs["level"]][
+            "sop_instance_uid"]
+        assert wado.attrs["bytes"] == sum(
+            len(svc.retrieve_frame(sop, i))
+            for i in range(sp.attrs["frames"]))
+    # the batched decoder serves the 4-frame level, the per-tile one the
+    # single frame
+    engines = [sp.attrs["engine"] for sp in tracer.spans_named(
+        "decode.entropy")]
+    assert engines[1] == "python" and engines[0] in ("jax", "numpy")
+    assert all(index[k.parent_id].name == "export.level"
+               for k in tracer.spans_named("decode.parse"))
+    # the per-level events stay on the study span
+    assert [n for _, n, _ in top.events] == ["export.level"] * 2
+
+
+def test_export_bytes_identical_armed_vs_disarmed():
+    from repro.core import tracing
+
+    _, svc, store, study = _stored_study()
+    plain = ExportService(svc, store.bucket("plain"))
+    plain.export_study(study)
+    traced = ExportService(svc, store.bucket("traced"))
+    with tracing.capture() as tracer:
+        traced.export_study(study)
+    assert tracer.spans_named("export.level"), "tracer saw no export"
+    a = _derived_bytes(store.bucket("plain"))
+    b = _derived_bytes(store.bucket("traced"))
+    assert len(a) == 2 and list(a.values()) == list(b.values())
+
+
 def test_native_study_exports_lossless_pixels():
     """jpeg=False studies export through the native path — the TIFF pixels
     equal the original scan exactly (no transform loss anywhere)."""

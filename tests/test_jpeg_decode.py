@@ -193,7 +193,7 @@ def test_decode_tile_accepts_dicom_even_length_pad(tissue_jpg):
 # jitted lockstep entropy engine vs the numpy oracle
 # --------------------------------------------------------------------------
 def _scans(jpgs):
-    """Unstuffed scan arrays + geometry, as _entropy_decode_batch sees
+    """Unstuffed scan arrays + geometry, as the lockstep engines see
     them."""
     from repro.wsi import jpeg as J
 
@@ -204,12 +204,18 @@ def _scans(jpgs):
     return scans, H, W
 
 
+def _lockstep(scans, H, W, engine="auto"):
+    """The lockstep decode as ``decode_coef_batch`` composes it: zigzag
+    coefficients, DC slots holding differentials."""
+    from repro.wsi import jpeg as J
+
+    return J._run_packed(*J._pack_scans(scans, H, W, engine), H, W)
+
+
 @pytest.mark.parametrize("kind", ["noise", "gradient"])
 def test_entropy_engines_coefficient_exact(kind):
     """engine="jax" (lax.while_loop lockstep) must match engine="numpy"
     coefficient-for-coefficient, odd batch sizes included (pad lanes)."""
-    from repro.wsi.jpeg import _entropy_decode_batch
-
     if kind == "noise":
         tiles = RNG.integers(0, 256, size=(5, 64, 128, 3)).astype(np.uint8)
     else:
@@ -218,15 +224,13 @@ def test_entropy_engines_coefficient_exact(kind):
         tiles = np.stack([one, one[:, ::-1], one[::-1]])
     scans, H, W = _scans(encode_tiles_batch(tiles))
     np.testing.assert_array_equal(
-        _entropy_decode_batch(scans, H, W, engine="jax"),
-        _entropy_decode_batch(scans, H, W, engine="numpy"))
+        _lockstep(scans, H, W, engine="jax"),
+        _lockstep(scans, H, W, engine="numpy"))
 
 
 def test_entropy_engines_raise_identical_errors(tissue_jpg):
     """Both engines must raise the same actionable string at the same
     failure class: truncation, garbage (invalid Huffman code)."""
-    from repro.wsi.jpeg import _entropy_decode_batch
-
     scans, H, W = _scans([tissue_jpg] * 2)
     for mutate in (
         lambda s: s[: max(4, s.size // 2)],          # mid-stream truncation
@@ -237,7 +241,7 @@ def test_entropy_engines_raise_identical_errors(tissue_jpg):
         errs = []
         for engine in ("jax", "numpy"):
             with pytest.raises(ValueError, match="corrupt JPEG") as ei:
-                _entropy_decode_batch(bad, H, W, engine=engine)
+                _lockstep(bad, H, W, engine=engine)
             errs.append(str(ei.value))
         assert errs[0] == errs[1], errs
 
@@ -252,7 +256,30 @@ def test_entropy_engine_auto_thresholds():
     # 2 tiles × 3072 units ≥ _JAX_MIN_UNITS → the jax engine; equality with
     # the numpy oracle is the contract either way
     np.testing.assert_array_equal(
-        J._entropy_decode_batch(scans, H, W),
-        J._entropy_decode_batch(scans, H, W, engine="numpy"))
+        _lockstep(scans, H, W),
+        _lockstep(scans, H, W, engine="numpy"))
+    assert J._pack_scans(scans, H, W)[0] == "jax"
     with pytest.raises(ValueError, match="engine"):
-        J._entropy_decode_batch(scans, H, W, engine="cuda")
+        _lockstep(scans, H, W, engine="cuda")
+
+
+@pytest.mark.parametrize("hw,n,engine", [(256, 2, "jax"), (64, 2, "numpy")])
+def test_decode_path_spans_name_its_stages(hw, n, engine):
+    """The batched decode runs as ``decode.parse`` (container parse,
+    unstuffing, packing), ``decode.entropy`` (the lockstep loop of the
+    engine ``auto`` picked, and its fetch), ``decode.scatter`` (DC
+    integration, zigzag scatter) and ``decode.inverse``; the pixels are
+    the same with the tracer armed."""
+    from repro.core import tracing
+
+    jpgs = encode_tiles_batch(_tissue_tiles(n, hw=hw))
+    plain = decode_tiles_batch(jpgs)
+    with tracing.capture() as tracer:
+        traced = decode_tiles_batch(jpgs)
+    np.testing.assert_array_equal(plain, traced)
+    assert [(sp.name, sp.attrs) for sp in tracer.spans] == [
+        ("decode.parse", {"frames": n}),
+        ("decode.entropy", {"engine": engine}),
+        ("decode.scatter", {}),
+        ("decode.inverse", {})]
+    assert all(sp.status == "ok" for sp in tracer.spans)

@@ -3,6 +3,8 @@ suppresses it, the path exemptions hold, and the shipped tree is clean."""
 import textwrap
 from pathlib import Path
 
+import pytest
+
 from repro.analysis import lint
 
 REPO = Path(__file__).resolve().parents[1]
@@ -110,6 +112,21 @@ def test_span_name_fires(tmp_path):
     """)
     assert _rules(fs) == ["span-name"] * 3
     assert "segment.segment" in fs[0].message
+
+
+@pytest.mark.parametrize("name", [
+    "convert.fetch", "convert.encode", "convert.wrap", "export.query",
+    "export.level", "export.wado", "export.tiff", "export.put",
+    "decode.parse", "decode.entropy", "decode.scatter", "decode.inverse",
+    "validate.verify", "inference.score"])
+def test_span_name_accepts_the_layer_spans(tmp_path, name):
+    fs = _findings(tmp_path, f"""\
+        from repro.core import tracing
+        with tracing.span("{name}"):
+            pass
+        sp = tracing.start_span("{name}")
+    """)
+    assert fs == []
 
 
 def test_jit_global_mutation_fires(tmp_path):
