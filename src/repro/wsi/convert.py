@@ -4,7 +4,7 @@ Per slide: sniff the container (``repro.wsi.formats.open_slide`` — PSV,
 tiled TIFF/SVS, or any registered format), stream tiles through the
 ``SlideReader`` protocol, build the multi-resolution pyramid with the
 Pallas downsample kernel, transform-code every tile (Pallas DCT/quant +
-host Huffman), wrap each level in a DICOM Part-10 instance (TILED_FULL),
+Huffman), wrap each level in a DICOM Part-10 instance (TILED_FULL),
 and bundle the study as a tar archive. The converter consumes only the
 reader protocol, so identical pixel content produces byte-identical study
 tars regardless of the source container (given the same manifest UIDs) —
@@ -18,19 +18,27 @@ Three compute paths (see DESIGN.md, "Whole-level batched dispatch" and
   host ``(H, W, 3)`` array), then the **entire pyramid** — every level's
   ``jpeg_transform`` and the ``downsample2x2`` chain between levels — is
   one jitted dispatch (``donate_argnums`` retires the pixel buffer on
-  accelerators). The host consumes per-level coefficients behind async
-  fetches (``copy_to_host_async``), entropy-coding level N while the
-  device is still transforming levels > N. Exactly one host→device upload
-  and one dispatch per slide (the ``convert.slide`` span counts its
-  ``convert.upload``/``convert.dispatch``/``convert.fetch`` spans; the
+  accelerators). The coefficients never come back to the host: each
+  level is Huffman-coded on the device in row-aligned chunks
+  (``jpeg.encode_coef_batch`` on a device array, ``wsi/entropy_encode_jax``)
+  and only the packed scans are copied back, to be 0xFF-stuffed and
+  wrapped; a level's device buffers are dropped once it is coded. A
+  chunk too small to be worth a compile (the last levels' 1-, 2- and
+  4-tile chunks), or a tile the device coder flags (over its slab, or a
+  category outside the baseline tables), is copied back and coded by the
+  numpy coder — a rule on the input, not an option. Exactly one
+  host→device upload and one pyramid dispatch per slide (the
+  ``convert.slide`` span counts its ``convert.upload``/
+  ``convert.dispatch``/``convert.fetch`` spans and sums the
+  ``device_tiles``/``host_tiles`` its ``convert.encode`` spans coded; the
   conversion bench asserts the counts).
 - **batched sync** (``ConvertOptions(pipelined=False)``): level 0 is
   uploaded once; every further level is produced by chaining
   ``downsample2x2`` on device, and all tiles of a level are transform-coded
   by a single fused ``jpeg_transform`` dispatch followed by the vectorized
-  host entropy coder — but each level's host work completes before the next
-  level's device work is enqueued. Kept as the A/B baseline for the
-  pipelined path.
+  numpy entropy coder on the host — but each level's host work completes
+  before the next level's device work is enqueued. Kept as the A/B
+  baseline for the pipelined path.
 - **per-tile** (``ConvertOptions(batched=False)``): the original path — host
   pyramid, ``[encode_tile(f) for f in frames]`` with 4 dispatches per tile.
   Kept for A/B benchmarking.
@@ -55,8 +63,8 @@ the study archive has been durably stored.
 **Thread safety**: ``convert_wsi_to_dicom`` shares no mutable module state
 (the entropy coder's caches are lock-protected), so the real-mode pipeline
 runs up to ``concurrency`` conversions in parallel worker threads — the
-transform dispatch, the numpy entropy coder, and zlib inflation all release
-the GIL for their heavy regions.
+device dispatches and waits, the numpy entropy coder, and zlib inflation
+all release the GIL for their heavy regions.
 """
 from __future__ import annotations
 
@@ -224,7 +232,7 @@ def _wrap_level(opt: ConvertOptions, li: int, frames: list[bytes], ts: str,
 
 def _level_chunks(batch, bh: int, bw: int) -> list:
     """Split a level's (N, 3, T, T) coefficient batch into row-aligned
-    chunks for the host entropy coder.
+    chunks for the entropy coder.
 
     Chunk boundaries sit on whole tile rows and each tile is entropy-coded
     as its own scan, so per-chunk encode emits exactly the frames of a
@@ -236,6 +244,17 @@ def _level_chunks(batch, bh: int, bw: int) -> list:
     rows_per = max(1, bh // 4)
     return [batch[r0 * bw:min(r0 + rows_per, bh) * bw]
             for r0 in range(0, bh, rows_per)]
+
+
+#: what the entropy coder's ``jpeg.encode`` spans count, summed into
+#: ``convert.encode``
+_ENCODE_ATTRS = ("device_tiles", "host_tiles", "bytes_in")
+
+
+def _summed(sp, name: str, attrs: tuple[str, ...]) -> dict[str, int]:
+    """``attrs`` summed over the ``name`` spans below ``sp``."""
+    below = [d for d in tracing.descendants(sp) if d.name == name]
+    return {a: sum(d.attrs.get(a, 0) for d in below) for a in attrs}
 
 
 def _pyramid_dims(H: int, W: int,
@@ -291,14 +310,15 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
     2. **Fused pyramid dispatch** — a single jitted call
        (``_pyramid_chain``) runs every level's ``jpeg_transform`` and the
        ``downsample2x2`` chain between levels in one traced graph. The
-       dispatch returns immediately (JAX async dispatch); every level's
-       coefficient fetch is started with ``copy_to_host_async`` so
-       downloads overlap the remaining device work.
+       dispatch returns immediately (JAX async dispatch).
     3. **Ordered consume** — levels are entropy-coded and Part-10-wrapped
-       in pyramid order, in row-aligned chunks (``_level_chunks``); each
+       in pyramid order, in row-aligned chunks (``_level_chunks``) of the
+       device-resident coefficients: each chunk is Huffman-coded on the
+       device and only its packed scans come back (``convert.encode``
+       records ``device_tiles``, ``host_tiles`` and ``bytes_in``). Each
        finished level is checkpointed into the manifest immediately, so a
-       crash mid-pyramid resumes from every completed level. While the
-       host codes level N, the device is still transforming levels > N.
+       crash mid-pyramid resumes from every completed level, and its
+       device coefficients are released.
 
     The per-tile math and emitted frame order are identical to the sync
     engine's per-level dispatch — fusion changes only where buffers live —
@@ -321,28 +341,28 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
         # device work overlaps the per-level entropy spans below
         outs = _pyramid_chain(n_levels, needed, tile, donate, mesh)(dev)
     del dev  # donated / retired: the chain owns the pixel pyramid now
-    for coef in outs:
-        if hasattr(coef, "copy_to_host_async"):
-            coef.copy_to_host_async()
+    levels = dict(zip(needed, outs))
+    del outs  # each level's coefficients are freed once it is coded
 
-    for li, coef_dev in zip(needed, outs):
+    for li in needed:
         H, W = dims[li]
+        coef = levels.pop(li)
         with tracing.span("convert.entropy", level=li):
             # the host blocked on the device: the rest of the chain up to
-            # this level, then its device-to-host copy
-            with tracing.span("convert.fetch", level=li) as sp:
-                coef = np.asarray(coef_dev)
-                if sp is not None:
-                    sp.attrs["bytes"] = coef.nbytes
+            # this level; nothing is copied
+            with tracing.span("convert.fetch", level=li):
+                coef.block_until_ready()
             bh, bw = H // tile, W // tile
-            chunks = [coef] if (bh == 0 or bw == 0) \
-                else _level_chunks(coef, bh, bw)
             frames: list[bytes] = []
             with tracing.span("convert.encode") as sp:
-                for ch in chunks:
-                    frames += encode_coef_batch(np.asarray(ch))
+                chunks = [coef] if (bh == 0 or bw == 0) \
+                    else _level_chunks(coef, bh, bw)
+                del coef
+                while chunks:
+                    frames += encode_coef_batch(chunks.pop(0))
                 if sp is not None:
-                    sp.attrs.update(frames=len(frames),
+                    sp.attrs.update(_summed(sp, "jpeg.encode", _ENCODE_ATTRS),
+                                    frames=len(frames),
                                     bytes_out=sum(map(len, frames)))
             with tracing.span("convert.wrap"):
                 _wrap_level(opt, li, frames, TS_JPEG_BASELINE, tile, H, W,
@@ -459,7 +479,9 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
             n = Counter(d.name for d in tracing.descendants(sp))
             sp.attrs.update(levels=n_levels, uploads=n["convert.upload"],
                             dispatches=n["convert.dispatch"],
-                            fetches=n["convert.fetch"])
+                            fetches=n["convert.fetch"],
+                            **_summed(sp, "convert.encode",
+                                      ("device_tiles", "host_tiles")))
     return out
 
 

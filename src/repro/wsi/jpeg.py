@@ -1,10 +1,13 @@
-"""JPEG baseline codec: JAX/Pallas transform stage + host entropy stage.
+"""JPEG baseline codec: JAX/Pallas transform stage + entropy stage.
 
 Hardware-adaptation split (recorded in DESIGN.md, "Transform/entropy split"):
 the transform math (color conversion, 8×8 DCT, quantization) is data-parallel
-→ Pallas kernels; Huffman coding is a sequential, branchy bitstream operation
-with no MXU/VPU analogue → host numpy. This mirrors what the C++ ``wsi2dcm``
-converter does (SIMD transform, scalar entropy coder).
+→ Pallas kernels. Huffman coding is a sequential bitstream per tile, but
+every tile is its own scan, and within a tile the symbols, their bit
+offsets (a prefix sum) and their packing into words are vectorizable: so
+coefficients that already live on the device are Huffman-coded there
+(``repro.wsi.entropy_encode_jax``) and only the packed scans come back;
+the host keeps the 0xFF byte stuffing and the JFIF wrap.
 
 Two encoder paths, byte-identical by construction (tested):
 
@@ -12,9 +15,15 @@ Two encoder paths, byte-identical by construction (tested):
   (rgb2ycbcr + 3× dct8x8_quant) and a per-coefficient Python Huffman loop.
   Kept as the A/B baseline for benchmarks.
 - ``encode_tiles_batch``: the whole-level batched path — one fused
-  ``jpeg_transform`` dispatch for every tile of a level, then a
-  numpy-vectorized symbol-stream entropy coder (``encode_coef_batch``) whose
-  cost scales with the number of emitted symbols, not coefficients.
+  ``jpeg_transform`` dispatch for every tile of a level, then the
+  entropy stage (``encode_coef_batch``). Where the coefficients live
+  decides where it runs: a device-resident batch of at least
+  ``_DEVICE_MIN_UNITS`` units (the pipelined converter's level chunks) on
+  the device, numpy input and small device batches in the numpy-vectorized
+  symbol-stream coder, whose cost scales with the number of emitted
+  symbols and which stays the oracle. A tile the device coder flags (over
+  its slab, or a category outside the baseline tables) is coded by numpy,
+  which raises its own ``ValueError`` where the input is out of range.
 
 And two decoder paths, pixel-identical by construction (tested) — the
 export subsystem's compute spine run in reverse:
@@ -38,14 +47,16 @@ string is what the export service turns into an actionable DLQ reason.
 
 Both encoder paths are thread-safe (the zigzag gather-index cache is the
 only module-level mutable state and is lock-protected), and the heavy numpy
-regions release the GIL — the real-mode pipeline entropy-codes several
-slides' levels in parallel worker threads.
+regions and device waits release the GIL — the real-mode pipeline
+entropy-codes several slides' levels in parallel worker threads.
 """
 from __future__ import annotations
 
 import struct
 
 import numpy as np
+
+import jax
 
 from repro.analysis.lockdep import TrackedLock
 from repro.core import tracing
@@ -891,19 +902,80 @@ def encode_tile(tile_rgb: np.ndarray) -> bytes:
     return bytes(buf)
 
 
-def encode_coef_batch(coef: np.ndarray) -> list[bytes]:
+#: device-resident batches with at least this many block-component units
+#: (N × nu) are Huffman-coded on the device; smaller ones (the last levels'
+#: 1-, 2- and 4-tile chunks) are copied back and coded by numpy, where a
+#: compile per chunk shape would cost more than the coding
+_DEVICE_MIN_UNITS = 1 << 15
+
+#: pixels per device-coder dispatch (16 tiles of 256²): bounds the coder's
+#: temporaries (≈ 0.1 GiB) and the shapes it compiles (one for every level
+#: of a square slide), and per tile it ran faster on the chip than 64-tile
+#: dispatches (0.16 against 0.25 ms); a larger batch is coded in
+#: dispatches of this size, all enqueued before the first wait
+_DEVICE_PX = 1 << 20
+
+
+def _device_scans(coef: jax.Array) -> tuple[list[bytes], int, int]:
+    """Entropy-code a device-resident batch on the device
+    (``repro.wsi.entropy_encode_jax``): copy back each tile's packed scan
+    and bit count, 0xFF-stuff it here. A tile the device coder flags (over
+    its capacity, or a category outside the baseline tables) is copied
+    back alone and coded by the numpy coder, which raises its own
+    ``ValueError`` where the input is out of range.
+
+    Returns (stuffed scans, tiles coded on the host, bytes copied back).
+    """
+    from repro.wsi.entropy_encode_jax import huffman_encode
+    N, _, H, W = coef.shape
+    step = max(1, _DEVICE_PX // (H * W))
+    outs = [huffman_encode(coef if N <= step else coef[a:a + step])
+            for a in range(0, max(N, 1), step)]
+    slabs, bits, flags = (np.concatenate([np.asarray(o[i]) for o in outs])
+                          for i in range(3))
+    copied = slabs.nbytes + bits.nbytes + flags.nbytes
+    ends = (bits.astype(np.int64) + 7) >> 3
+    scans = [b"" if flag else _stuff(slab[:end])
+             for slab, end, flag in zip(slabs, ends, flags)]
+    for i in map(int, np.flatnonzero(flags)):
+        tile = np.asarray(coef[i:i + 1])
+        copied += tile.nbytes
+        scans[i] = _entropy_encode_batch(tile)[0]
+    return scans, int(flags.sum()), copied
+
+
+def encode_coef_batch(coef) -> list[bytes]:
     """(N, 3, H, W) int quantized YCbCr DCT coefficients → N JFIF tiles.
 
-    The host entropy stage of the batched path: vectorized symbol-stream
-    encoding (scales with emitted symbols, not coefficients).
+    The entropy stage of the batched path. Where the coefficients live
+    decides where they are coded: a ``jax.Array`` of at least
+    ``_DEVICE_MIN_UNITS`` units is Huffman-coded on the device and only
+    the packed scans come back (``_device_scans``); anything else — numpy
+    input, or a device batch too small to be worth a compile — goes
+    through the vectorized numpy coder, whose cost scales with emitted
+    symbols. Both give the same bytes. The ``jpeg.encode`` span records
+    ``device_tiles``, ``host_tiles`` and ``bytes_in`` (bytes copied from
+    the device).
     """
-    coef = np.asarray(coef)
+    on_device = isinstance(coef, jax.Array)
+    if not on_device:
+        coef = np.asarray(coef)
     N, _, H, W = coef.shape
     if N == 0:
         return []
+    with tracing.span("jpeg.encode", frames=N) as sp:
+        if on_device and N * (H // 8) * (W // 8) * 3 >= _DEVICE_MIN_UNITS:
+            scans, host, copied = _device_scans(coef)
+        else:
+            host_coef = np.asarray(coef)
+            scans, host = _entropy_encode_batch(host_coef), N
+            copied = host_coef.nbytes if on_device else 0
+        if sp is not None:
+            sp.attrs.update(device_tiles=N - host, host_tiles=host,
+                            bytes_in=copied)
     header = bytes(_jfif_header(H, W))
     eoi = bytes((0xFF, 0xD9))
-    return [header + scan + eoi for scan in _entropy_encode_batch(coef)]
+    return [header + scan + eoi for scan in scans]
 
 
 def encode_tiles_batch(tiles_rgb: np.ndarray) -> list[bytes]:

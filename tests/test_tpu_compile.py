@@ -101,3 +101,20 @@ def test_pyramid_chain_8192_fits_one_chip(on_tpu, one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.alias_size_in_bytes > 0  # level 0 donated to the chain
     assert total < HBM_BYTES, total
+
+
+def test_huffman_encode_dispatch_fits_one_chip(on_tpu, one_chip):
+    """The device Huffman coder on one dispatch of the pipelined engine
+    (``jpeg._DEVICE_PX`` pixels: 16 tiles of 256²) compiles as a program of
+    its own, loops over no symbol (no ``while`` in the compiled text), and
+    its temporaries stay under 1 GiB, so three converters in flight add at
+    most 3 GiB to the chip's 16 GB."""
+    from repro.wsi import jpeg
+    from repro.wsi.entropy_encode_jax import huffman_encode
+
+    n = jpeg._DEVICE_PX // (256 * 256)
+    compiled = huffman_encode.lower(jax.ShapeDtypeStruct(
+        (n, 3, 256, 256), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and "huffman_encode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30

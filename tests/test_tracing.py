@@ -388,15 +388,20 @@ def test_entropy_span_children_cover_it():
         parts += took
         fetch, encode, _ = kids
         side = 1024 >> sp.attrs["level"]
-        assert fetch.attrs["level"] == sp.attrs["level"]
-        assert fetch.attrs["bytes"] == side * side * 3 * 4  # int32 coefs
+        assert fetch.attrs == {"level": sp.attrs["level"]}  # waits only
         assert encode.attrs["frames"] == (side // 256) ** 2
+        # chunks of ≤ 4 tiles: coded on the host from their int32 coefs
+        assert encode.attrs["host_tiles"] == encode.attrs["frames"]
+        assert encode.attrs["device_tiles"] == 0
+        assert encode.attrs["bytes_in"] == side * side * 3 * 4
         assert encode.attrs["bytes_out"] > 0
     assert parts >= 0.95 * sum(sp.duration() for sp in entropy)
     (slide,) = tracer.spans_named("convert.slide")
     assert {k: slide.attrs[k] for k in ("uploads", "dispatches",
-                                        "fetches", "levels")} == {
-        "uploads": 1, "dispatches": 1, "fetches": 3, "levels": 3}
+                                        "fetches", "levels", "host_tiles",
+                                        "device_tiles")} == {
+        "uploads": 1, "dispatches": 1, "fetches": 3, "levels": 3,
+        "host_tiles": 21, "device_tiles": 0}
 
 
 def test_slide_span_counts_only_its_own_transfers():
