@@ -8,6 +8,7 @@ from repro.kernels.ops import (  # noqa: F401
     downsample2x2,
     idct8x8_dequant,
     jpeg_inverse,
+    jpeg_inverse420,
     jpeg_transform,
     rgb2ycbcr,
 )

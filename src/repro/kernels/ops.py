@@ -54,11 +54,13 @@ from repro.kernels import ref
 from repro.kernels.dct8x8_quant import dct8x8_quant_pallas
 from repro.kernels.downsample2x2 import downsample2x2_pallas
 from repro.kernels.jpeg_inverse import jpeg_inverse_pallas
+from repro.kernels.jpeg_inverse420 import jpeg_inverse420_pallas
 from repro.kernels.jpeg_transform import jpeg_transform_pallas
 from repro.kernels.rgb2ycbcr import rgb2ycbcr_pallas
 
 __all__ = ["rgb2ycbcr", "downsample2x2", "dct8x8_quant", "idct8x8_dequant",
-           "jpeg_transform", "jpeg_inverse", "default_mesh", "use_mesh",
+           "jpeg_transform", "jpeg_inverse", "jpeg_inverse420",
+           "default_mesh", "use_mesh",
            "data_sharding"]
 
 
@@ -299,6 +301,40 @@ def jpeg_inverse(coef, qluma=None, qchroma=None, impl: str = "auto"):
     return _batched_call(
         coef, lambda x, mesh: _jpeg_inverse_core(x, qluma, qchroma,
                                                 mode, mesh))
+
+
+@partial(jax.jit, static_argnames=("mode", "mesh"))
+def _jpeg_inverse420_core(y, c, q, mode: str, mesh):
+    def run(y, c, q):
+        H, W = y.shape[1:]
+        h, w = c.shape[2:]
+        # whole-tile blocks: Y's sides tile the lanes and the chroma
+        # planes' sides are multiples of 8 (4:2:0 of a 256-px tile: 128)
+        aligned = _aligned(H, 8) and _aligned(W, 128) and _aligned(h, 8) \
+            and _aligned(w, 128)
+        return _dispatch(
+            mode, aligned, partial(jpeg_inverse420_pallas, y, c, q),
+            lambda: ref.jpeg_inverse420_ref(y, c, q),
+            lambda **kw: ref.jpeg_inverse420_ref(y, c, q))
+    spec = data_sharding(y.shape[0], mesh).spec
+    return jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, P()),
+                         out_specs=spec, check_vma=False)(y, c, q)
+
+
+def jpeg_inverse420(y, c, q, impl: str = "auto"):
+    """Y (N, H, W) and chroma (N, 2, h, w) i32 quantized coefficients of
+    tiles with subsampled chroma, (3, 8, 8) quantisation tables → (N, 3,
+    H, W) f32 RGB samples (integers in [0, 255]).
+
+    The whole-level inverse of a scanner's 4:2:0 tiles (4:2:2 and 4:4:4
+    by the same code): dequantise by the stream's tables, 8×8 iDCT at
+    ``HIGHEST``, triangle-filter chroma upsampling (``ref.upsample_matrix``),
+    YCbCr→RGB, round and clip. The batch is laid out over the ambient
+    mesh's ``data`` axis where it divides. Tiles whose planes the kernel's
+    whole-tile blocks do not tile run the oracle on every platform.
+    """
+    return _jpeg_inverse420_core(y, c, jnp.asarray(q, jnp.float32),
+                                 _mode(impl), default_mesh())
 
 
 @jax.jit

@@ -10,7 +10,8 @@ __all__ = [
     "idct8x8_dequant_ref", "jpeg_transform_ref", "jpeg_inverse_ref",
     "strip_transform", "strip_dct_matrices", "qtable_strip",
     "ycbcr_polynomials", "ycbcr_inverse_polynomials", "dct_matrix",
-    "JPEG_LUMA_Q", "JPEG_CHROMA_Q", "STRIP",
+    "upsample_matrix", "inverse420_operands", "inverse420_planes",
+    "jpeg_inverse420_ref", "JPEG_LUMA_Q", "JPEG_CHROMA_Q", "STRIP",
 ]
 
 # ITU-T81 Annex K quantization tables (quality 50)
@@ -213,3 +214,74 @@ def jpeg_inverse_ref(coef, qluma=None, qchroma=None):
     r, g, b = ycbcr_inverse_polynomials(*planes)
     rgb = jnp.stack([r, g, b], axis=1)
     return jnp.clip(jnp.round(rgb), 0, 255).astype(jnp.uint8)
+
+
+# --------------------------------------------------------------------------
+# inverse transform of streams with subsampled chroma (a scanner's tiles)
+# --------------------------------------------------------------------------
+def upsample_matrix(n_out: int, n_in: int) -> np.ndarray:
+    """(n_out, n_in) chroma upsampling along one axis, ``n_out`` = 2·n_in
+    (or the identity where the axis is not subsampled).
+
+    The triangle filter of centred (JFIF) chroma siting: output sample 2i
+    is ¾ of chroma sample i plus ¼ of sample i−1, output 2i+1 ¾ of i plus
+    ¼ of i+1, replicating at the edge (each tile is its own JPEG image) —
+    libjpeg's h2v2 "fancy" upsampler in float, without its integer rounding
+    bias. The weights are exact in every float format.
+    """
+    if n_out == n_in:
+        return np.eye(n_in, dtype=np.float32)
+    assert n_out == 2 * n_in, (n_out, n_in)
+    m = np.zeros((n_out, n_in), np.float32)
+    i = np.arange(n_in)
+    np.add.at(m, (2 * i, i), 0.75)
+    np.add.at(m, (2 * i, np.maximum(i - 1, 0)), 0.25)
+    np.add.at(m, (2 * i + 1, i), 0.75)
+    np.add.at(m, (2 * i + 1, np.minimum(i + 1, n_in - 1)), 0.25)
+    return m
+
+
+def inverse420_operands(q, H: int, W: int, h: int, w: int) -> tuple:
+    """The constant operands of the subsampled inverse for an H×W tile
+    with h×w chroma planes: dequantisation planes (Y (H, W), chroma (2, h,
+    w)) from the stream's (3, 8, 8) tables ``q``; block-diagonal iDCT
+    matrices (``L·X·R`` inverts every 8×8 block of a plane) for Y and
+    chroma; the vertical and (transposed) horizontal upsampling matrices.
+    """
+    C = dct_matrix()
+
+    def idct(n: int) -> np.ndarray:
+        return np.kron(np.eye(n // 8, dtype=np.float32), C)
+
+    q = jnp.asarray(q, jnp.float32)
+    qy = jnp.tile(q[0], (H // 8, W // 8))
+    qc = jnp.stack([jnp.tile(q[i], (h // 8, w // 8)) for i in (1, 2)])
+    return (qy, qc, np.ascontiguousarray(idct(H).T), idct(W),
+            np.ascontiguousarray(idct(h).T), idct(w),
+            upsample_matrix(H, h), np.ascontiguousarray(
+                upsample_matrix(W, w).T))
+
+
+def inverse420_planes(y, cb, cr, qy, qcb, qcr, ly, ry, lc, rc, uv, uh):
+    """The single copy of the subsampled inverse, shared by the Pallas
+    kernel body (one tile) and the oracle (a batch): dequantise, block
+    iDCT (``strip_transform`` with block-diagonal matrices), upsample each
+    chroma plane vertically then horizontally, YCbCr→RGB, round, clip to
+    [0, 255]. Returns the R, G, B planes as float32."""
+    yp = strip_transform(y.astype(jnp.float32) * qy, ly, ry)
+    chroma = [strip_transform(strip_transform(c.astype(jnp.float32) * qc,
+                                              lc, rc), uv, uh)
+              for c, qc in ((cb, qcb), (cr, qcr))]
+    return [jnp.clip(jnp.round(ch), 0, 255)
+            for ch in ycbcr_inverse_polynomials(yp, *chroma)]
+
+
+def jpeg_inverse420_ref(y, c, q):
+    """Oracle for the subsampled inverse kernel: Y (N, H, W) and chroma
+    (N, 2, h, w) quantized coefficients, (3, 8, 8) tables → (N, 3, H, W)
+    float32 RGB samples, rounded and clipped."""
+    H, W = y.shape[1:]
+    h, w = c.shape[2:]
+    qy, qc, *mats = inverse420_operands(q, H, W, h, w)
+    return jnp.stack(inverse420_planes(y, c[:, 0], c[:, 1], qy, qc[0],
+                                       qc[1], *mats), axis=1)

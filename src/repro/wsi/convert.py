@@ -10,8 +10,15 @@ reader protocol, so identical pixel content produces byte-identical study
 tars regardless of the source container (given the same manifest UIDs) —
 asserted across PSV vs tiled-TIFF in tests and the benchmark.
 
-Three compute paths (see DESIGN.md, "Whole-level batched dispatch" and
-"Kernel roofline & sharding"), all emitting **byte-identical** study tars:
+A container of JPEG tiles (a scanner's SVS) takes the **transcoding**
+engine (``_convert_transcode``, DESIGN.md "Transcoding a scanner's
+JPEG"): its tiles become level 0's frames unchanged, level 0 is decoded
+on the device, and the pipelined engine's pyramid builds levels ≥ 1 from
+it. The container picks the engine; nothing else does.
+
+Otherwise three compute paths (see DESIGN.md, "Whole-level batched
+dispatch" and "Kernel roofline & sharding"), all emitting
+**byte-identical** study tars:
 
 - **pipelined/fused** (default): the device-resident engine. Level-0 tile
   rows are uploaded to the device as the reader inflates them (no full
@@ -73,7 +80,7 @@ import json
 import tarfile
 from collections import Counter
 from contextlib import nullcontext
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import numpy as np
 
@@ -83,6 +90,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import tracing
 from repro.kernels import downsample2x2, jpeg_transform, ops as kernel_ops
+from repro.wsi import entropy_jax, jpeg
 from repro.wsi.dicom import (TS_EXPLICIT_LE, TS_JPEG_BASELINE, new_uid,
                              write_part10)
 from repro.wsi.formats import SlideReader, open_slide
@@ -217,9 +225,11 @@ def _upload_level0(rd: SlideReader, mesh) -> jnp.ndarray:
 
 def _wrap_level(opt: ConvertOptions, li: int, frames: list[bytes], ts: str,
                 tile: int, H: int, W: int, metadata: dict | None,
-                study_uid: str, series_uid: str) -> None:
+                study_uid: str, series_uid: str,
+                photometric: str | None = None) -> None:
     """Wrap one finished level as Part-10 bytes into the manifest."""
     opt.manifest[str(li)] = write_part10(
+        photometric=photometric,
         frames=frames, rows=tile, cols=tile,
         total_rows=H, total_cols=W, transfer_syntax=ts,
         study_uid=study_uid, series_uid=series_uid,
@@ -326,21 +336,38 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
     """
     tile = rd.tile
     dims = _pyramid_dims(rd.H, rd.W, opt.min_level_size)
-    n_levels = len(dims)
-    needed = tuple(li for li in range(n_levels)
+    needed = tuple(li for li in range(len(dims))
                    if str(li) not in opt.manifest)
     if not needed:
-        return n_levels
+        return len(dims)
 
     mesh = kernel_ops.default_mesh()
-    with tracing.span("convert.upload"):
+    with tracing.span("convert.upload") as sp:
         dev = _upload_level0(rd, mesh)
+        if sp is not None:
+            sp.attrs["bytes"] = dev.nbytes
+    _code_pyramid(dev, dims, needed, tile, mesh, opt, metadata, study_uid,
+                  series_uid)
+    return len(dims)
+
+
+def _code_pyramid(dev, dims: list[tuple[int, int]], needed: tuple[int, ...],
+                  tile: int, mesh, opt: ConvertOptions, metadata: dict | None,
+                  study_uid: str, series_uid: str) -> None:
+    """Steps 2 and 3 of the pipelined engine, from level 0's (3, H, W)
+    device planes: the fused pyramid dispatch for the ``needed`` levels,
+    then each level Huffman-coded, wrapped and checkpointed in order."""
+    n_levels = len(dims)
     donate = jax.default_backend() != "cpu"
     with tracing.span("convert.dispatch", levels=len(needed)):
         # async dispatch: the span covers trace/launch, not device time —
         # device work overlaps the per-level entropy spans below
         outs = _pyramid_chain(n_levels, needed, tile, donate, mesh)(dev)
-    del dev  # donated / retired: the chain owns the pixel pyramid now
+    if not dev.is_deleted():
+        # not donated (the CPU; or level 0's transform skipped, so no
+        # output can take its buffer): release it now, not when the
+        # caller's reference goes after every level is coded
+        dev.delete()
     levels = dict(zip(needed, outs))
     del outs  # each level's coefficients are freed once it is coded
 
@@ -369,7 +396,89 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
                             metadata, study_uid, series_uid)
             tracing.add_event(None, "convert.checkpoint", level=li,
                               frames=len(frames))
-    return n_levels
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _tiles_to_plane(tiles, bh: int, bw: int):
+    """(N, 3, T, T) row-major tile batch → (3, bh·T, bw·T) level planes
+    (the inverse of ``_tile_batch``)."""
+    T = tiles.shape[-1]
+    return (tiles.reshape(bh, bw, 3, T, T).transpose(2, 0, 3, 1, 4)
+            .reshape(3, bh * T, bw * T))
+
+
+def _decode_level0(frames: list[bytes], bh: int, bw: int,
+                   mesh) -> tuple[jax.Array, int, int]:
+    """Level 0 of a JPEG-tiled container decoded on the device → its
+    (3, H, W) float32 planes (exact uint8 values, the layout
+    ``_upload_level0`` gives), the bytes uploaded and the blocks decoded.
+
+    The host parses, unstuffs and packs the scans (``decode.parse``) and
+    uploads them compressed (``convert.upload``); the device runs the
+    lockstep entropy decoder (``decode.entropy``: only its error flags come
+    back), integrates the DC terms and de-zigzags, and inverts every tile
+    (``decode.inverse``: ``jpeg_inverse420``, the stream's tables, chroma
+    upsampled). No coefficient or pixel of level 0 comes back.
+    """
+    with tracing.span("decode.parse", frames=len(frames)):
+        H, W, coding, scans = jpeg._parse_batch(frames)
+        _, packed = jpeg._pack_scans(scans, H, W, "jax", coding)
+    with tracing.span("convert.upload") as sp:
+        packed = (*jax.device_put(packed[:4], mesh.devices.flat[0]),
+                  packed[4])
+        nbytes = sum(a.nbytes for a in packed[:4])
+        if sp is not None:
+            sp.attrs["bytes"] = nbytes
+    with tracing.span("decode.entropy", engine="jax"):
+        zzf = entropy_jax.decode_packed(packed, H, W, coding)
+    with tracing.span("decode.inverse"):
+        y, c = entropy_jax.coef_planes(zzf, n=len(frames), H=H, W=W,
+                                       coding=coding)
+        del zzf
+        if mesh.devices.size > 1:
+            y, c = jax.device_put((y, c), NamedSharding(mesh, P()))
+        dev = _tiles_to_plane(
+            kernel_ops.jpeg_inverse420(y, c, coding.qtables()), bh, bw)
+    return dev, nbytes, len(frames) * coding.units(H, W)
+
+
+def _convert_transcode(rd: SlideReader, frames: list[bytes],
+                       metadata: dict | None, opt: ConvertOptions,
+                       study_uid: str, series_uid: str) -> int:
+    """The transcoding engine, for a container of JPEG tiles (a scanner's
+    SVS). Returns the number of levels.
+
+    Level 0's frames are the scanner's tiles with the shared tables merged
+    in — its entropy-coded data unchanged, never re-encoded (that would add
+    a second generation of JPEG loss to every pixel of the archive) — and
+    are wrapped and checkpointed before any device work, as YBR_FULL_422
+    where the chroma is subsampled. Level 0 is then decoded on the device
+    (``convert.decode``: ``frames``, ``bytes_in`` uploaded, ``blocks``) and
+    its planes feed the pipelined engine's pyramid with level 0's own
+    transform skipped; levels ≥ 1 are coded and wrapped as from any other
+    container.
+    """
+    tile = rd.tile
+    dims = _pyramid_dims(rd.H, rd.W, opt.min_level_size)
+    if "0" not in opt.manifest:
+        with tracing.span("convert.wrap"):
+            _wrap_level(opt, 0, frames, TS_JPEG_BASELINE, tile, rd.H, rd.W,
+                        metadata, study_uid, series_uid,
+                        photometric=jpeg.photometric(frames[0]))
+        tracing.add_event(None, "convert.checkpoint", level=0,
+                          frames=len(frames))
+    needed = tuple(li for li in range(1, len(dims))
+                   if str(li) not in opt.manifest)
+    if not needed:
+        return len(dims)
+    mesh = kernel_ops.default_mesh()
+    with tracing.span("convert.decode", frames=len(frames)) as sp:
+        dev, nbytes, blocks = _decode_level0(frames, *rd.grid, mesh)
+        if sp is not None:
+            sp.attrs.update(bytes_in=nbytes, blocks=blocks)
+    _code_pyramid(dev, dims, needed, tile, mesh, opt, metadata, study_uid,
+                  series_uid)
+    return len(dims)
 
 
 def _convert_sync(rd: SlideReader, metadata: dict | None, opt: ConvertOptions,
@@ -462,10 +571,17 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
     study_uid, series_uid = _study_uids(opt)
     ctx = kernel_ops.use_mesh(opt.mesh) if opt.mesh is not None \
         else nullcontext()
+    # the container picks the engine: a scanner's JPEG tiles are kept as
+    # level 0 when the output is JPEG
+    frames = rd.jpeg_frames() if opt.jpeg and hasattr(rd, "jpeg_frames") \
+        else None
     with tracing.span("convert.slide",
                       slide=(metadata or {}).get("slide_id")) as sp:
         with ctx:
-            if opt.pipelined and opt.batched and opt.jpeg:
+            if frames is not None:
+                n_levels = _convert_transcode(rd, frames, metadata, opt,
+                                              study_uid, series_uid)
+            elif opt.pipelined and opt.batched and opt.jpeg:
                 n_levels = _convert_pipelined(rd, metadata, opt, study_uid,
                                               series_uid)
             else:
@@ -480,6 +596,7 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
             sp.attrs.update(levels=n_levels, uploads=n["convert.upload"],
                             dispatches=n["convert.dispatch"],
                             fetches=n["convert.fetch"],
+                            transcoded_frames=len(frames or ()),
                             **_summed(sp, "convert.encode",
                                       ("device_tiles", "host_tiles")))
     return out

@@ -120,8 +120,13 @@ def write_part10(
     instance_number: int = 1,
     patient_id: str = "ANON",
     metadata: dict | None = None,
+    photometric: str | None = None,
 ) -> bytes:
-    """Build one WSM instance (one pyramid level) as Part-10 bytes."""
+    """Build one WSM instance (one pyramid level) as Part-10 bytes.
+
+    ``photometric`` defaults to YBR_FULL for JPEG frames (4:4:4) and RGB
+    for native ones; JPEG frames with subsampled chroma (a scanner's own
+    tiles) are YBR_FULL_422 (PS3.5 §8.2.1)."""
     sop_uid = sop_instance_uid or new_uid()
     encapsulated = transfer_syntax != TS_EXPLICIT_LE
 
@@ -147,8 +152,8 @@ def write_part10(
     ds.put(0x0020, 0x0013, "IS", instance_number)
     ds.put(0x0020, 0x9311, "CS", "TILED_FULL")
     ds.put(0x0028, 0x0002, "US", 3)
-    ds.put(0x0028, 0x0004, "CS",
-           "YBR_FULL" if encapsulated else "RGB")
+    ds.put(0x0028, 0x0004, "CS", photometric
+           or ("YBR_FULL" if encapsulated else "RGB"))
     ds.put(0x0028, 0x0006, "US", 0)
     ds.put(0x0028, 0x0008, "IS", len(frames))
     ds.put(0x0028, 0x0010, "US", rows)
@@ -383,3 +388,22 @@ class Part10Index:
                     raise ValueError(
                         f"corrupt Part-10 stream: frame {i} lacks a JPEG "
                         "SOI marker")
+            self._verify_photometric()
+
+    def _verify_photometric(self) -> None:
+        """JPEG frames are YBR_FULL, or YBR_FULL_422 where their chroma is
+        subsampled (a scanner's own tiles; PS3.5 §8.2.1): the first
+        frame's header says which."""
+        from repro.wsi.jpeg import photometric
+
+        photo = self.get_str(0x0028, 0x0004)
+        if photo is None or not self.frames:
+            return
+        off, ln = self.frames[0]
+        try:
+            want = photometric(self.data[off:off + ln])
+        except ValueError:
+            return  # an undecodable frame is the decoder's to report
+        if photo != want:
+            raise ValueError(f"corrupt Part-10 stream: photometric {photo} "
+                             f"for {want} frames")

@@ -27,6 +27,13 @@ compile would dominate):
   before AC overrun before truncation — all surviving flags are from the
   same step, so the replay is exact).
 
+The MCU's pattern of blocks and the tables each is coded with come from
+the stream (``jpeg.Coding``): static per coding, they are part of the
+compile key, and the LUTs are the stream's own. On the ingest path the
+coefficients stay on the device (``decode_packed``, then
+``coef_planes``: DC integration and de-zigzag by a 64×64 permutation as a
+matmul); only the error flags come back to the host.
+
 Everything runs in int32 (no x64): the ≤16-bit Huffman code and the ≤11
 magnitude bits are each read through a 24-bit window built from a 3-byte
 gather, so bit cursors stay well under 2^31 for any realistic level
@@ -34,14 +41,14 @@ gather, so bit cursors stay well under 2^31 for any realistic level
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["pack_scans", "run_packed"]
+__all__ = ["pack_scans", "decode_packed", "run_packed", "coef_planes"]
 
 _ERR_INVALID, _ERR_RUN, _ERR_TRUNC = 1, 2, 3
 
@@ -52,13 +59,25 @@ _ERR_INVALID, _ERR_RUN, _ERR_TRUNC = 1, 2, 3
 _GUARD = 8
 
 
-@partial(jax.jit, static_argnames=("nu",))
+def _pick(m, rows: tuple[int, ...]):
+    """``rows[m]`` for a static pattern, as selects (no gather)."""
+    out = jnp.full_like(m, rows[-1])
+    for i, r in enumerate(rows[:-1]):
+        if r != rows[-1]:
+            out = jnp.where(m == i, r, out)
+    return out
+
+
+@partial(jax.jit, static_argnames=("nu", "dc_rows", "ac_rows"))
 def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
-              nu: int):
-    """Run all lanes to completion (or first error). Shapes are the compile
-    key: callers pad the lane count and buffer length to powers of two so
-    every level of a slide reuses a handful of cached executables."""
+              nu: int, dc_rows: tuple[int, ...], ac_rows: tuple[int, ...]):
+    """Run all lanes to completion (or first error). Shapes and the MCU's
+    table pattern (``dc_rows``/``ac_rows``: the LUT row of each block of an
+    MCU) are the compile key: callers pad the lane count and buffer length
+    to powers of two so every level of a slide reuses a handful of cached
+    executables."""
     n = pos0.shape[0]
+    upm = len(dc_rows)
     total = n * nu * 64
     base = jnp.arange(n, dtype=jnp.int32) * (nu * 64)
 
@@ -78,7 +97,8 @@ def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
         sh = pos & 7
         code = (w24 >> (8 - sh)) & 0xFFFF
         is_dc = k == 0
-        tbl = jnp.where(is_dc, 0, 2) + ((u % 3) != 0)
+        m = u % upm
+        tbl = jnp.where(is_dc, _pick(m, dc_rows), _pick(m, ac_rows))
         sym = lut_sym[tbl * 65536 + code]
         ln = lut_len[tbl * 65536 + code]
 
@@ -125,38 +145,33 @@ def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
     return err, zzf
 
 
-_TABLES: dict | None = None
-
-
-def _device_tables():
-    """LUTs committed once: the four stacked 16-bit-lookahead Huffman tables
-    (flattened for a single-gather lookup) and the magnitude-decode rows."""
-    global _TABLES
-    if _TABLES is None:
-        from repro.wsi import jpeg
-        _TABLES = {
-            "sym": jnp.asarray(jpeg._LUT_SYM.reshape(-1), jnp.int32),
-            "len": jnp.asarray(jpeg._LUT_LEN.reshape(-1), jnp.int32),
-            "half": jnp.asarray(jpeg._MAG_HALF, jnp.int32),
-            "ext": jnp.asarray(jpeg._MAG_EXT, jnp.int32),
-        }
-    return _TABLES
+@lru_cache(maxsize=32)
+def _device_tables(huff: tuple, device=None) -> tuple:
+    """A stream's Huffman LUTs, flattened for a single-gather lookup, and
+    the magnitude-decode rows, committed once per table set and device."""
+    from repro.wsi import jpeg
+    sym, ln = jpeg._luts(huff)
+    put = partial(jax.device_put, device=device)
+    return (put(jnp.asarray(sym.reshape(-1), jnp.int32)),
+            put(jnp.asarray(ln.reshape(-1), jnp.int32)),
+            put(jnp.asarray(jpeg._MAG_HALF, jnp.int32)),
+            put(jnp.asarray(jpeg._MAG_EXT, jnp.int32)))
 
 
 def _pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def pack_scans(scans: list[np.ndarray], H: int, W: int) -> tuple:
-    """The host half: N unstuffed scans → one guarded, power-of-two padded
-    byte buffer with each lane's start bit, end bit and first unit.
+def pack_scans(scans: list[np.ndarray], nu: int) -> tuple:
+    """The host half: N unstuffed scans of ``nu`` blocks each → one
+    guarded, power-of-two padded byte buffer with each lane's start bit,
+    end bit and first unit.
 
     Lane count and buffer length are padded to powers of two so the jit
     cache stays small; pad lanes start exhausted (``u = nu``) and can
     neither write nor flag errors.
     """
     N = len(scans)
-    nu = (H // 8) * (W // 8) * 3
     npad = _pow2(N)
 
     offs = np.zeros(npad, np.int64)
@@ -175,21 +190,23 @@ def pack_scans(scans: list[np.ndarray], H: int, W: int) -> tuple:
 
     u0 = np.full(npad, nu, np.int32)
     u0[:N] = 0
-    return buf, offs * 8, ends, u0, N
+    return buf, (offs * 8).astype(np.int32), ends.astype(np.int32), u0, N
 
 
-def run_packed(packed: tuple, H: int, W: int) -> np.ndarray:
-    """The device half: the lockstep loop over a packed buffer, its error
-    flags and coefficients fetched back → (N, nb, 3, 64) int32 zigzag
-    coefficients, the DC slots holding differentials."""
+def decode_packed(packed: tuple, H: int, W: int, coding) -> jax.Array:
+    """The device half: the lockstep loop over a packed buffer (host
+    arrays, or ``pack_scans``' arrays already on a device). Only the error
+    flags come back: a corrupt stream raises the numpy engine's
+    ``ValueError``; the coefficients stay on the device as a flat (lanes ·
+    blocks · 64,) int32 array of zigzag coefficients, the DC slots holding
+    differentials."""
     buf, bit0, ends, u0, N = packed
-    nb = (H // 8) * (W // 8)
-    nu = nb * 3
-    t = _device_tables()
-    err, zzf = _lockstep(
-        jnp.asarray(buf), jnp.asarray(bit0, jnp.int32),
-        jnp.asarray(ends, jnp.int32), jnp.asarray(u0),
-        t["sym"], t["len"], t["half"], t["ext"], nu=nu)
+    device = next(iter(buf.devices())) if isinstance(buf, jax.Array) \
+        else None
+    err, zzf = _lockstep(buf, bit0, ends, u0,
+                         *_device_tables(coding.huff, device),
+                         nu=coding.units(H, W), dc_rows=coding.dc_rows,
+                         ac_rows=coding.ac_rows)
     err = np.asarray(err)
     if (err == _ERR_INVALID).any():
         raise ValueError("corrupt JPEG stream: invalid Huffman code")
@@ -197,6 +214,55 @@ def run_packed(packed: tuple, H: int, W: int) -> np.ndarray:
         raise ValueError("corrupt JPEG stream: AC run past end of block")
     if (err == _ERR_TRUNC).any():
         raise ValueError("corrupt JPEG stream: truncated scan data")
+    return zzf
 
-    npad = u0.shape[0]
-    return np.array(zzf).reshape(npad, nu * 64)[:N].reshape(N, nb, 3, 64)
+
+def run_packed(packed: tuple, H: int, W: int, coding) -> np.ndarray:
+    """``decode_packed`` with the coefficients fetched back → (N, mcus,
+    blocks per MCU, 64) int32 zigzag coefficients, DC differentials."""
+    N, npad = packed[4], packed[3].shape[0]
+    upm = len(coding.unit_comps)
+    zzf = np.array(decode_packed(packed, H, W, coding))
+    return zzf.reshape(npad, -1)[:N].reshape(N, -1, upm, 64)
+
+
+def _dezigzag() -> np.ndarray:
+    """P with ``zz @ P`` = the natural-order block of a zigzag one."""
+    from repro.wsi.jpeg import _ZIGZAG
+    p = np.zeros((64, 64), np.float32)
+    p[np.arange(64), _ZIGZAG] = 1.0
+    return p
+
+
+@partial(jax.jit, static_argnames=("n", "H", "W", "coding"))
+def coef_planes(zzf, *, n: int, H: int, W: int, coding):
+    """The lockstep decoder's flat zigzag coefficients (DC differentials)
+    of ``n`` H×W tiles → Y (n, H, W) and chroma (n, 2, h, w) int32
+    coefficient planes, blocks in place, on the device.
+
+    The DC differentials are integrated per component in bitstream order
+    (the predictor resets at every tile: each is its own scan). The
+    de-zigzag is one 64×64 permutation as a matmul at ``HIGHEST``: exact
+    for |coefficient| < 2²⁴, and on this chip a gather or scatter of
+    millions of elements costs far more than a matmul.
+    """
+    upm = len(coding.unit_comps)
+    hm, vm = coding.hmax, coding.vmax
+    mr, mc = H // (8 * vm), W // (8 * hm)
+    zz = zzf.reshape(-1, mr * mc, upm, 64)[:n]
+    dc, first = [], 0
+    for h, v in coding.sampling:
+        k = h * v
+        part = zz[:, :, first:first + k, 0].reshape(n, -1)
+        dc.append(jnp.cumsum(part, axis=1).reshape(n, mr * mc, k))
+        first += k
+    zz = zz.at[..., 0].set(jnp.concatenate(dc, axis=2))
+    nat = jnp.matmul(zz.astype(jnp.float32), _dezigzag(),
+                     precision=jax.lax.Precision.HIGHEST)
+    planes, first = [], 0
+    for h, v in coding.sampling:
+        blk = nat[:, :, first:first + h * v].reshape(n, mr, mc, v, h, 8, 8)
+        planes.append(blk.transpose(0, 1, 3, 5, 2, 4, 6)
+                      .reshape(n, mr * v * 8, mc * h * 8).astype(jnp.int32))
+        first += h * v
+    return planes[0], jnp.stack(planes[1:], axis=1)

@@ -37,6 +37,11 @@ class SlideReader(Protocol):
     TIFF); ``tiles()`` streams them in row-major order. ``metadata`` holds
     whatever vendor key/values the container carries (e.g. the parsed
     Aperio ``ImageDescription``) — empty for formats without any.
+
+    A reader whose container holds JPEG tiles may also offer
+    ``jpeg_frames()``: every tile, row-major, as a complete JPEG stream
+    (``None`` where its tiles are not JPEG). The converter then keeps those
+    frames as level 0 instead of re-encoding decoded pixels.
     """
 
     H: int

@@ -13,13 +13,22 @@ baseline image is carved into fixed-size tiles —
 — which is exactly how Aperio ``.svs`` lays out its pyramid levels (an SVS
 file *is* a tiled TIFF; its vendor metadata rides in ``ImageDescription``
 as ``Aperio …|Key = Value|…`` pairs, which the reader parses into
-``metadata``). The writer emits little-endian by default (what every
-scanner ships) but both byte orders round-trip; the reader accepts either.
+``metadata``). What a scanner writes is JPEG tiles: Compression 7 (TIFF
+Technical Note 2), each tile an abbreviated baseline stream in YCbCr with
+subsampled chroma (``YCbCrSubsampling``, tag 530; 4:2:0 in an SVS), all of
+them sharing the quantisation and Huffman tables of the ``JPEGTables`` tag
+(347); an SVS chains further IFDs after level 0 (a stripped thumbnail,
+reduced levels, label and macro images). The reader walks the chain, serves
+the largest tiled image, and hands JPEG tiles out both as pixels and as
+complete streams. The writer emits Deflate, little-endian by default (what
+every scanner ships) but both byte orders round-trip; the reader accepts
+either.
 
 Unsupported-but-recognizable containers fail with *actionable* errors
-(striped layout, JPEG/LZW compression, non-RGB), and every tile extent is
-bounds-checked against the container at open time so a truncated file is a
-clear ``ValueError`` rather than a mid-conversion explosion.
+(striped layout, BigTIFF, LZW or JPEG 2000 compression, non-RGB), and every
+tile extent is bounds-checked against the container at open time so a
+truncated file is a clear ``ValueError`` rather than a mid-conversion
+explosion.
 """
 from __future__ import annotations
 
@@ -48,11 +57,15 @@ _TILE_WIDTH = 322
 _TILE_LENGTH = 323
 _TILE_OFFSETS = 324
 _TILE_BYTE_COUNTS = 325
+_JPEG_TABLES = 347
+_YCBCR_SUBSAMPLING = 530
 
-_ASCII, _SHORT, _LONG = 2, 3, 4
-_TYPE_SIZE = {1: 1, _ASCII: 1, _SHORT: 2, _LONG: 4}
+_ASCII, _SHORT, _LONG, _UNDEFINED = 2, 3, 4, 7
+_TYPE_SIZE = {1: 1, _ASCII: 1, _SHORT: 2, _LONG: 4, _UNDEFINED: 1}
 
+_PHOTO_YCBCR = 6
 _COMP_NONE = 1
+_COMP_JPEG = 7  # TIFF Technical Note 2 ("new-style" JPEG)
 _COMP_DEFLATE_ADOBE = 8  # what Adobe/Aperio write
 _COMP_DEFLATE_OLD = 32946  # the original libtiff Deflate code
 _DEFLATE = (_COMP_DEFLATE_ADOBE, _COMP_DEFLATE_OLD)
@@ -177,10 +190,19 @@ def _parse_description(desc: str) -> dict:
 class TiffSlideReader:
     """Streaming tile reader over a classic tiled TIFF/SVS container.
 
-    Indexes the first IFD once (both byte orders accepted), validates the
-    layout it can serve — tiled, 8-bit chunky RGB, Deflate or uncompressed
-    — with actionable errors for everything else, bounds-checks every tile
-    extent against the container size, and inflates tiles on demand.
+    Walks the IFD chain (both byte orders accepted) and serves the largest
+    tiled image, which is a scanner's full-resolution level: an SVS file
+    also holds a stripped thumbnail, reduced levels, a label and a macro
+    image. It validates the layout it can serve — tiled, 8-bit, 3 samples,
+    chunky; Deflate or uncompressed RGB, or JPEG (Compression 7, TIFF
+    Technical Note 2) whose tiles share the ``JPEGTables`` — with
+    actionable errors for everything else, bounds-checks every tile extent
+    against the container size, and inflates or decodes tiles on demand.
+
+    A JPEG-tiled image is also handed out as it is stored:
+    ``jpeg_frames()`` gives every tile as a complete interchange stream
+    (the shared tables merged in, the entropy-coded data untouched),
+    ``chroma_subsampling`` the (h, v) factors of its luma.
     """
 
     def __init__(self, data: bytes):
@@ -200,7 +222,13 @@ class TiffSlideReader:
             raise ValueError(
                 f"unsupported TIFF: magic {magic} (classic TIFF is 42; "
                 "BigTIFF (43) is not supported)")
-        tags = self._read_ifd(data, ifd_off)
+        ifds = self._read_chain(data, ifd_off)
+        if not ifds:
+            raise ValueError("corrupt TIFF: no image file directory")
+        tiled = [t for t in ifds if _TILE_OFFSETS in t and _TILE_WIDTH in t
+                 and _IMAGE_WIDTH in t and _IMAGE_LENGTH in t]
+        tags = max(tiled, key=lambda t: int(t[_IMAGE_WIDTH][0])
+                   * int(t[_IMAGE_LENGTH][0])) if tiled else ifds[0]
 
         if _IMAGE_WIDTH not in tags or _IMAGE_LENGTH not in tags:
             raise ValueError("corrupt TIFF: missing ImageWidth/ImageLength")
@@ -228,21 +256,26 @@ class TiffSlideReader:
         self.tile = tw
 
         comp = int(tags.get(_COMPRESSION, [_COMP_NONE])[0])
-        if comp not in (_COMP_NONE, *_DEFLATE):
+        if comp not in (_COMP_NONE, _COMP_JPEG, *_DEFLATE):
             name = _COMP_NAMES.get(comp, f"code {comp}")
             raise ValueError(
                 f"unsupported TIFF compression: {name} — this reader "
-                "handles Deflate (8/32946) and uncompressed (1); "
+                "handles Deflate (8/32946), JPEG (7) and uncompressed (1); "
                 "re-encode the slide with Deflate tiles")
         self._comp = comp
         photo = int(tags.get(_PHOTOMETRIC, [2])[0])
         spp = int(tags.get(_SAMPLES_PER_PIXEL, [1])[0])
         bps = [int(b) for b in tags.get(_BITS_PER_SAMPLE, [8])]
-        if photo != 2 or spp != 3 or any(b != 8 for b in bps):
+        # a JPEG tile's colour space is its stream's (as libjpeg reads it):
+        # scanners write Photometric 2 or 6 over the same YCbCr tiles
+        photos = (2, _PHOTO_YCBCR) if comp == _COMP_JPEG else (2,)
+        if photo not in photos or spp != 3 or any(b != 8 for b in bps):
+            need = ("JPEG tiles of 3 samples of 8 bits, photometric 2 or 6"
+                    if comp == _COMP_JPEG else "8-bit chunky RGB "
+                    "(photometric 2, 3 samples of 8 bits)")
             raise ValueError(
                 f"unsupported TIFF: photometric={photo} samples={spp} "
-                f"bits={bps} — need 8-bit chunky RGB (photometric 2, "
-                "3 samples of 8 bits)")
+                f"bits={bps} — need {need}")
         if int(tags.get(_PLANAR_CONFIG, [1])[0]) != 1:
             raise ValueError("unsupported TIFF: planar (separate-plane) "
                              "configuration — need chunky RGB")
@@ -262,8 +295,41 @@ class TiffSlideReader:
         self._offsets, self._counts = offsets, counts
         self._data = data
         self.metadata = _parse_description(tags.get(_IMAGE_DESCRIPTION, ""))
+        self.jpeg_tables = None
+        self.chroma_subsampling = None
+        if comp == _COMP_JPEG:
+            self._check_jpeg(tags, photo)
 
-    def _read_ifd(self, data: bytes, off: int) -> dict:
+    def _check_jpeg(self, tags: dict, photo: int) -> None:
+        """The shared tables, and the first tile's stream against the
+        TIFF's own account of it (``YCbCrSubsampling``, the tile size)."""
+        from repro.wsi.jpeg import _parse_stream, merge_tables
+
+        tables = tags.get(_JPEG_TABLES, b"")
+        self.jpeg_tables = bytes(tables)
+        H, W, _, _, coding = _parse_stream(
+            merge_tables(self._raw(0), self.jpeg_tables))
+        if (H, W) != (self.tile, self.tile):
+            raise ValueError(f"corrupt TIFF: JPEG tile of {H}x{W} in a "
+                             f"{self.tile}-px tile grid")
+        self.chroma_subsampling = coding.sampling[0]
+        if photo == _PHOTO_YCBCR:
+            want = tuple(int(f) for f in tags.get(_YCBCR_SUBSAMPLING, [2, 2]))
+            if want != self.chroma_subsampling:
+                raise ValueError(
+                    f"corrupt TIFF: YCbCrSubsampling {want} but the JPEG "
+                    f"tiles are sampled {self.chroma_subsampling}")
+
+    def _read_chain(self, data: bytes, off: int) -> list[dict]:
+        """Every IFD of the chain, in file order (a loop ends the walk)."""
+        ifds, seen = [], set()
+        while off and off not in seen:
+            seen.add(off)
+            tags, off = self._read_ifd(data, off)
+            ifds.append(tags)
+        return ifds
+
+    def _read_ifd(self, data: bytes, off: int) -> tuple[dict, int]:
         e = self._e
         if off + 2 > len(data):
             raise ValueError(
@@ -291,29 +357,50 @@ class TiffSlideReader:
             if typ == _ASCII:
                 tags[tag] = data[pos:pos + count].split(b"\0")[0] \
                     .decode("latin-1")
+            elif typ == _UNDEFINED:
+                tags[tag] = data[pos:pos + count]
             else:
                 fmt = {1: "B", _SHORT: "H", _LONG: "I"}[typ]
                 tags[tag] = list(struct.unpack_from(f"{e}{count}{fmt}",
                                                     data, pos))
-        return tags
+        (nxt,) = struct.unpack_from(e + "I", data, off + 2 + 12 * n)
+        return tags, nxt
 
     @property
     def grid(self) -> tuple[int, int]:
         return _grid(self.H, self.W, self.tile)
+
+    def _raw(self, i: int) -> bytes:
+        return self._data[self._offsets[i]:self._offsets[i] + self._counts[i]]
+
+    def jpeg_frames(self) -> list[bytes] | None:
+        """Every tile, row-major, as a complete JPEG interchange stream
+        (``JPEGTables`` merged in, entropy-coded data as stored); ``None``
+        for a container whose tiles are not JPEG."""
+        if self._comp != _COMP_JPEG:
+            return None
+        from repro.wsi.jpeg import merge_tables
+
+        return [merge_tables(self._raw(i), self.jpeg_tables)
+                for i in range(len(self._offsets))]
 
     def read_tile(self, r: int, c: int) -> np.ndarray:
         bh, bw = self.grid
         if not (0 <= r < bh and 0 <= c < bw):
             raise KeyError((r, c))
         i = r * bw + c
-        raw = self._data[self._offsets[i]:self._offsets[i] + self._counts[i]]
+        raw = self._raw(i)
+        t = self.tile
+        if self._comp == _COMP_JPEG:
+            from repro.wsi.jpeg import decode_tile, merge_tables
+
+            return decode_tile(merge_tables(raw, self.jpeg_tables))
         if self._comp in _DEFLATE:
             try:
                 raw = zlib.decompress(raw)
             except zlib.error as exc:
                 raise ValueError(f"corrupt TIFF tile ({r},{c}): {exc}") \
                     from None
-        t = self.tile
         if len(raw) != t * t * 3:
             raise ValueError(
                 f"corrupt TIFF tile ({r},{c}): {len(raw)} bytes after "
@@ -329,10 +416,10 @@ class TiffSlideReader:
 
 TIFF_FORMAT = SlideFormat(
     name="tiff",
-    description="classic tiled TIFF / SVS (Deflate RGB tiles)",
+    description="classic tiled TIFF / SVS (Deflate RGB or JPEG tiles)",
     extensions=(".tiff", ".tif", ".svs"),
     # match on the byte-order mark alone so recognizable-but-unsupported
-    # variants (BigTIFF, striped, JPEG-compressed) reach the reader's
+    # variants (BigTIFF, striped, JPEG 2000) reach the reader's
     # *specific* error instead of the generic unknown-container one
     matches=lambda data: bytes(data[:2]) in (b"II", b"MM"),
     reader=TiffSlideReader,

@@ -40,10 +40,15 @@ export subsystem's compute spine run in reverse:
   coefficient level (the bitstream is lossless; only quantization loses
   information).
 
-Produces/consumes real JFIF bytes (SOI/APP0/DQT/SOF0/DHT/SOS/EOI, standard
-Annex-K tables, 4:4:4, byte stuffing). Truncated or garbage input raises
-``ValueError("corrupt JPEG …")`` from every decode entry point — that
-string is what the export service turns into an actionable DLQ reason.
+Produces real JFIF bytes (SOI/APP0/DQT/SOF0/DHT/SOS/EOI, standard Annex-K
+tables, 4:4:4, byte stuffing). Consumes any baseline stream whose tables
+and sampling factors it reads from the stream itself (``Coding``): its own
+4:4:4 frames, and a scanner's 4:2:0 tiles once the shared ``JPEGTables``
+of their TIFF container are merged in (``merge_tables``). Truncated or
+garbage input raises ``ValueError("corrupt JPEG …")`` from every decode
+entry point — that string is what the export service turns into an
+actionable DLQ reason; streams outside the baseline subset it reads raise
+``ValueError("unsupported JPEG …")``.
 
 Both encoder paths are thread-safe (the zigzag gather-index cache is the
 only module-level mutable state and is lock-protected), and the heavy numpy
@@ -52,7 +57,9 @@ entropy-codes several slides' levels in parallel worker threads.
 """
 from __future__ import annotations
 
+import dataclasses
 import struct
+from functools import lru_cache
 
 import numpy as np
 
@@ -60,14 +67,15 @@ import jax
 
 from repro.analysis.lockdep import TrackedLock
 from repro.core import tracing
-from repro.kernels import (dct8x8_quant, jpeg_inverse, jpeg_transform,
-                           rgb2ycbcr)
+from repro.kernels import (dct8x8_quant, jpeg_inverse, jpeg_inverse420,
+                           jpeg_transform, rgb2ycbcr)
 from repro.kernels.ref import JPEG_CHROMA_Q, JPEG_LUMA_Q
 from repro.wsi.dicom import TS_EXPLICIT_LE, TS_JPEG_BASELINE
 
 __all__ = ["encode_tile", "encode_tiles_batch", "encode_coef_batch",
            "decode_tile", "decode_tiles_batch", "decode_coef_batch",
-           "decode_frames", "psnr"]
+           "decode_components", "decode_frames", "merge_tables",
+           "photometric", "psnr"]
 
 # --------------------------------------------------------------------------
 # Annex-K Huffman tables
@@ -138,9 +146,74 @@ _ENC = {
     ("ac", 0): _build_codes(_AC_L_BITS, _AC_L_VALS),
     ("ac", 1): _build_codes(_AC_C_BITS, _AC_C_VALS),
 }
-_DEC = {
-    k: {v: sym for sym, v in table.items()} for k, table in _ENC.items()
-}
+
+
+@dataclasses.dataclass(frozen=True)
+class Coding:
+    """What a baseline stream's headers fix for its scan.
+
+    ``sampling`` is each component's (h, v) in scan order (Y at most 2×2,
+    chroma 1×1), ``q`` its quantisation table in natural order, ``dc`` and
+    ``ac`` the row of ``huff`` — (BITS, HUFFVAL) pairs — it is coded with.
+    Hashable: the MCU's pattern of blocks and tables is static per
+    coding, so it keys the jitted decoder's compile.
+    """
+
+    sampling: tuple[tuple[int, int], ...]
+    q: tuple[tuple[int, ...], ...]
+    dc: tuple[int, ...]
+    ac: tuple[int, ...]
+    huff: tuple[tuple[bytes, bytes], ...]
+
+    @property
+    def hmax(self) -> int:
+        return max(h for h, _ in self.sampling)
+
+    @property
+    def vmax(self) -> int:
+        return max(v for _, v in self.sampling)
+
+    @property
+    def subsampled(self) -> bool:
+        return self.hmax * self.vmax > 1
+
+    @property
+    def unit_comps(self) -> tuple[int, ...]:
+        """The component of each block of an MCU, in bitstream order
+        (4:2:0: Y0 Y1 Y2 Y3 Cb Cr)."""
+        return tuple(c for c, (h, v) in enumerate(self.sampling)
+                     for _ in range(h * v))
+
+    @property
+    def dc_rows(self) -> tuple[int, ...]:
+        return tuple(self.dc[c] for c in self.unit_comps)
+
+    @property
+    def ac_rows(self) -> tuple[int, ...]:
+        return tuple(self.ac[c] for c in self.unit_comps)
+
+    def units(self, H: int, W: int) -> int:
+        """Blocks of an H×W tile, every component's."""
+        mcus = (H // (8 * self.vmax)) * (W // (8 * self.hmax))
+        return mcus * len(self.unit_comps)
+
+    def qtables(self) -> np.ndarray:
+        """(3, 8, 8) float32 quantisation tables, one per component."""
+        return np.array(self.q, np.float32).reshape(3, 8, 8)
+
+
+def _table(bits, vals) -> tuple[bytes, bytes]:
+    return bytes(bits), bytes(vals)
+
+
+#: what this module's own encoder writes: 4:4:4, Annex K tables
+_STANDARD = Coding(
+    sampling=((1, 1),) * 3,
+    q=tuple(tuple(int(v) for v in t.reshape(64))
+            for t in (JPEG_LUMA_Q, JPEG_CHROMA_Q, JPEG_CHROMA_Q)),
+    dc=(0, 1, 1), ac=(2, 3, 3),
+    huff=(_table(_DC_L_BITS, _DC_L_VALS), _table(_DC_C_BITS, _DC_C_VALS),
+          _table(_AC_L_BITS, _AC_L_VALS), _table(_AC_C_BITS, _AC_C_VALS)))
 
 
 class _BitWriter:
@@ -461,45 +534,55 @@ def _entropy_encode_batch(coef: np.ndarray) -> list[bytes]:
     return _pack_bits_tiled(codes[order], lens[order], tile_ids, N)
 
 
-def _decode_blocks(br: _BitReader, H: int, W: int) -> list[np.ndarray]:
-    bh, bwid = H // 8, W // 8
-    out = [np.zeros((bh, bwid, 64), np.int32) for _ in range(3)]
-    pred = [0, 0, 0]
+def _decode_blocks(br: _BitReader, H: int, W: int,
+                   coding: "Coding") -> list[np.ndarray]:
+    """The per-symbol reference decode of one scan → one coefficient plane
+    per component (blocks in place; chroma planes smaller where sampled)."""
+    hm, vm = coding.hmax, coding.vmax
+    mr, mc = H // (8 * vm), W // (8 * hm)
+    decs = _dec_tables(coding.huff)
+    out = [np.zeros((mr * v, mc * h, 64), np.int32)
+           for h, v in coding.sampling]
+    pred = [0] * len(coding.sampling)
     inv_zz = np.argsort(_ZIGZAG)
-    for r in range(bh):
-        for c in range(bwid):
-            for comp in range(3):
-                tid = 0 if comp == 0 else 1
-                blk = np.zeros(64, np.int32)
-                s = br.huff(_DEC[("dc", tid)])
-                diff = 0
-                if s:
-                    bits = br.get(s)
-                    diff = bits if bits >= (1 << (s - 1)) else bits - (1 << s) + 1
-                pred[comp] += diff
-                blk[0] = pred[comp]
-                k = 1
-                while k < 64:
-                    sym = br.huff(_DEC[("ac", tid)])
-                    if sym == 0x00:
-                        break
-                    run, s = sym >> 4, sym & 0xF
-                    if sym == 0xF0:
-                        k += 16
-                        continue
-                    k += run
-                    if k > 63:
-                        raise ValueError(
-                            "corrupt JPEG stream: AC run past end of block")
-                    bits = br.get(s)
-                    v = bits if bits >= (1 << (s - 1)) else bits - (1 << s) + 1
-                    blk[k] = v
-                    k += 1
-                out[comp][r, c] = blk
+    for r in range(mr):
+        for c in range(mc):
+            for comp, (h, v) in enumerate(coding.sampling):
+                dc_tab, ac_tab = decs[coding.dc[comp]], decs[coding.ac[comp]]
+                for k in range(h * v):
+                    blk = np.zeros(64, np.int32)
+                    s = br.huff(dc_tab)
+                    diff = 0
+                    if s:
+                        bits = br.get(s)
+                        diff = bits if bits >= (1 << (s - 1)) \
+                            else bits - (1 << s) + 1
+                    pred[comp] += diff
+                    blk[0] = pred[comp]
+                    z = 1
+                    while z < 64:
+                        sym = br.huff(ac_tab)
+                        if sym == 0x00:
+                            break
+                        run, s = sym >> 4, sym & 0xF
+                        if sym == 0xF0:
+                            z += 16
+                            continue
+                        z += run
+                        if z > 63:
+                            raise ValueError(
+                                "corrupt JPEG stream: AC run past end of "
+                                "block")
+                        bits = br.get(s)
+                        blk[z] = bits if bits >= (1 << (s - 1)) \
+                            else bits - (1 << s) + 1
+                        z += 1
+                    out[comp][r * v + k // h, c * h + k % h] = blk
     planes = []
-    for comp in range(3):
-        zz = out[comp][:, :, inv_zz].reshape(bh, bwid, 8, 8)
-        planes.append(zz.transpose(0, 2, 1, 3).reshape(H, W))
+    for blocks in out:
+        bh, bwid = blocks.shape[:2]
+        zz = blocks[:, :, inv_zz].reshape(bh, bwid, 8, 8)
+        planes.append(zz.transpose(0, 2, 1, 3).reshape(bh * 8, bwid * 8))
     return planes
 
 
@@ -518,13 +601,22 @@ def _huff_lut(table: dict) -> tuple[np.ndarray, np.ndarray]:
         ln[lo:lo + (1 << (16 - length))] = length
     return sym, ln
 
-# stacked [dc-luma, dc-chroma, ac-luma, ac-chroma]: the lockstep decoder
-# selects a row per tile from its (DC/AC phase, component) state
-_LUTS = [_huff_lut(_ENC[(kind, tid)])
-         for kind in ("dc", "ac") for tid in (0, 1)]
-_LUT_SYM = np.stack([s for s, _ in _LUTS])
-_LUT_LEN = np.stack([ln for _, ln in _LUTS])
-del _LUTS
+
+@lru_cache(maxsize=32)
+def _luts(huff: tuple) -> tuple[np.ndarray, np.ndarray]:
+    """A stream's Huffman tables stacked as 16-bit lookahead LUTs, one row
+    per table of ``Coding.huff``: (symbols, code lengths), (R, 65536)."""
+    luts = [_huff_lut(_build_codes(list(bits), list(vals)))
+            for bits, vals in huff]
+    return (np.stack([s for s, _ in luts]), np.stack([n for _, n in luts]))
+
+
+@lru_cache(maxsize=32)
+def _dec_tables(huff: tuple) -> list[dict]:
+    """The per-symbol decoder's tables: (code, length) → symbol per row."""
+    return [{v: sym for sym, v in _build_codes(list(bits), list(vals))
+             .items()} for bits, vals in huff]
+
 
 # magnitude decode, tabulated per category s: value = bits if bits ≥ 2^(s-1)
 # else bits - (2^s - 1)   (s = 0 ⇒ no bits, value 0)
@@ -577,14 +669,17 @@ _JAX_MAX_BYTES = 1 << 27
 
 
 def _pack_scans(scans: list[np.ndarray], H: int, W: int,
-                engine: str = "auto") -> tuple[str, tuple]:
+                engine: str = "auto", coding: "Coding | None" = None
+                ) -> tuple[str, tuple]:
     """The host half of a lockstep decode over N independent scans: the
     engine that decodes them, and the scans laid out for it.
 
     Every tile of a level is its own bitstream (one scan per tile, DC
     predictors reset at tile boundaries), which is the vectorization axis
     the sequential Huffman dependency cannot remove *within* a stream: all
-    N tiles advance one symbol per step. Two engines run the identical
+    N tiles advance one symbol per step. ``coding`` (default: this
+    module's own 4:4:4 Annex-K streams) fixes the MCU's blocks and the
+    tables each one is coded with. Two engines run the identical
     automaton (coefficient-exact, same error strings — differentially
     tested):
 
@@ -605,13 +700,14 @@ def _pack_scans(scans: list[np.ndarray], H: int, W: int,
     if engine not in ("auto", "numpy", "jax"):
         raise ValueError(f"engine must be 'auto', 'numpy' or 'jax': "
                          f"{engine!r}")
-    nu = (H // 8) * (W // 8) * 3
+    coding = coding or _STANDARD
+    nu = coding.units(H, W)
     total_bytes = sum(s.size for s in scans)
     if engine == "jax" or (engine == "auto"
                            and len(scans) * nu >= _JAX_MIN_UNITS
                            and total_bytes < _JAX_MAX_BYTES):
         from repro.wsi.entropy_jax import pack_scans
-        return "jax", pack_scans(scans, H, W)
+        return "jax", pack_scans(scans, nu)
     N = len(scans)
     offs = np.zeros(N, np.int64)
     ends = np.zeros(N, np.int64)  # exclusive bit end of each tile's stream
@@ -624,26 +720,32 @@ def _pack_scans(scans: list[np.ndarray], H: int, W: int,
     return "numpy", (_window64(np.concatenate(parts)), offs, ends)
 
 
-def _run_packed(engine: str, packed: tuple, H: int, W: int) -> np.ndarray:
-    """The lockstep decode of ``_pack_scans``' result → (N, nb, 3, 64)
-    int32 zigzag coefficients, exactly the symbols the per-tile reference
-    loop decodes, with the DC slots holding differentials
-    (``_coef_planes`` integrates them)."""
+def _run_packed(engine: str, packed: tuple, H: int, W: int,
+                coding: "Coding | None" = None) -> np.ndarray:
+    """The lockstep decode of ``_pack_scans``' result → (N, mcus,
+    blocks per MCU, 64) int32 zigzag coefficients — for 4:4:4 streams
+    (N, nb, 3, 64) — exactly the symbols the per-tile reference loop
+    decodes, with the DC slots holding differentials (``_coef_planes``
+    integrates them)."""
+    coding = coding or _STANDARD
     if engine == "jax":
         from repro.wsi.entropy_jax import run_packed
-        return run_packed(packed, H, W)
+        return run_packed(packed, H, W, coding)
     w64, offs, ends = packed
     N = offs.size
-    nb = (H // 8) * (W // 8)
-    nu = nb * 3  # block-component units per tile, in bitstream order
+    nu = coding.units(H, W)  # blocks per tile, in bitstream order
+    upm = len(coding.unit_comps)
+    lut_sym, lut_len = _luts(coding.huff)
 
     pos = offs * 8
-    u = np.zeros(N, np.int64)  # unit index: block * 3 + component
+    u = np.zeros(N, np.int64)  # unit index: MCU * upm + block of the MCU
     k = np.zeros(N, np.int64)  # next zigzag slot; 0 ⇒ the DC symbol is next
-    zzf = np.zeros(N * nu * 64, np.int32)  # flat (tile, block, comp, slot)
+    zzf = np.zeros(N * nu * 64, np.int32)  # flat (tile, unit, slot)
     base = np.arange(N, dtype=np.int64) * (nu * 64)
     active = u < nu
-    chroma = (np.arange(nu + 1) % 3 > 0).astype(np.int64)  # unit → table
+    # unit → the LUT row of its DC and of its AC table
+    dc_row = np.array(coding.dc_rows, np.int64)[np.arange(nu + 1) % upm]
+    ac_row = np.array(coding.ac_rows, np.int64)[np.arange(nu + 1) % upm]
     _c48, _c64 = np.uint64(48), np.uint64(64)
     _m16, _one = np.uint64(0xFFFF), np.uint64(1)
 
@@ -652,9 +754,9 @@ def _run_packed(engine: str, packed: tuple, H: int, W: int) -> np.ndarray:
         sh = (pos & 7).astype(np.uint64)
         code = ((w >> (_c48 - sh)) & _m16).astype(np.int64)
         is_dc = k == 0
-        tbl = np.where(is_dc, 0, 2) + chroma[u]
-        sym = _LUT_SYM[tbl, code]
-        ln = _LUT_LEN[tbl, code]
+        tbl = np.where(is_dc, dc_row[u], ac_row[u])
+        sym = lut_sym[tbl, code]
+        ln = lut_len[tbl, code]
         # EOB (0x00) and ZRL (0xF0) have zero magnitude bits by construction
         s = np.where(is_dc, sym, sym & 0xF)
         su = s.astype(np.uint64)
@@ -693,12 +795,12 @@ def _run_packed(engine: str, packed: tuple, H: int, W: int) -> np.ndarray:
         if (active & (pos > ends)).any():
             raise ValueError("corrupt JPEG stream: truncated scan data")
 
-    return zzf.reshape(N, nb, 3, 64)
+    return zzf.reshape(N, nu // upm, upm, 64)
 
 
 def _coef_planes(zz: np.ndarray, H: int, W: int) -> np.ndarray:
-    """(N, nb, 3, 64) zigzag coefficients, DC slots holding differentials
-    → (N, 3, H, W) coefficient planes."""
+    """(N, nb, 3, 64) zigzag coefficients of 4:4:4 streams, DC slots
+    holding differentials → (N, 3, H, W) coefficient planes."""
     N, nb = zz.shape[:2]
     # integrate the DC differentials (predictor resets at tile boundaries)
     zz[:, :, :, 0] = np.cumsum(zz[:, :, :, 0], axis=1)
@@ -709,24 +811,35 @@ def _coef_planes(zz: np.ndarray, H: int, W: int) -> np.ndarray:
     return out.reshape(N, 3, H, W)
 
 
-def _parse_jfif(jpg: bytes) -> tuple[int, int, int, int]:
-    """Parse one tile's JFIF container → (H, W, scan start, scan end).
+_SOI, _EOI = b"\xff\xd8", b"\xff\xd9"
+#: frame markers of every process but baseline/extended Huffman (SOF0/1)
+_OTHER_SOF = frozenset({0xC2, 0xC3, 0xC5, 0xC6, 0xC7, 0xC9, 0xCA, 0xCB,
+                        0xCD, 0xCE, 0xCF})
 
-    Accepts what ``encode_tile``/``encode_coef_batch`` emit (baseline,
-    4:4:4, standard tables), plus DICOM's even-length convention of one
-    trailing 0x00 pad byte after the EOI marker (encapsulated fragments).
-    Truncated or malformed containers raise ``ValueError("corrupt JPEG
-    …")`` — never ``IndexError``/``struct.error``.
+
+def _parse_stream(jpg: bytes) -> tuple[int, int, int, int, Coding]:
+    """Parse one tile's interchange stream → (H, W, scan start, scan end,
+    coding).
+
+    Tables, sampling factors and the colour space come from the stream
+    (a tile of a TIFF/SVS container once its shared ``JPEGTables`` are
+    merged in, ``merge_tables``): baseline Huffman, 8-bit, three
+    components, Y sampled at most 2×2 and chroma 1×1, one interleaved scan.
+    Accepts DICOM's even-length convention of one trailing 0x00 pad byte
+    after the EOI marker (encapsulated fragments). Truncated or malformed
+    containers raise ``ValueError("corrupt JPEG …")``, streams outside
+    that subset ``ValueError("unsupported JPEG …")`` — never
+    ``IndexError``/``struct.error``. The header up to the scan is parsed
+    once per distinct header (every tile of a level shares one).
     """
-    if len(jpg) < 4 or jpg[:2] != b"\xff\xd8":
+    if len(jpg) < 4 or jpg[:2] != _SOI:
         raise ValueError("corrupt JPEG stream: missing SOI marker")
     end = len(jpg)
-    if jpg[end - 1] == 0x00 and jpg[end - 3:end - 1] == b"\xff\xd9":
+    if jpg[end - 1] == 0x00 and jpg[end - 3:end - 1] == _EOI:
         end -= 1  # DICOM even-length fragment pad
-    if jpg[end - 2:end] != b"\xff\xd9":
+    if jpg[end - 2:end] != _EOI:
         raise ValueError("corrupt JPEG stream: missing EOI marker")
     pos = 0
-    H = W = None
     while pos + 2 <= end:
         if jpg[pos] != 0xFF:
             raise ValueError(
@@ -741,67 +854,281 @@ def _parse_jfif(jpg: bytes) -> tuple[int, int, int, int]:
         if ln < 2 or pos + ln > end:
             raise ValueError(
                 "corrupt JPEG stream: marker segment overruns container")
-        if code == 0xC0:
-            if ln < 9:
-                raise ValueError("corrupt JPEG stream: short SOF segment")
-            _, H, W, _ = struct.unpack_from(">BHHB", jpg, pos + 2)
-            if not H or not W or H % 8 or W % 8:
-                raise ValueError(
-                    f"corrupt JPEG stream: unsupported frame size {H}x{W}")
-        if code == 0xDA:
-            if H is None:
-                raise ValueError("corrupt JPEG stream: SOS before SOF")
-            start = pos + ln
-            if start > end - 2:
-                raise ValueError("corrupt JPEG stream: no scan data")
-            return H, W, start, end - 2
         pos += ln
+        if code == 0xDA:
+            if pos > end - 2:
+                raise ValueError("corrupt JPEG stream: no scan data")
+            H, W, coding = _header(bytes(jpg[:pos]))
+            return H, W, pos, end - 2, coding
     raise ValueError("corrupt JPEG stream: no SOS marker")
 
 
-def decode_coef_batch(jpgs: list[bytes]) -> np.ndarray:
-    """N baseline JFIF tiles → (N, 3, H, W) int32 quantized coefficients.
+@lru_cache(maxsize=64)
+def _header(head: bytes) -> tuple[int, int, Coding]:
+    """SOI … SOS of a structurally sound stream → (H, W, coding)."""
+    qt: dict[int, tuple[int, ...]] = {}
+    ht: dict[tuple[int, int], tuple[bytes, bytes]] = {}
+    frame = scan = adobe = None
+    jfif = False
+    pos = 2
+    while pos < len(head):
+        code = head[pos + 1]
+        if code in (0xD8, 0xD9):
+            pos += 2
+            continue
+        ln = struct.unpack_from(">H", head, pos + 2)[0]
+        seg = head[pos + 4:pos + 2 + ln]
+        pos += 2 + ln
+        if code in (0xC0, 0xC1):
+            if ln < 9:
+                raise ValueError("corrupt JPEG stream: short SOF segment")
+            frame = seg
+        elif code in _OTHER_SOF:
+            raise ValueError(
+                f"unsupported JPEG stream: SOF{code - 0xC0} (progressive, "
+                "lossless, hierarchical or arithmetic coding) — baseline "
+                "Huffman only")
+        elif code == 0xDB:
+            p = 0
+            while p < len(seg):
+                if seg[p] >> 4:
+                    raise ValueError("unsupported JPEG stream: 16-bit "
+                                     "quantisation table")
+                if p + 65 > len(seg):
+                    raise ValueError("corrupt JPEG stream: short DQT segment")
+                nat = np.zeros(64, np.int64)
+                nat[_ZIGZAG] = np.frombuffer(seg, np.uint8, 64, p + 1)
+                qt[seg[p] & 15] = tuple(int(x) for x in nat)
+                p += 65
+        elif code == 0xC4:
+            p = 0
+            while p < len(seg):
+                if p + 17 > len(seg):
+                    raise ValueError("corrupt JPEG stream: short DHT segment")
+                bits = bytes(seg[p + 1:p + 17])
+                n = sum(bits)
+                vals = bytes(seg[p + 17:p + 17 + n])
+                if len(vals) != n or _code_overflow(bits):
+                    raise ValueError("corrupt JPEG stream: bad Huffman table")
+                cls = seg[p] >> 4
+                if any(v > 11 for v in vals) if cls == 0 else \
+                        any((v & 15) > 10 for v in vals):
+                    raise ValueError("corrupt JPEG stream: Huffman symbol "
+                                     "out of the baseline range")
+                ht[cls, seg[p] & 15] = (bits, vals)
+                p += 17 + n
+        elif code == 0xDD:
+            if len(seg) >= 2 and struct.unpack_from(">H", seg)[0]:
+                raise ValueError("unsupported JPEG stream: restart "
+                                 "intervals")
+        elif code == 0xE0 and seg[:5] == b"JFIF\0":
+            jfif = True
+        elif code == 0xEE and seg[:5] == b"Adobe" and len(seg) >= 12:
+            adobe = seg[11]
+        elif code == 0xDA:
+            scan = seg
+    if frame is None:
+        raise ValueError("corrupt JPEG stream: SOS before SOF")
+    prec, H, W, nc = struct.unpack_from(">BHHB", frame)
+    if prec != 8:
+        raise ValueError(f"unsupported JPEG stream: {prec}-bit samples")
+    if nc != 3 or len(frame) < 6 + 3 * nc:
+        raise ValueError(f"unsupported JPEG stream: {nc} components (the "
+                         "converter reads three: YCbCr)")
+    comps = [tuple(frame[6 + 3 * i:9 + 3 * i]) for i in range(nc)]
+    ids = tuple(c[0] for c in comps)
+    if adobe == 0 or (adobe is None and not jfif and ids == (82, 71, 66)):
+        raise ValueError("unsupported JPEG stream: RGB-coded components "
+                         "(the converter reads YCbCr)")
+    sampling = tuple((c[1] >> 4, c[1] & 15) for c in comps)
+    if sampling[0] not in ((1, 1), (2, 1), (1, 2), (2, 2)) \
+            or any(s != (1, 1) for s in sampling[1:]):
+        raise ValueError(f"unsupported JPEG stream: sampling {sampling} "
+                         "(Y at most 2x2, chroma 1x1)")
+    hm, vm = sampling[0]
+    if not H or not W or H % (8 * vm) or W % (8 * hm):
+        raise ValueError(
+            f"corrupt JPEG stream: unsupported frame size {H}x{W}")
+    if not scan or len(scan) < 1 + 2 * scan[0] + 3 or scan[0] != nc:
+        raise ValueError("unsupported JPEG stream: one interleaved scan of "
+                         "every component is read")
+    sel = [tuple(scan[1 + 2 * i:3 + 2 * i]) for i in range(nc)]
+    ss, se, a = scan[1 + 2 * nc:4 + 2 * nc]
+    if [s[0] for s in sel] != list(ids) or (ss, se, a) != (0, 63, 0):
+        raise ValueError("unsupported JPEG stream: one interleaved scan of "
+                         "every component is read")
+    for c in comps:
+        if c[2] not in qt:
+            raise ValueError(
+                f"corrupt JPEG stream: no quantisation table {c[2]}")
+    used = sorted({(0, t >> 4) for _, t in sel} | {(1, t & 15)
+                                                  for _, t in sel})
+    for key in used:
+        if key not in ht:
+            raise ValueError(f"corrupt JPEG stream: no Huffman table "
+                             f"{'DC' if key[0] == 0 else 'AC'}{key[1]}")
+    row = {key: i for i, key in enumerate(used)}
+    return H, W, Coding(
+        sampling=sampling, q=tuple(qt[c[2]] for c in comps),
+        dc=tuple(row[0, t >> 4] for _, t in sel),
+        ac=tuple(row[1, t & 15] for _, t in sel),
+        huff=tuple(ht[key] for key in used))
 
-    The host entropy stage of the batched decode path — the exact inverse
-    of ``encode_coef_batch`` (``decode_coef_batch(encode_coef_batch(c))``
-    is coefficient-exact; only the transform stage is lossy). All tiles of
-    a batch must share one geometry, as a pyramid level's frames do.
-    Raises ``ValueError("corrupt JPEG …")`` on truncated/garbage input.
+
+def _code_overflow(bits: bytes) -> bool:
+    """Whether canonical code assignment (T.81 Annex C) runs out of codes."""
+    code = 0
+    for ln in range(1, 17):
+        code += bits[ln - 1]
+        if code > (1 << ln):
+            return True
+        code <<= 1
+    return False
+
+
+def merge_tables(tile: bytes, tables: bytes) -> bytes:
+    """An abbreviated tile stream and the shared tables-only stream → one
+    complete interchange stream.
+
+    TIFF Technical Note 2 ("new-style" JPEG, Compression 7): every tile is
+    SOI, frame, scan, EOI, and the quantisation and Huffman tables they
+    share sit once in the ``JPEGTables`` tag as SOI, DQT/DHT, EOI. The
+    tables go in right after the tile's SOI; the entropy-coded data is
+    untouched, so a DICOM frame made this way is the scanner's own JPEG.
+    """
+    if not tables:
+        return tile
+    if tables[:2] != _SOI or tables[-2:] != _EOI:
+        raise ValueError("corrupt JPEGTables: not an SOI … EOI "
+                         "tables-only stream")
+    if tile[:2] != _SOI:
+        raise ValueError("corrupt JPEG stream: missing SOI marker")
+    return tile[:2] + tables[2:-2] + tile[2:]
+
+
+def photometric(jpg: bytes) -> str:
+    """The DICOM Photometric Interpretation of a JPEG frame: YBR_FULL_422
+    where its chroma is subsampled (PS3.5 §8.2.1), YBR_FULL otherwise."""
+    return "YBR_FULL_422" if _parse_stream(jpg)[4].subsampled \
+        else "YBR_FULL"
+
+
+def _parse_batch(jpgs: list[bytes]
+                 ) -> tuple[int, int, Coding, list[np.ndarray]]:
+    """Parse a batch of one level's frames → (H, W, coding, unstuffed
+    scans); every frame must share the geometry and the coding."""
+    parsed = [_parse_stream(j) for j in jpgs]
+    H, W, _, _, coding = parsed[0]
+    if any((h, w) != (H, W) for h, w, _, _, _ in parsed):
+        raise ValueError(
+            "corrupt JPEG stream: mixed tile geometries in one batch "
+            f"({sorted({(h, w) for h, w, _, _, _ in parsed})})")
+    if any(c != coding for *_, c in parsed):
+        raise ValueError("corrupt JPEG stream: mixed tables or sampling in "
+                         "one batch")
+    scans = [_unstuff(np.frombuffer(jpg, np.uint8, end - start, start))
+             for jpg, (_, _, start, end, _) in zip(jpgs, parsed)]
+    return H, W, coding, scans
+
+
+def decode_components(jpgs: list[bytes]) -> list[np.ndarray]:
+    """N baseline tiles sharing one coding → per component (Y, Cb, Cr) an
+    (N, h, w) int32 array of quantized coefficients, blocks in place (the
+    chroma planes of a subsampled stream are smaller).
+
+    The host entropy stage of the batched decode path (exact: only the
+    transform stage is lossy). 4:4:4 streams are integrated and scattered
+    on the host; a subsampled stream's planes come from the device
+    (``entropy_jax.coef_planes``). Raises ``ValueError("corrupt JPEG …")``
+    on truncated/garbage input.
     """
     jpgs = list(jpgs)
     if not jpgs:
-        return np.zeros((0, 3, 0, 0), np.int32)
+        return [np.zeros((0, 0, 0), np.int32)] * 3
     with tracing.span("decode.parse", frames=len(jpgs)):
-        geom = [_parse_jfif(j) for j in jpgs]
-        H, W = geom[0][:2]
-        if any((h, w) != (H, W) for h, w, _, _ in geom):
-            raise ValueError(
-                "corrupt JPEG stream: mixed tile geometries in one batch "
-                f"({sorted({(h, w) for h, w, _, _ in geom})})")
-        scans = [_unstuff(np.frombuffer(jpg, np.uint8, end - start, start))
-                 for jpg, (_, _, start, end) in zip(jpgs, geom)]
-        engine, packed = _pack_scans(scans, H, W)
+        H, W, coding, scans = _parse_batch(jpgs)
+        engine, packed = _pack_scans(scans, H, W, coding=coding)
     with tracing.span("decode.entropy", engine=engine):
-        zz = _run_packed(engine, packed, H, W)
+        zz = _run_packed(engine, packed, H, W, coding)
     with tracing.span("decode.scatter"):
-        return _coef_planes(zz, H, W)
+        if not coding.subsampled:
+            return list(_coef_planes(zz, H, W).transpose(1, 0, 2, 3))
+        from repro.wsi.entropy_jax import coef_planes
+        y, c = coef_planes(zz.reshape(-1), n=len(jpgs), H=H, W=W,
+                           coding=coding)
+        c = np.asarray(c)
+        return [np.asarray(y), c[:, 0], c[:, 1]]
+
+
+def decode_coef_batch(jpgs: list[bytes]) -> np.ndarray:
+    """N baseline 4:4:4 tiles → (N, 3, H, W) int32 quantized coefficients.
+
+    The exact inverse of ``encode_coef_batch``
+    (``decode_coef_batch(encode_coef_batch(c))`` is coefficient-exact;
+    only the transform stage is lossy). All tiles of a batch must share one
+    geometry, as a pyramid level's frames do; subsampled streams have
+    planes of two sizes (``decode_components``). Raises
+    ``ValueError("corrupt JPEG …")`` on truncated/garbage input.
+    """
+    comps = decode_components(jpgs)
+    if comps[0].shape != comps[1].shape:
+        raise ValueError("subsampled JPEG stream: its planes differ in size "
+                         "(decode_components)")
+    return np.stack(comps, axis=1)
+
+
+def _rgb(coef, coding: Coding) -> np.ndarray:
+    """(N, 3, H, W) coefficient planes of a 4:4:4 stream → (N, H, W, 3)
+    uint8 RGB: the fused ``jpeg_inverse`` where both chroma components
+    share one table, ``jpeg_inverse420`` otherwise."""
+    q = coding.qtables()
+    if coding.q[1] == coding.q[2]:
+        rgb = jpeg_inverse(coef, q[0], q[1])
+    else:
+        rgb = jpeg_inverse420(coef[:, 0], coef[:, 1:], q)
+    return np.ascontiguousarray(
+        np.asarray(rgb).astype(np.uint8, copy=False).transpose(0, 2, 3, 1))
+
+
+def _rgb_subsampled(y, c, coding: Coding) -> np.ndarray:
+    """Y (N, H, W) and chroma (N, 2, h, w) coefficient planes → (N, H, W,
+    3) uint8 RGB through ``jpeg_inverse420`` (the stream's tables, chroma
+    upsampled)."""
+    rgb = jpeg_inverse420(y, c, coding.qtables())
+    return np.ascontiguousarray(
+        np.asarray(rgb).astype(np.uint8).transpose(0, 2, 3, 1))
 
 
 def decode_tiles_batch(jpgs: list[bytes]) -> np.ndarray:
-    """N baseline JFIF tiles → (N, H, W, 3) uint8 RGB.
+    """N baseline tiles sharing one coding → (N, H, W, 3) uint8 RGB.
 
     The whole-level batched decode path: one vectorized entropy-decode
-    pass (``decode_coef_batch``), then a single fused ``jpeg_inverse``
-    dispatch. Output is pixel-identical to ``[decode_tile(j) for j in
-    jpgs]`` — both paths share the one ``jpeg_inverse`` transform, so
-    identity reduces to the (exact, integer) coefficient streams matching.
+    pass, then a single fused inverse dispatch. Output is pixel-identical
+    to ``[decode_tile(j) for j in jpgs]`` — both paths share the one
+    inverse transform, so identity reduces to the (exact, integer)
+    coefficient streams matching. A subsampled stream's coefficients stay
+    on the device from the entropy decoder to the inverse.
     """
-    coef = decode_coef_batch(jpgs)
-    if coef.shape[0] == 0:
+    jpgs = list(jpgs)
+    if not jpgs:
         return np.zeros((0, 0, 0, 3), np.uint8)
+    with tracing.span("decode.parse", frames=len(jpgs)):
+        H, W, coding, scans = _parse_batch(jpgs)
+        engine, packed = _pack_scans(scans, H, W, coding=coding)
+    if not coding.subsampled:
+        with tracing.span("decode.entropy", engine=engine):
+            zz = _run_packed(engine, packed, H, W, coding)
+        with tracing.span("decode.scatter"):
+            coef = _coef_planes(zz, H, W)
+        with tracing.span("decode.inverse"):
+            return _rgb(coef, coding)
+    from repro.wsi.entropy_jax import coef_planes, decode_packed
+    with tracing.span("decode.entropy", engine=engine):
+        zzf = decode_packed(packed, H, W, coding) if engine == "jax" \
+            else _run_packed(engine, packed, H, W, coding).reshape(-1)
     with tracing.span("decode.inverse"):
-        rgb = np.asarray(jpeg_inverse(coef))
-    return np.ascontiguousarray(rgb.transpose(0, 2, 3, 1))
+        y, c = coef_planes(zzf, n=len(jpgs), H=H, W=W, coding=coding)
+        return _rgb_subsampled(y, c, coding)
 
 
 def decode_frames(frames: list[bytes], *, transfer_syntax: str,
@@ -810,11 +1137,13 @@ def decode_frames(frames: list[bytes], *, transfer_syntax: str,
 
     The single transfer-syntax dispatch shared by every store consumer
     (the export service, the ML-inference subscriber): JPEG-baseline
-    frames go through the batched decode path when there is more than one
-    (the lockstep decoder's win grows with the batch — see
-    BENCH_export.json's ``batch_scaling``; small pulls sit near parity,
-    whole levels win outright), native explicit-VR-LE frames are reshaped
-    directly. Geometry mismatches and unknown syntaxes raise ``ValueError``.
+    frames — YBR_FULL (4:4:4) or YBR_FULL_422 (subsampled chroma, a
+    scanner's own tiles), which the stream itself tells apart — go through
+    the batched decode path when there is more than one (the lockstep
+    decoder's win grows with the batch — see BENCH_export.json's
+    ``batch_scaling``; small pulls sit near parity, whole levels win
+    outright), native explicit-VR-LE frames are reshaped directly.
+    Geometry mismatches and unknown syntaxes raise ``ValueError``.
     """
     frames = list(frames)
     n = len(frames)
@@ -994,22 +1323,24 @@ def encode_tiles_batch(tiles_rgb: np.ndarray) -> list[bytes]:
 
 
 def decode_tile(jpg: bytes) -> np.ndarray:
-    """Baseline JFIF (as produced by ``encode_tile``) → RGB (H, W, 3) uint8.
+    """One baseline tile (``encode_tile``'s, or a scanner's once its tables
+    are merged in) → RGB (H, W, 3) uint8.
 
     The per-tile decode path: a per-symbol Python Huffman loop, then the
-    shared fused ``jpeg_inverse`` transform on a batch of one — kept as
+    inverse transform the batched path uses on a batch of one — kept as
     the A/B baseline for ``decode_tiles_batch`` (pixel-identical output).
     Truncated/garbage input raises ``ValueError("corrupt JPEG …")``.
     """
     with tracing.span("decode.parse", frames=1):
-        H, W, data_start, data_end = _parse_jfif(jpg)
+        H, W, data_start, data_end, coding = _parse_stream(jpg)
     with tracing.span("decode.entropy", engine="python"):
         br = _BitReader(jpg[data_start:data_end])
-        planes = _decode_blocks(br, H, W)
-        coef = np.stack(planes)[None].astype(np.int32)  # (1, 3, H, W)
+        planes = [p[None] for p in _decode_blocks(br, H, W, coding)]
     with tracing.span("decode.inverse"):
-        rgb = np.asarray(jpeg_inverse(coef))[0]
-    return np.ascontiguousarray(rgb.transpose(1, 2, 0))
+        if coding.subsampled:
+            return _rgb_subsampled(planes[0], np.stack(planes[1:], axis=1),
+                                   coding)[0]
+        return _rgb(np.stack(planes, axis=1), coding)[0]
 
 
 def psnr(a: np.ndarray, b: np.ndarray) -> float:

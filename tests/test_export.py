@@ -274,3 +274,31 @@ def test_full_circle_export_reingests_through_sniffing_pipeline():
     assert len(pipe.store_service.search_studies()) == 2
     assert pipe.validator.quarantined == []
     sched.shutdown()
+
+
+def test_export_of_a_transcoded_jpeg_svs_decodes_its_420_level0():
+    """A study transcoded from a scanner's JPEG SVS keeps the scanner's
+    4:2:0 tiles as level 0 (YBR_FULL_422); its export writes level 0's
+    TIFF from those frames, decoded as the per-tile decoder decodes them."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    import scanner_jpeg
+
+    _, svs = scanner_jpeg.scan(512, 512, 256, 4.25)
+    sched = SimScheduler()
+    store = ObjectStore(sched)
+    svc = DicomStoreService(store.bucket("dicom"), sched)
+    svc.store_study_archive("studies/svs.tar", convert_wsi_to_dicom(svs))
+    (study,) = svc.search_studies()
+    meta = svc.search_instances(study)[0]
+    exporter = ExportService(svc, store.bucket("derived"))
+    keys = exporter.export_study(study)
+    assert len(keys) == 2
+    rd = open_slide(store.bucket("derived").get(keys[0]).data)
+    assert (rd.H, rd.W) == (512, 512)
+    for i in range(4):
+        frame = svc.retrieve_frame(meta["sop_instance_uid"], i)
+        np.testing.assert_array_equal(rd.read_tile(*divmod(i, 2)),
+                                      decode_tile(frame))

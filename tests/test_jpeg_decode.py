@@ -165,9 +165,9 @@ def test_decode_tile_garbage_raises_corrupt(tissue_jpg):
 def test_decode_tile_scan_bitflip_never_escapes_value_error(tissue_jpg):
     """Corrupting scan bytes may still decode (a different valid stream) or
     must raise the corrupt-JPEG error — both decoders, same contract."""
-    from repro.wsi.jpeg import _parse_jfif
+    from repro.wsi.jpeg import _parse_stream
 
-    _, _, start, _ = _parse_jfif(tissue_jpg)
+    _, _, start, _, _ = _parse_stream(tissue_jpg)
     rng = np.random.default_rng(1)
     for _ in range(12):
         mut = bytearray(tissue_jpg)
@@ -199,7 +199,7 @@ def _scans(jpgs):
 
     scans, H, W = [], None, None
     for j in jpgs:
-        H, W, s, e = J._parse_jfif(j)
+        H, W, s, e, _ = J._parse_stream(j)
         scans.append(J._unstuff(np.frombuffer(j, np.uint8)[s:e]))
     return scans, H, W
 

@@ -118,3 +118,50 @@ def test_huffman_encode_dispatch_fits_one_chip(on_tpu, one_chip):
     text = compiled.as_text()
     assert " while(" not in text and "huffman_encode" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_jpeg_inverse420_level0_batch_compiles_for_v5e(on_tpu, one_chip):
+    """The inverse of a scanner's 4:2:0 tiles on the level-0 batch of an
+    8192² slide (1024 tiles of 256²): the kernel compiles natively, and
+    its program fits one chip (``memory_analysis`` recorded in PERF.md)."""
+    y = jax.ShapeDtypeStruct((1024, 256, 256), jnp.int32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((1024, 2, 128, 128), jnp.int32,
+                             sharding=one_chip)
+    q = jax.ShapeDtypeStruct((3, 8, 8), jnp.float32, sharding=one_chip)
+    compiled = _compile(ops.jpeg_inverse420, y, c, q)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def test_scanner_decode_8192_fits_one_chip(on_tpu, one_chip):
+    """The table-general lockstep decoder and the device DC integration and
+    de-zigzag at the 4:2:0 level 0 of an 8192² slide (1024 lanes of 1536
+    blocks) compile for the chip; the coefficients stay on the device
+    (≈ 0.4 GB of int32) and the de-zigzag is a matmul, not a gather."""
+    from repro.wsi import entropy_jax, jpeg
+
+    coding = jpeg.Coding(
+        sampling=((2, 2), (1, 1), (1, 1)), q=jpeg._STANDARD.q,
+        dc=(0, 1, 1), ac=(2, 3, 3), huff=jpeg._STANDARD.huff)
+    nu = coding.units(256, 256)
+    assert nu == 1536
+    lanes = [jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip)
+             for _ in range(3)]
+    luts = [jax.ShapeDtypeStruct((4 * 65536,), jnp.int32, sharding=one_chip)
+            ] * 2 + [jax.ShapeDtypeStruct((16,), jnp.int32,
+                                          sharding=one_chip)] * 2
+    loop = entropy_jax._lockstep.lower(
+        jax.ShapeDtypeStruct((1 << 25,), jnp.uint8, sharding=one_chip),
+        *lanes, *luts, nu=nu, dc_rows=coding.dc_rows,
+        ac_rows=coding.ac_rows).compile()
+    assert loop.memory_analysis().output_size_in_bytes < 2**30
+    planes = entropy_jax.coef_planes.lower(
+        jax.ShapeDtypeStruct((1024 * nu * 64,), jnp.int32,
+                             sharding=one_chip),
+        n=1024, H=256, W=256, coding=coding).compile()
+    text = planes.as_text()
+    assert " gather(" not in text and " scatter(" not in text
+    mem = planes.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < HBM_BYTES
