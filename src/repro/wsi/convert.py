@@ -408,17 +408,20 @@ def _tiles_to_plane(tiles, bh: int, bw: int):
 
 
 def _decode_level0(frames: list[bytes], bh: int, bw: int,
-                   mesh) -> tuple[jax.Array, int, int]:
+                   mesh) -> tuple[jax.Array, dict]:
     """Level 0 of a JPEG-tiled container decoded on the device → its
     (3, H, W) float32 planes (exact uint8 values, the layout
-    ``_upload_level0`` gives), the bytes uploaded and the blocks decoded.
+    ``_upload_level0`` gives) and what ``convert.decode`` records: the
+    bytes uploaded (``bytes_in``), the blocks decoded and, where traced,
+    the lockstep loop's ``steps`` and ``lanes``.
 
     The host parses, unstuffs and packs the scans (``decode.parse``) and
     uploads them compressed (``convert.upload``); the device runs the
-    lockstep entropy decoder (``decode.entropy``: only its error flags come
-    back), integrates the DC terms and de-zigzags, and inverts every tile
-    (``decode.inverse``: ``jpeg_inverse420``, the stream's tables, chroma
-    upsampled). No coefficient or pixel of level 0 comes back.
+    lockstep entropy decoder (``decode.entropy``: only its error flags and
+    trip count come back), integrates the DC terms and de-zigzags, and
+    inverts every tile (``decode.inverse``: ``jpeg_inverse420``, the
+    stream's tables, chroma upsampled). No coefficient or pixel of level 0
+    comes back.
     """
     with tracing.span("decode.parse", frames=len(frames)):
         H, W, coding, scans = jpeg._parse_batch(frames)
@@ -429,8 +432,9 @@ def _decode_level0(frames: list[bytes], bh: int, bw: int,
         nbytes = sum(a.nbytes for a in packed[:4])
         if sp is not None:
             sp.attrs["bytes"] = nbytes
-    with tracing.span("decode.entropy", engine="jax"):
+    with tracing.span("decode.entropy", engine="jax") as sp:
         zzf = entropy_jax.decode_packed(packed, H, W, coding)
+    loop = {} if sp is None else {k: sp.attrs[k] for k in ("steps", "lanes")}
     with tracing.span("decode.inverse"):
         y, c = entropy_jax.coef_planes(zzf, n=len(frames), H=H, W=W,
                                        coding=coding)
@@ -439,7 +443,8 @@ def _decode_level0(frames: list[bytes], bh: int, bw: int,
             y, c = jax.device_put((y, c), NamedSharding(mesh, P()))
         dev = _tiles_to_plane(
             kernel_ops.jpeg_inverse420(y, c, coding.qtables()), bh, bw)
-    return dev, nbytes, len(frames) * coding.units(H, W)
+    return dev, dict(bytes_in=nbytes, blocks=len(frames) * coding.units(H, W),
+                     **loop)
 
 
 def _convert_transcode(rd: SlideReader, frames: list[bytes],
@@ -453,10 +458,10 @@ def _convert_transcode(rd: SlideReader, frames: list[bytes],
     a second generation of JPEG loss to every pixel of the archive) — and
     are wrapped and checkpointed before any device work, as YBR_FULL_422
     where the chroma is subsampled. Level 0 is then decoded on the device
-    (``convert.decode``: ``frames``, ``bytes_in`` uploaded, ``blocks``) and
-    its planes feed the pipelined engine's pyramid with level 0's own
-    transform skipped; levels ≥ 1 are coded and wrapped as from any other
-    container.
+    (``convert.decode``: ``frames``, ``bytes_in`` uploaded, ``blocks``, the
+    lockstep loop's ``steps`` and ``lanes``) and its planes feed the
+    pipelined engine's pyramid with level 0's own transform skipped;
+    levels ≥ 1 are coded and wrapped as from any other container.
     """
     tile = rd.tile
     dims = _pyramid_dims(rd.H, rd.W, opt.min_level_size)
@@ -473,9 +478,9 @@ def _convert_transcode(rd: SlideReader, frames: list[bytes],
         return len(dims)
     mesh = kernel_ops.default_mesh()
     with tracing.span("convert.decode", frames=len(frames)) as sp:
-        dev, nbytes, blocks = _decode_level0(frames, *rd.grid, mesh)
+        dev, attrs = _decode_level0(frames, *rd.grid, mesh)
         if sp is not None:
-            sp.attrs.update(bytes_in=nbytes, blocks=blocks)
+            sp.attrs.update(attrs)
     _code_pyramid(dev, dims, needed, tile, mesh, opt, metadata, study_uid,
                   series_uid)
     return len(dims)

@@ -32,12 +32,18 @@ the stream (``jpeg.Coding``): static per coding, they are part of the
 compile key, and the LUTs are the stream's own. On the ingest path the
 coefficients stay on the device (``decode_packed``, then
 ``coef_planes``: DC integration and de-zigzag by a 64×64 permutation as a
-matmul); only the error flags come back to the host.
+matmul); only the error flags and the loop's trip count come back to the
+host.
 
-Everything runs in int32 (no x64): the ≤16-bit Huffman code and the ≤11
-magnitude bits are each read through a 24-bit window built from a 3-byte
-gather, so bit cursors stay well under 2^31 for any realistic level
-(callers keep batches below ``2^27`` buffer bytes).
+Everything runs in 32 bits (no x64). The scan buffer reaches the loop as
+big-endian 32-bit words, and each step reads one 32-bit window at the bit
+cursor, joined from the two words it straddles by logical shifts: its top
+16 bits index the Huffman LUT, whose int32 entries hold the symbol and
+the code length, and the ≤ 11 magnitude bits follow the ≤ 16-bit code
+inside the same window (ln + s ≤ 27). A step thus reads two words and one
+LUT entry, and writes one coefficient; the magnitude's masks are shifts.
+Bit cursors stay well under 2^31 for any realistic level (callers keep
+batches below ``2^27`` buffer bytes).
 """
 from __future__ import annotations
 
@@ -48,14 +54,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
+
 __all__ = ["pack_scans", "decode_packed", "run_packed", "coef_planes"]
 
 _ERR_INVALID, _ERR_RUN, _ERR_TRUNC = 1, 2, 3
 
 #: per-tile zero bytes after each scan — same layout (and same reason) as
-#: the numpy engine's guard: one step can overrun a corrupt stream's end by
-#: ≤ 27 bits before the truncation flag fires, and the 3-byte windows must
-#: stay inside the buffer
+#: the numpy engine's guard: a lane reads only while its cursor is at or
+#: before its stream's end, and the two words it reads then end ≤ 64 bits
+#: past the cursor
 _GUARD = 8
 
 
@@ -69,9 +77,10 @@ def _pick(m, rows: tuple[int, ...]):
 
 
 @partial(jax.jit, static_argnames=("nu", "dc_rows", "ac_rows"))
-def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
-              nu: int, dc_rows: tuple[int, ...], ac_rows: tuple[int, ...]):
-    """Run all lanes to completion (or first error). Shapes and the MCU's
+def _lockstep(words, pos0, ends, u0, lut, *, nu: int,
+              dc_rows: tuple[int, ...], ac_rows: tuple[int, ...]):
+    """Run all lanes to completion (or first error) → the error flags, the
+    flat coefficients and the number of steps taken. Shapes and the MCU's
     table pattern (``dc_rows``/``ac_rows``: the LUT row of each block of an
     MCU) are the compile key: callers pad the lane count and buffer length
     to powers of two so every level of a slide reuses a handful of cached
@@ -82,36 +91,33 @@ def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
     base = jnp.arange(n, dtype=jnp.int32) * (nu * 64)
 
     def cond(st):
-        pos, u, k, err, zzf = st
+        pos, u, k, err, zzf, steps = st
         return jnp.any(u < nu) & ~jnp.any(err > 0)
 
     def body(st):
-        pos, u, k, err, zzf = st
+        pos, u, k, err, zzf, steps = st
         active = u < nu
 
-        # 16-bit Huffman window: 3 bytes from the bit cursor's byte
-        bp = pos >> 3
-        w24 = ((buf[bp].astype(jnp.int32) << 16)
-               | (buf[bp + 1].astype(jnp.int32) << 8)
-               | buf[bp + 2].astype(jnp.int32))
-        sh = pos & 7
-        code = (w24 >> (8 - sh)) & 0xFFFF
+        # the 32 bits from the cursor, out of the two words it straddles
+        # (the second word's shift is split in two so that at phase 0 no
+        # shift reaches 32 bits)
+        wi = pos >> 5
+        sh = (pos & 31).astype(jnp.uint32)
+        win = (words[wi] << sh) | ((words[wi + 1] >> 1) >> (31 - sh))
         is_dc = k == 0
         m = u % upm
         tbl = jnp.where(is_dc, _pick(m, dc_rows), _pick(m, ac_rows))
-        sym = lut_sym[tbl * 65536 + code]
-        ln = lut_len[tbl * 65536 + code]
+        ent = lut[tbl * 65536 + (win >> 16).astype(jnp.int32)]
+        sym, ln = ent & 0xFF, ent >> 8
 
-        # magnitude bits (≤ 11) through a second 3-byte window at pos + ln
+        # the s ≤ 11 magnitude bits right after the code, in the same
+        # window; s = 0 (and an invalid code's ln = 0) masks them all off
         s = jnp.where(is_dc, sym, sym & 0xF)
-        pos2 = pos + ln
-        bp2 = pos2 >> 3
-        w24m = ((buf[bp2].astype(jnp.int32) << 16)
-                | (buf[bp2 + 1].astype(jnp.int32) << 8)
-                | buf[bp2 + 2].astype(jnp.int32))
-        bits = (w24m >> (24 - (pos2 & 7) - s)) & mag_ext[s]
-        v = jnp.where(bits >= mag_half[s], bits, bits - mag_ext[s])
-        pos = jnp.where(active, pos2 + s, pos)
+        ext = (1 << s) - 1
+        bits = (win >> (32 - ln - s).astype(jnp.uint32)).astype(jnp.int32) \
+            & ext
+        v = jnp.where(bits >= (1 << jnp.maximum(s - 1, 0)), bits, bits - ext)
+        pos = jnp.where(active, pos + ln + s, pos)
 
         is_eob = ~is_dc & (sym == 0x00)
         is_zrl = ~is_dc & (sym == 0xF0)
@@ -136,26 +142,24 @@ def _lockstep(buf, pos0, ends, u0, lut_sym, lut_len, mag_half, mag_ext, *,
         k = jnp.where(adv, 0, k)
         err = jnp.where((u < nu) & (err == 0) & (pos > ends),
                         _ERR_TRUNC, err)
-        return pos, u, k, err, zzf
+        return pos, u, k, err, zzf, steps + 1
 
     state = (pos0, u0, jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
-             jnp.zeros(total, jnp.int32))
+             jnp.zeros(total, jnp.int32), jnp.int32(0))
     with jax.named_scope("lockstep_decode"):
-        pos, u, k, err, zzf = jax.lax.while_loop(cond, body, state)
-    return err, zzf
+        pos, u, k, err, zzf, steps = jax.lax.while_loop(cond, body, state)
+    return err, zzf, steps
 
 
 @lru_cache(maxsize=32)
-def _device_tables(huff: tuple, device=None) -> tuple:
-    """A stream's Huffman LUTs, flattened for a single-gather lookup, and
-    the magnitude-decode rows, committed once per table set and device."""
+def _device_tables(huff: tuple, device=None) -> jax.Array:
+    """A stream's Huffman LUTs flattened for a single-gather lookup, each
+    int32 entry ``symbol | code length << 8`` (length 0: no code),
+    committed once per table set and device."""
     from repro.wsi import jpeg
     sym, ln = jpeg._luts(huff)
-    put = partial(jax.device_put, device=device)
-    return (put(jnp.asarray(sym.reshape(-1), jnp.int32)),
-            put(jnp.asarray(ln.reshape(-1), jnp.int32)),
-            put(jnp.asarray(jpeg._MAG_HALF, jnp.int32)),
-            put(jnp.asarray(jpeg._MAG_EXT, jnp.int32)))
+    packed = sym.astype(np.int32) | ln.astype(np.int32) << 8
+    return jax.device_put(packed.reshape(-1), device)
 
 
 def _pow2(n: int) -> int:
@@ -164,8 +168,8 @@ def _pow2(n: int) -> int:
 
 def pack_scans(scans: list[np.ndarray], nu: int) -> tuple:
     """The host half: N unstuffed scans of ``nu`` blocks each → one
-    guarded, power-of-two padded byte buffer with each lane's start bit,
-    end bit and first unit.
+    guarded, power-of-two padded buffer of big-endian 32-bit words (the
+    same bytes) with each lane's start bit, end bit and first unit.
 
     Lane count and buffer length are padded to powers of two so the jit
     cache stays small; pad lanes start exhausted (``u = nu``) and can
@@ -174,40 +178,44 @@ def pack_scans(scans: list[np.ndarray], nu: int) -> tuple:
     N = len(scans)
     npad = _pow2(N)
 
+    sizes = np.array([scan.size for scan in scans], np.int64)
     offs = np.zeros(npad, np.int64)
+    offs[:N] = np.cumsum(sizes + _GUARD) - sizes - _GUARD
     ends = np.zeros(npad, np.int64)
-    parts, cur = [], 0
-    for i, scan in enumerate(scans):
-        offs[i] = cur
-        ends[i] = (cur + scan.size) * 8
-        parts += [scan, np.zeros(_GUARD, np.uint8)]
-        cur += scan.size + _GUARD
-    buf = np.concatenate(parts) if parts else np.zeros(_GUARD, np.uint8)
-    blen = _pow2(max(buf.size, _GUARD))
-    if blen > buf.size:
-        buf = np.concatenate([buf, np.zeros(blen - buf.size, np.uint8)])
+    ends[:N] = (offs[:N] + sizes) * 8
+    blen = _pow2(max(int(sizes.sum()) + N * _GUARD, _GUARD))
     assert blen * 8 < 2**31, "scan buffer too large for int32 bit cursors"
+    raw = np.zeros(blen, np.uint8)
+    for off, scan in zip(offs, scans):
+        raw[off:off + scan.size] = scan
 
     u0 = np.full(npad, nu, np.int32)
     u0[:N] = 0
-    return buf, (offs * 8).astype(np.int32), ends.astype(np.int32), u0, N
+    words = raw.view(">u4").astype(np.uint32)
+    return words, (offs * 8).astype(np.int32), ends.astype(np.int32), u0, N
 
 
 def decode_packed(packed: tuple, H: int, W: int, coding) -> jax.Array:
     """The device half: the lockstep loop over a packed buffer (host
     arrays, or ``pack_scans``' arrays already on a device). Only the error
-    flags come back: a corrupt stream raises the numpy engine's
-    ``ValueError``; the coefficients stay on the device as a flat (lanes ·
-    blocks · 64,) int32 array of zigzag coefficients, the DC slots holding
-    differentials."""
-    buf, bit0, ends, u0, N = packed
-    device = next(iter(buf.devices())) if isinstance(buf, jax.Array) \
+    flags and the loop's trip count come back: a corrupt stream raises the
+    numpy engine's ``ValueError``; the coefficients stay on the device as a
+    flat (lanes · blocks · 64,) int32 array of zigzag coefficients, the DC
+    slots holding differentials. The ambient span (``decode.entropy``)
+    records ``steps`` (the loop's trips: the most symbols any lane decodes)
+    and ``lanes`` (the loop's width, padded to a power of two)."""
+    words, bit0, ends, u0, N = packed
+    device = next(iter(words.devices())) if isinstance(words, jax.Array) \
         else None
-    err, zzf = _lockstep(buf, bit0, ends, u0,
-                         *_device_tables(coding.huff, device),
-                         nu=coding.units(H, W), dc_rows=coding.dc_rows,
-                         ac_rows=coding.ac_rows)
-    err = np.asarray(err)
+    err, zzf, steps = _lockstep(words, bit0, ends, u0,
+                                _device_tables(coding.huff, device),
+                                nu=coding.units(H, W),
+                                dc_rows=coding.dc_rows,
+                                ac_rows=coding.ac_rows)
+    err, steps = jax.device_get((err, steps))
+    sp = tracing.current_span()
+    if sp is not None:
+        sp.attrs.update(steps=int(steps), lanes=err.size)
     if (err == _ERR_INVALID).any():
         raise ValueError("corrupt JPEG stream: invalid Huffman code")
     if (err == _ERR_RUN).any():

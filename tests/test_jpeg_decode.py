@@ -263,6 +263,178 @@ def test_entropy_engine_auto_thresholds():
         _lockstep(scans, H, W, engine="cuda")
 
 
+# --------------------------------------------------------------------------
+# the jitted engine's 32-bit window at its widest reads
+# --------------------------------------------------------------------------
+def _scanner_coding():
+    """The stand-in scanner's coding: 4:2:0, Annex K tables."""
+    from repro.wsi import jpeg as J
+
+    return J.Coding(sampling=((2, 2), (1, 1), (1, 1)), q=J._STANDARD.q,
+                    dc=(0, 1, 1), ac=(2, 3, 3), huff=J._STANDARD.huff)
+
+
+def _wide_blocks(n, rng):
+    """``n`` zigzag blocks of one component in bitstream order, each with
+    the widest reads a baseline scan has: a DC alternating ±600 (a
+    category-11 difference after the first) and, after a run of 15 zeros,
+    an AC of category 10 (symbol 0xFA: a 16-bit code and 10 bits); then a
+    random count of ±1 (3 bits each), so the wide symbols start at every
+    bit phase of a word."""
+    zz = np.zeros((n, 64), np.int64)
+    zz[:, 0] = np.where(np.arange(n) % 2, -600, 600)
+    zz[:, 16] = rng.choice([-1, 1], n) * rng.integers(512, 1024, n)
+    for b, f in enumerate(rng.integers(0, 47, n)):
+        zz[b, 17:17 + f] = rng.choice([-1, 1], f)
+    return zz
+
+
+def _wide_scans(coding_name, n=3, H=64, W=128):
+    """``n`` H×W tiles of ``_wide_blocks`` → unstuffed scans and the
+    coding: 4:4:4 through ``encode_coef_batch``, 4:2:0 through the stand-in
+    scanner's coder (``encode_coef_batch`` writes 4:4:4 only)."""
+    import sys
+    from pathlib import Path
+
+    from repro.wsi import jpeg as J
+
+    rng = np.random.default_rng(29)
+    if coding_name == "444-annexk":
+        nat = np.zeros((n * 3, H // 8 * (W // 8), 64), np.int64)
+        for c in range(n * 3):
+            nat[c][:, J._ZIGZAG] = _wide_blocks(nat.shape[1], rng)
+        coef = (nat.reshape(n, 3, H // 8, W // 8, 8, 8)
+                .transpose(0, 1, 2, 4, 3, 5).reshape(n, 3, H, W))
+        scans, _, _ = _scans(encode_coef_batch(coef))
+        return scans, J._STANDARD
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    import scanner_jpeg
+
+    nm = (H // 16) * (W // 16)
+    units = np.zeros((n, nm, 6, 64), np.int64)
+    for t in range(n):
+        units[t, :, :4] = _wide_blocks(nm * 4, rng).reshape(nm, 4, 64)
+        units[t, :, 4] = _wide_blocks(nm, rng)
+        units[t, :, 5] = _wide_blocks(nm, rng)
+    return ([J._unstuff(np.frombuffer(s, np.uint8))
+             for s in scanner_jpeg._scans(units)], _scanner_coding())
+
+
+def _wide_symbol_starts(scan, coding, nu):
+    """Bit offsets in ``scan`` where a DC category 11 and an AC 0xFA
+    symbol start, found by walking the scan symbol by symbol."""
+    from repro.wsi import jpeg as J
+
+    sym_lut, len_lut = J._luts(coding.huff)
+    bits = int.from_bytes(scan.tobytes() + bytes(4), "big")
+    top = 8 * (scan.size + 4) - 16
+    upm = len(coding.unit_comps)
+    pos, starts = 0, {"dc11": [], "ac_fa": []}
+    for u in range(nu):
+        k = 0
+        while k < 64:
+            row = (coding.dc_rows if k == 0 else coding.ac_rows)[u % upm]
+            code = (bits >> (top - pos)) & 0xFFFF
+            sym, ln = int(sym_lut[row, code]), int(len_lut[row, code])
+            if k == 0:
+                if sym == 11:
+                    starts["dc11"].append(pos)
+                pos, k = pos + ln + sym, 1
+                continue
+            if sym == 0xFA:
+                starts["ac_fa"].append(pos)
+            pos += ln + (sym & 15)
+            if sym == 0x00:
+                break
+            k += 16 if sym == 0xF0 else (sym >> 4) + 1
+    return starts
+
+
+@pytest.mark.parametrize("coding_name", ["444-annexk", "420-scanner"])
+def test_entropy_engines_agree_on_the_widest_reads_at_every_phase(
+        coding_name):
+    """The jitted engine reads the code and the magnitude bits from one
+    32-bit window joined from two words: DC category 11 and AC (15, 10)
+    symbols starting at each of the 32 bit phases of a word decode
+    coefficient for coefficient as the numpy engine decodes them."""
+    from repro.wsi import entropy_jax
+    from repro.wsi import jpeg as J
+
+    H, W = 64, 128
+    scans, coding = _wide_scans(coding_name, H=H, W=W)
+    nu = coding.units(H, W)
+    bit0 = entropy_jax.pack_scans(scans, nu)[1]
+    for kind in ("dc11", "ac_fa"):
+        phases = {int(b + p) % 32 for b, scan in zip(bit0, scans)
+                  for p in _wide_symbol_starts(scan, coding, nu)[kind]}
+        assert phases == set(range(32)), kind
+    zz = {e: J._run_packed(*J._pack_scans(scans, H, W, e, coding), H, W,
+                           coding) for e in ("numpy", "jax")}
+    np.testing.assert_array_equal(zz["jax"], zz["numpy"])
+    assert (np.abs(zz["jax"][..., 16]) >= 512).all()
+
+
+def test_lockstep_counts_its_steps_and_lanes():
+    """Blocks of zeros are two symbols each (DC category 0, EOB), so the
+    loop takes exactly two steps a block; the width is the lane count
+    padded to a power of two."""
+    from repro.core import tracing
+
+    scans, H, W = _scans(encode_coef_batch(np.zeros((3, 3, 64, 128),
+                                                    np.int32)))
+    with tracing.capture() as tracer:
+        with tracing.span("decode.entropy"):
+            zz = _lockstep(scans, H, W, engine="jax")
+    assert not zz.any()
+    (sp,) = tracer.spans
+    assert sp.attrs == {"steps": 2 * 3 * 8 * 16, "lanes": 4}
+
+
+def _while_body_ops(hlo: str) -> str:
+    """The instructions of the compiled ``while`` loop's body and of every
+    computation it calls (fusions included)."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    todo = [re.search(r" while\(.*body=%?([\w.\-]+)", hlo).group(1)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            for line in comps[name]:
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+    return "\n".join(line for name in seen for line in comps[name])
+
+
+def test_lockstep_step_reads_two_words_and_one_lut_entry():
+    """At the 4:2:0 level-0 shapes of an 8192² slide (1024 lanes of 1536
+    blocks), the compiled loop body gathers at most 3 times (the two words
+    of the window, the packed LUT entry) and scatters once (the
+    coefficient)."""
+    import jax
+
+    from repro.wsi import entropy_jax
+
+    coding = _scanner_coding()
+    lanes = [jax.ShapeDtypeStruct((1024,), jnp.int32)] * 3
+    hlo = entropy_jax._lockstep.lower(
+        jax.ShapeDtypeStruct((1 << 23,), jnp.uint32), *lanes,
+        jax.ShapeDtypeStruct((4 * 65536,), jnp.int32),
+        nu=coding.units(256, 256), dc_rows=coding.dc_rows,
+        ac_rows=coding.ac_rows).compile().as_text()
+    body = _while_body_ops(hlo)
+    assert body.count(" gather(") <= 3
+    assert body.count(" scatter(") == 1
+
+
 @pytest.mark.parametrize("hw,n,engine", [(256, 2, "jax"), (64, 2, "numpy")])
 def test_decode_path_spans_name_its_stages(hw, n, engine):
     """The batched decode runs as ``decode.parse`` (container parse,
@@ -277,9 +449,14 @@ def test_decode_path_spans_name_its_stages(hw, n, engine):
     with tracing.capture() as tracer:
         traced = decode_tiles_batch(jpgs)
     np.testing.assert_array_equal(plain, traced)
+    # the jitted loop also records its trip count and width
+    loop = tracer.spans[1].attrs.get("steps")
+    assert (loop is None) == (engine == "numpy")
+    counts = {} if loop is None else {"steps": loop, "lanes": n}
+    assert loop is None or loop >= 2 * 3 * (hw // 8) ** 2
     assert [(sp.name, sp.attrs) for sp in tracer.spans] == [
         ("decode.parse", {"frames": n}),
-        ("decode.entropy", {"engine": engine}),
+        ("decode.entropy", {"engine": engine, **counts}),
         ("decode.scatter", {}),
         ("decode.inverse", {})]
     assert all(sp.status == "ok" for sp in tracer.spans)

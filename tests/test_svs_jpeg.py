@@ -241,6 +241,7 @@ def test_transcoding_keeps_level0_and_matches_the_reference(svs512):
     (dec,) = tr.spans_named("convert.decode")
     (up,) = tr.spans_named("convert.upload")
     assert dec.attrs["frames"] == 4 and dec.attrs["blocks"] == 4 * 1536
+    assert dec.attrs["lanes"] == 4 and dec.attrs["steps"] >= 2 * 1536
     assert up.attrs["bytes"] == dec.attrs["bytes_in"] < 512 * 512
     names = {d.name for d in tr.descendants(dec)}
     assert {"decode.parse", "convert.upload", "decode.entropy",

@@ -149,12 +149,10 @@ def test_scanner_decode_8192_fits_one_chip(on_tpu, one_chip):
     assert nu == 1536
     lanes = [jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip)
              for _ in range(3)]
-    luts = [jax.ShapeDtypeStruct((4 * 65536,), jnp.int32, sharding=one_chip)
-            ] * 2 + [jax.ShapeDtypeStruct((16,), jnp.int32,
-                                          sharding=one_chip)] * 2
+    lut = jax.ShapeDtypeStruct((4 * 65536,), jnp.int32, sharding=one_chip)
     loop = entropy_jax._lockstep.lower(
-        jax.ShapeDtypeStruct((1 << 25,), jnp.uint8, sharding=one_chip),
-        *lanes, *luts, nu=nu, dc_rows=coding.dc_rows,
+        jax.ShapeDtypeStruct((1 << 23,), jnp.uint32, sharding=one_chip),
+        *lanes, lut, nu=nu, dc_rows=coding.dc_rows,
         ac_rows=coding.ac_rows).compile()
     assert loop.memory_analysis().output_size_in_bytes < 2**30
     planes = entropy_jax.coef_planes.lower(
