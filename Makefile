@@ -39,15 +39,10 @@ race:
 trace:
 	python -m repro.core.dashboard --smoke --out artifacts
 
-# CPU byte-identity smoke: the conversion benchmark with --fast asserts
-# per-tile ≡ batched ≡ pipelined ≡ concurrent output bytes on small slides
-# AND runs the mixed-format batch (PSV + tiled-TIFF deliveries of the same
-# pixels through one sniffing deployment must emit byte-identical study
-# tars) and the fused-engine transfer ledger (1 upload + 1 dispatch per
-# slide); the store benchmark asserts indexed-WADO byte identity + ≥10x
-# plus re-STOW / crash-rebuild QIDO/WADO identity; the export benchmark
-# asserts batched-decode pixel identity + coefficient-exact round-trip
-# and a >1x decode speedup at EVERY batch-scaling point; the kernel
+# CPU smoke of the benches: the store benchmark asserts indexed-WADO byte
+# identity + ≥10x plus re-STOW / crash-rebuild QIDO/WADO identity; the
+# export benchmark asserts batched-decode pixel identity +
+# coefficient-exact round-trip and a >1x decode speedup at EVERY batch-scaling point; the kernel
 # benchmark asserts flat batch scaling (no small-batch recompile cliff)
 # and pow2-bucket jit-cache reuse, and writes the roofline terms; the
 # fleet benchmark asserts the Figure-2 crossover (fleet loses at n=1,
@@ -56,17 +51,15 @@ trace:
 # kill + shard crash -> zero lost/double-converted, study tars
 # byte-identical to a serial conversion)
 smoke:
-	python -m benchmarks.convert_bench --fast
 	python -m benchmarks.store_bench --fast
 	python -m benchmarks.export_bench --fast
 	python -m benchmarks.kernels_bench --fast
 	python -m benchmarks.fleet_bench --fast
 
-# benchmark suite: paper figures + kernels + conversion + store + export
-# + fleet hot paths (writes BENCH_*.json into the working directory)
+# benchmark suite: paper figures + kernels + store + export + fleet hot
+# paths (writes BENCH_*.json into the working directory)
 bench:
 	python -m benchmarks.run
-	python -m benchmarks.convert_bench
 	python -m benchmarks.store_bench
 	python -m benchmarks.export_bench
 	python -m benchmarks.kernels_bench

@@ -1,76 +1,19 @@
-"""Render the README benchmark tables from ``BENCH_convert.json`` (and,
-when present, ``BENCH_store.json`` / ``BENCH_export.json`` /
-``BENCH_kernels.json``).
+"""Render the README benchmark tables from the ``BENCH_*.json`` files that
+are present (``BENCH_store.json`` / ``BENCH_export.json`` /
+``BENCH_kernels.json`` / ``BENCH_fleet.json``).
 
-    PYTHONPATH=src python -m benchmarks.bench_table [BENCH_convert.json]
+    PYTHONPATH=src python -m benchmarks.bench_table [DIR]
 
 Prints GitHub-flavored markdown. The tables embedded in README.md are the
 output of this script over the checked-in ``BENCH_*.json``; re-run
 ``make bench`` followed by this module to refresh them after a change to
-the conversion or store hot paths.
+the store, export or fleet hot paths.
 """
 from __future__ import annotations
 
 import json
 import os
 import sys
-
-
-def render(bench: dict) -> str:
-    s = bench["slide"]
-    te = bench["tile_encode_256"]
-    e2e = bench["end_to_end"]
-    ms = bench["multi_slide"]
-    lines = [
-        f"Single slide ({s['hw']}×{s['hw']}, {s['tiles']} tiles of "
-        f"{s['tile']}²):",
-        "",
-        "| path | tile encode (µs/tile) | end-to-end (MPix/s) | vs sync |",
-        "|---|---|---|---|",
-        f"| per-tile (seed) | {te['per_tile_us']:,.0f} | "
-        f"{e2e['per_tile_mpix_s']:.2f} | "
-        f"{e2e['per_tile_mpix_s'] / e2e['sync_mpix_s']:.2f}× |",
-        f"| batched sync | {te['batched_us']:,.0f} | "
-        f"{e2e['sync_mpix_s']:.2f} | 1.00× |",
-        f"| pipelined | {te['batched_us']:,.0f} | "
-        f"{e2e['pipelined_mpix_s']:.2f} | "
-        f"{e2e['pipelined_speedup_vs_sync']:.2f}× |",
-        "",
-        f"Multi-slide batch ({ms['n_slides']} × {ms['hw']}² slides, "
-        f"{ms['max_instances']} instance × concurrency "
-        f"{ms['concurrency']}):",
-        "",
-        "| path | batch wall (s) | MPix/s | vs sync |",
-        "|---|---|---|---|",
-        f"| sync serial | {ms['sync_s']:.3f} | {ms['sync_mpix_s']:.2f} | "
-        "1.00× |",
-        f"| pipelined serial | {ms['pipelined_s']:.3f} | "
-        f"{ms['pipelined_mpix_s']:.2f} | {ms['pipelined_speedup']:.2f}× |",
-        f"| pipelined + concurrent (event-driven) | {ms['concurrent_s']:.3f}"
-        f" | {ms['concurrent_mpix_s']:.2f} | "
-        f"{ms['concurrent_speedup']:.2f}× |",
-        "",
-        f"All paths emit byte-identical study tars "
-        f"(asserted in the run: {ms['bytes_identical']}).",
-    ]
-    mx = bench.get("mixed_format")
-    if mx:
-        per_fmt = ", ".join(f"{n} {f}" for f, n in
-                            sorted(mx["formats_converted"].items()))
-        lines += [
-            "",
-            f"Mixed-format landing bucket ({mx['n_slides']} × {mx['hw']}² "
-            f"slides: {per_fmt}; 1 instance × concurrency "
-            f"{mx['concurrency']}):",
-            "",
-            "| metric | value |",
-            "|---|---|",
-            f"| batch wall (s) | {mx['batch_s']:.3f} |",
-            f"| throughput (MPix/s) | {mx['mpix_s']:.2f} |",
-            f"| PSV vs TIFF study tars byte-identical | "
-            f"{mx['cross_format_bytes_identical']} |",
-        ]
-    return "\n".join(lines)
 
 
 def render_store(bench: dict) -> str:
@@ -219,20 +162,17 @@ def render_fleet(bench: dict) -> str:
 
 
 def main() -> None:
-    path = sys.argv[1] if len(sys.argv) > 1 else "BENCH_convert.json"
-    with open(path) as f:
-        bench = json.load(f)
-    print(render(bench))
-    base = os.path.dirname(path) or "."
+    base = sys.argv[1] if len(sys.argv) > 1 else "."
+    tables = []
     for name, renderer in (("BENCH_store.json", render_store),
                            ("BENCH_export.json", render_export),
                            ("BENCH_kernels.json", render_kernels),
                            ("BENCH_fleet.json", render_fleet)):
-        extra = os.path.join(base, name)
-        if os.path.exists(extra):
-            with open(extra) as f:
-                print()
-                print(renderer(json.load(f)))
+        path = os.path.join(base, name)
+        if os.path.exists(path):
+            with open(path) as f:
+                tables.append(renderer(json.load(f)))
+    print("\n\n".join(tables))
 
 
 if __name__ == "__main__":

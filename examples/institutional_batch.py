@@ -11,11 +11,11 @@ What it demonstrates, and what to expect:
    the event chain (object notification → pub/sub push → autoscaled
    wsi2dcm service) converts them with the pipelined JAX engine, up to
    ``--concurrency`` in parallel per instance (default: cores // 2).
-   Prints the batch wall time vs the serial-sync equivalent and verifies
-   every study landed in the DICOM store. ``auto_export=True`` closes the
-   retrieval loop: every stored instance triggers the dicom2tiff export
-   hop, and the final printout reports the ``pipeline.export.*`` counters
-   plus the derived-bucket tiled TIFFs.
+   Prints the batch wall time vs a serial loop of the same conversions and
+   verifies every study landed in the DICOM store. ``auto_export=True``
+   closes the retrieval loop: every stored instance triggers the dicom2tiff
+   export hop, and the final printout reports the ``pipeline.export.*``
+   counters plus the derived-bucket tiled TIFFs.
 2. **Paper scale** — the three workflows (serial, 16-way parallel VM pool,
    event-driven autoscaling) simulated at the paper's scale in the
    discrete-event simulator, calibrated by the measured real conversion,
@@ -37,8 +37,7 @@ from benchmarks.fig3_autoscaling import run as fig3_run
 
 from repro.core import ConversionPipeline, RealScheduler, tracing
 from repro.core.dashboard import build_report
-from repro.wsi import (ConvertOptions, SyntheticScanner, convert_wsi_to_dicom,
-                       study_levels)
+from repro.wsi import SyntheticScanner, convert_wsi_to_dicom, study_levels
 
 
 def run_real_batch(n: int, size: int, concurrency: int) -> None:
@@ -47,20 +46,13 @@ def run_real_batch(n: int, size: int, concurrency: int) -> None:
     slides = {f"slides/batch{i:03d}.psv": scanner.scan(size, size, 256)
               for i in range(n)}
 
-    def convert(data: bytes, meta: dict) -> bytes:
-        return convert_wsi_to_dicom(data, meta,
-                                    options=ConvertOptions(pipelined=True))
+    # warm the jit caches so neither run pays compile time
+    convert_wsi_to_dicom(next(iter(slides.values())))
 
-    # warm the jit caches so neither variant pays compile time
-    first = next(iter(slides.values()))
-    convert_wsi_to_dicom(first, options=ConvertOptions(pipelined=False))
-    convert_wsi_to_dicom(first, options=ConvertOptions(pipelined=True))
-
-    # serial-sync reference: the same slides, one at a time, no pipelining
+    # serial reference: the same slides, one at a time
     t0 = time.perf_counter()
     for key, psv in slides.items():
-        convert_wsi_to_dicom(psv, {"slide_id": key},
-                             options=ConvertOptions(pipelined=False))
+        convert_wsi_to_dicom(psv, {"slide_id": key})
     t_serial = time.perf_counter() - t0
 
     # one instance, `concurrency` parallel conversions: the per-instance
@@ -69,7 +61,7 @@ def run_real_batch(n: int, size: int, concurrency: int) -> None:
     sched = RealScheduler(workers=max(8, 4 * concurrency))
     with tracing.capture(now=sched.now) as tracer:
         pipe = ConversionPipeline(
-            sched, convert=convert, max_instances=1,
+            sched, convert=convert_wsi_to_dicom, max_instances=1,
             concurrency=concurrency, cold_start=0.0, scale_down_delay=5.0,
             auto_export=True,
         )
@@ -80,9 +72,9 @@ def run_real_batch(n: int, size: int, concurrency: int) -> None:
 
     print(f"real event-driven batch: {n} × {size}² slides, "
           f"concurrency={concurrency}")
-    print(f"  serial sync loop : {t_serial:6.2f}s")
+    print(f"  serial loop      : {t_serial:6.2f}s")
     print(f"  event-driven     : {t_batch:6.2f}s "
-          f"({t_serial / t_batch:.2f}× vs serial sync)")
+          f"({t_serial / t_batch:.2f}× vs serial)")
     for key in pipe.dicom.list():
         study = study_levels(pipe.dicom.get(key).data)
         n_dcm = sum(1 for k in study if k.endswith(".dcm"))

@@ -8,55 +8,46 @@ Huffman), wrap each level in a DICOM Part-10 instance (TILED_FULL),
 and bundle the study as a tar archive. The converter consumes only the
 reader protocol, so identical pixel content produces byte-identical study
 tars regardless of the source container (given the same manifest UIDs) —
-asserted across PSV vs tiled-TIFF in tests and the benchmark.
+asserted across PSV vs tiled-TIFF in the tests.
 
-A container of JPEG tiles (a scanner's SVS) takes the **transcoding**
-engine (``_convert_transcode``, DESIGN.md "Transcoding a scanner's
-JPEG"): its tiles become level 0's frames unchanged, level 0 is decoded
-on the device, and the pipelined engine's pyramid builds levels ≥ 1 from
-it. The container picks the engine; nothing else does.
+The container picks the engine; nothing else does. Two engines emit JPEG
+(see DESIGN.md, "Pipelined conversion" and "Transcoding a scanner's
+JPEG"):
 
-Otherwise three compute paths (see DESIGN.md, "Whole-level batched
-dispatch" and "Kernel roofline & sharding"), all emitting
-**byte-identical** study tars:
-
-- **pipelined/fused** (default): the device-resident engine. Level-0 tile
-  rows are uploaded to the device as the reader inflates them (no full
-  host ``(H, W, 3)`` array), then the **entire pyramid** — every level's
-  ``jpeg_transform`` and the ``downsample2x2`` chain between levels — is
-  one jitted dispatch (``donate_argnums`` retires the pixel buffer on
-  accelerators). The coefficients never come back to the host: each
-  level is Huffman-coded on the device in row-aligned chunks
-  (``jpeg.encode_coef_batch`` on a device array, ``wsi/entropy_encode_jax``)
-  and only the packed scans are copied back, to be 0xFF-stuffed and
-  wrapped; a level's device buffers are dropped once it is coded. A
-  chunk too small to be worth a compile (the last levels' 1-, 2- and
-  4-tile chunks), or a tile the device coder flags (over its slab, or a
-  category outside the baseline tables), is copied back and coded by the
-  numpy coder — a rule on the input, not an option. Exactly one
-  host→device upload and one pyramid dispatch per slide (the
-  ``convert.slide`` span counts its ``convert.upload``/
+- **pipelined** (``_convert_pipelined``), for a container of raw pixels
+  (PSV, tiled TIFF/SVS). Level-0 tile rows are uploaded to the device as
+  the reader inflates them (no full host ``(H, W, 3)`` array), then the
+  **entire pyramid** — every level's ``jpeg_transform`` and the
+  ``downsample2x2`` chain between levels — is one jitted dispatch
+  (``donate_argnums`` retires the pixel buffer on accelerators). The
+  coefficients never come back to the host: each level is Huffman-coded
+  on the device in row-aligned chunks (``jpeg.encode_coef_batch`` on a
+  device array, ``wsi/entropy_encode_jax``) and only the packed scans are
+  copied back, to be 0xFF-stuffed and wrapped; a level's device buffers
+  are dropped once it is coded. A chunk too small to be worth a compile
+  (the last levels' 1-, 2- and 4-tile chunks), or a tile the device coder
+  flags (over its slab, or a category outside the baseline tables), is
+  copied back and coded by the numpy coder — a rule on the input, not an
+  option. Exactly one host→device upload and one pyramid dispatch per
+  slide (the ``convert.slide`` span counts its ``convert.upload``/
   ``convert.dispatch``/``convert.fetch`` spans and sums the
-  ``device_tiles``/``host_tiles`` its ``convert.encode`` spans coded; the
-  conversion bench asserts the counts).
-- **batched sync** (``ConvertOptions(pipelined=False)``): level 0 is
-  uploaded once; every further level is produced by chaining
-  ``downsample2x2`` on device, and all tiles of a level are transform-coded
-  by a single fused ``jpeg_transform`` dispatch followed by the vectorized
-  numpy entropy coder on the host — but each level's host work completes
-  before the next level's device work is enqueued. Kept as the A/B
-  baseline for the pipelined path.
-- **per-tile** (``ConvertOptions(batched=False)``): the original path — host
-  pyramid, ``[encode_tile(f) for f in frames]`` with 4 dispatches per tile.
-  Kept for A/B benchmarking.
+  ``device_tiles``/``host_tiles`` its ``convert.encode`` spans coded).
+- **transcoding** (``_convert_transcode``), for a container of JPEG tiles
+  (a scanner's SVS): its tiles become level 0's frames unchanged, level 0
+  is decoded on the device, and the pipelined engine's pyramid builds
+  levels ≥ 1 from it.
+
+Native output (``ConvertOptions(jpeg=False)``, explicit-VR-LE pixels) is a
+transfer syntax, not an engine: ``_convert_native`` uploads level 0 the
+same way, walks the same ``downsample2x2`` chain and copies each level's
+raw frames back.
 
 **Determinism**: the study/series UIDs are minted once and stored in the
 manifest (key ``"uids"``), and every level's SOP instance UID is derived
 from the series UID + instance number. Two conversions of the same slide
 that share a manifest (or whose manifests were seeded with the same
 ``"uids"`` entry) therefore produce byte-identical study tars — this is
-what the pipelined-vs-sync A/B asserts on whole archives, and what makes
-manifest resume reproduce a fresh conversion exactly.
+what makes manifest resume reproduce a fresh conversion exactly.
 
 **Crash/resume**: ``ConvertOptions.manifest`` is the single store of
 finished-level DICOM bytes (level index → Part-10 bytes). A converter
@@ -94,7 +85,7 @@ from repro.wsi import entropy_jax, jpeg
 from repro.wsi.dicom import (TS_EXPLICIT_LE, TS_JPEG_BASELINE, new_uid,
                              write_part10)
 from repro.wsi.formats import SlideReader, open_slide
-from repro.wsi.jpeg import encode_coef_batch, encode_tile
+from repro.wsi.jpeg import encode_coef_batch
 
 __all__ = ["convert_wsi_to_dicom", "study_levels", "ConvertOptions"]
 
@@ -107,9 +98,8 @@ class ConvertOptions:
         this (pixels). Levels smaller than one tile emit zero full frames.
     jpeg
         ``True`` → encapsulated JPEG baseline transfer syntax; ``False`` →
-        native (uncompressed) explicit-VR-LE pixel data. The batched/
-        pipelined device paths only apply to JPEG output; ``jpeg=False``
-        always runs the host per-tile wrap.
+        native (uncompressed) explicit-VR-LE pixel data: the same device
+        pyramid, with each level's raw frames copied back and wrapped.
     manifest
         Resume checkpoint *and* the only copy of finished-level bytes held
         by the converter: maps level index (str) to that level's Part-10
@@ -117,18 +107,6 @@ class ConvertOptions:
         minted on first use so a resumed — or deliberately re-seeded —
         conversion reproduces the original bytes exactly. The output tar is
         written from the manifest directly.
-    batched
-        ``True`` (default): device-resident pyramid, one fused transform
-        dispatch per level, vectorized host entropy coder. ``False``: the
-        original per-tile path (4 dispatches + Python Huffman loop per
-        tile), kept for A/B benchmarking.
-    pipelined
-        ``True`` (default): the fused device-resident engine — streamed
-        level-0 upload, the whole pyramid (transforms + downsample chain)
-        in one jitted dispatch, async per-level coefficient fetches.
-        ``False``: strictly sequential per-level stages (the PR-1 batched
-        path), kept as the byte-identity A/B baseline. Only effective when
-        ``batched`` and ``jpeg`` are both ``True``.
     mesh
         Optional ``jax.sharding.Mesh`` with a ``"data"`` axis: scope the
         conversion's batched kernel dispatches to this mesh (level batches
@@ -138,12 +116,9 @@ class ConvertOptions:
     """
 
     def __init__(self, *, min_level_size: int = 256, jpeg: bool = True,
-                 manifest: dict | None = None, batched: bool = True,
-                 pipelined: bool = True, mesh=None):
+                 manifest: dict | None = None, mesh=None):
         self.min_level_size = min_level_size
         self.jpeg = jpeg
-        self.batched = batched
-        self.pipelined = pipelined
         self.mesh = mesh
         self.manifest = manifest if manifest is not None else {}
 
@@ -166,24 +141,12 @@ def _study_uids(opt: ConvertOptions) -> tuple[str, str]:
     return study_uid, series_uid
 
 
-def _level_frames(img: np.ndarray, tile: int) -> tuple[list[np.ndarray], int, int]:
-    """Tile a (H, W, 3) level into row-major frames."""
-    H, W, _ = img.shape
-    frames = []
-    for r in range(H // tile):
-        for c in range(W // tile):
-            frames.append(img[r * tile:(r + 1) * tile,
-                              c * tile:(c + 1) * tile])
-    return frames, H // tile, W // tile
-
-
 def _tile_batch(dev: jnp.ndarray, tile: int) -> jnp.ndarray:
     """(3, H, W) device level → (N, 3, tile, tile) row-major tile batch."""
     _, H, W = dev.shape
     bh, bw = H // tile, W // tile
     if bh == 0 or bw == 0:
-        # level smaller than one tile: no full frames (matches the per-tile
-        # path, whose _level_frames loop body never runs)
+        # level smaller than one tile: no full frames
         return jnp.zeros((0, 3, tile, tile), dev.dtype)
     return (dev[:, :bh * tile, :bw * tile].reshape(3, bh, tile, bw, tile)
             .transpose(1, 3, 0, 2, 4).reshape(bh * bw, 3, tile, tile))
@@ -194,8 +157,8 @@ def _upload_level0(rd: SlideReader, mesh) -> jnp.ndarray:
 
     Each row strip is handed to ``jax.device_put`` as soon as its tiles are
     inflated, so the host↔device copy of row r overlaps the zlib inflation
-    of row r+1; the full-resolution ``(H, W, 3)`` host array of the sync
-    path is never materialized. The strips hold exact uint8 values in
+    of row r+1; the full-resolution ``(H, W, 3)`` host array is never
+    materialized. The strips hold exact uint8 values in
     float32, so the device concatenation is bit-identical to a whole-level
     upload. When the tile rows divide evenly over the mesh's devices, each
     device receives its own band of rows (``P(None, "data")``, the layout
@@ -269,8 +232,8 @@ def _summed(sp, name: str, attrs: tuple[str, ...]) -> dict[str, int]:
 
 def _pyramid_dims(H: int, W: int,
                   min_level_size: int) -> list[tuple[int, int]]:
-    """Host-side geometry walk: (H, W) per pyramid level, same stopping
-    rule as the sync engine's device walk."""
+    """Host-side geometry walk: (H, W) per pyramid level. The pyramid stops
+    once the next level's short edge would fall below ``min_level_size``."""
     dims = []
     while True:
         dims.append((H, W))
@@ -330,9 +293,9 @@ def _convert_pipelined(rd: SlideReader, metadata: dict | None,
        crash mid-pyramid resumes from every completed level, and its
        device coefficients are released.
 
-    The per-tile math and emitted frame order are identical to the sync
-    engine's per-level dispatch — fusion changes only where buffers live —
-    so the output bytes are identical (asserted in tests and the bench).
+    Each frame is the baseline JPEG that ``jpeg.encode_tile`` makes of the
+    same tile of a pyramid built level by level — fusion changes only where
+    buffers live (asserted against a plain numpy reference in the tests).
     """
     tile = rd.tile
     dims = _pyramid_dims(rd.H, rd.W, opt.min_level_size)
@@ -486,58 +449,29 @@ def _convert_transcode(rd: SlideReader, frames: list[bytes],
     return len(dims)
 
 
-def _convert_sync(rd: SlideReader, metadata: dict | None, opt: ConvertOptions,
-                  study_uid: str, series_uid: str) -> int:
-    """The strictly sequential engine (batched or per-tile). Returns the
-    number of levels."""
+def _convert_native(rd: SlideReader, metadata: dict | None,
+                    opt: ConvertOptions, study_uid: str,
+                    series_uid: str) -> int:
+    """Native (explicit-VR-LE) output. Returns the number of levels.
+
+    Level 0 is uploaded as the pipelined engine uploads it; each level
+    still missing from the manifest comes back as uint8 row-major raw
+    frames, and the next level is the same re-quantized ``downsample2x2``
+    as ``_pyramid_chain``'s.
+    """
     tile = rd.tile
-
-    # level 0 assembled tile-by-tile (streaming); higher levels by 2× pooling
-    H, W = rd.H, rd.W
-    level = np.empty((H, W, 3), np.uint8)
-    for (r, c), t in rd.tiles():
-        level[r * tile:(r + 1) * tile, c * tile:(c + 1) * tile] = t
-
-    # batched path: the pyramid lives on device as float32 planes holding
-    # exact uint8 values (downsample output is re-quantized on device), so
-    # the transform input matches the per-tile uint8 path bit-for-bit
-    dev = jnp.asarray(np.transpose(level, (2, 0, 1)).astype(np.float32)) \
-        if opt.batched else None
-
-    li = 0
-    while True:
-        if opt.batched:
-            H, W = int(dev.shape[1]), int(dev.shape[2])
-        else:
-            H, W = level.shape[:2]
+    dims = _pyramid_dims(rd.H, rd.W, opt.min_level_size)
+    with tracing.span("convert.upload"):
+        dev = _upload_level0(rd, kernel_ops.default_mesh())
+    for li, (H, W) in enumerate(dims):
         if str(li) not in opt.manifest:
-            if opt.jpeg and opt.batched:
-                coef = np.asarray(jpeg_transform(_tile_batch(dev, tile)))
-                frames = encode_coef_batch(coef)
-                ts = TS_JPEG_BASELINE
-            else:
-                if opt.batched:
-                    level = np.asarray(dev).transpose(1, 2, 0).astype(np.uint8)
-                frames_rgb, _, _ = _level_frames(level, tile)
-                if opt.jpeg:
-                    frames = [encode_tile(f) for f in frames_rgb]
-                    ts = TS_JPEG_BASELINE
-                else:
-                    frames = [np.ascontiguousarray(f).tobytes()
-                              for f in frames_rgb]
-                    ts = TS_EXPLICIT_LE
-            _wrap_level(opt, li, frames, ts, tile, H, W, metadata,
-                        study_uid, series_uid)
-        if min(H, W) // 2 < opt.min_level_size:
-            return li + 1
-        if opt.batched:
+            tiles = np.asarray(_tile_batch(dev, tile)).astype(np.uint8)
+            frames = [t.transpose(1, 2, 0).tobytes() for t in tiles]
+            _wrap_level(opt, li, frames, TS_EXPLICIT_LE, tile, H, W,
+                        metadata, study_uid, series_uid)
+        if li + 1 < len(dims):
             dev = jnp.clip(jnp.round(downsample2x2(dev)), 0, 255)
-        else:
-            chw = np.transpose(level, (2, 0, 1)).astype(np.float32)
-            down = np.asarray(downsample2x2(chw))
-            level = np.clip(np.round(np.transpose(down, (1, 2, 0))),
-                            0, 255).astype(np.uint8)
-        li += 1
+    return len(dims)
 
 
 def _pack_study(opt: ConvertOptions, n_levels: int, study_uid: str,
@@ -586,12 +520,12 @@ def convert_wsi_to_dicom(slide_bytes: bytes, metadata: dict | None = None,
             if frames is not None:
                 n_levels = _convert_transcode(rd, frames, metadata, opt,
                                               study_uid, series_uid)
-            elif opt.pipelined and opt.batched and opt.jpeg:
+            elif opt.jpeg:
                 n_levels = _convert_pipelined(rd, metadata, opt, study_uid,
                                               series_uid)
             else:
-                n_levels = _convert_sync(rd, metadata, opt, study_uid,
-                                         series_uid)
+                n_levels = _convert_native(rd, metadata, opt, study_uid,
+                                           series_uid)
         with tracing.span("convert.pack", levels=n_levels):
             out = _pack_study(opt, n_levels, study_uid, rd.tile)
         if sp is not None:

@@ -11,9 +11,9 @@ the host keeps the 0xFF byte stuffing and the JFIF wrap.
 
 Two encoder paths, byte-identical by construction (tested):
 
-- ``encode_tile``: the original per-tile path — 4 jitted dispatches per tile
+- ``encode_tile``: the per-tile path — 4 jitted dispatches per tile
   (rgb2ycbcr + 3× dct8x8_quant) and a per-coefficient Python Huffman loop.
-  Kept as the A/B baseline for benchmarks.
+  The per-tile reference the tests compare against.
 - ``encode_tiles_batch``: the whole-level batched path — one fused
   ``jpeg_transform`` dispatch for every tile of a level, then the
   entropy stage (``encode_coef_batch``). Where the coefficients live
@@ -29,7 +29,8 @@ And two decoder paths, pixel-identical by construction (tested) — the
 export subsystem's compute spine run in reverse:
 
 - ``decode_tile``: the per-tile path — a per-symbol Python Huffman loop,
-  then the fused ``jpeg_inverse`` dispatch. Kept as the A/B baseline.
+  then the fused ``jpeg_inverse`` dispatch. The per-tile reference the
+  tests compare against.
 - ``decode_tiles_batch``: the whole-level batched path — the lockstep
   entropy **decoder** (``decode_coef_batch``: every tile of a level is an
   independent bitstream, so N tiles advance one symbol position per step;
@@ -1213,8 +1214,9 @@ def _jfif_header(H: int, W: int) -> bytearray:
 def encode_tile(tile_rgb: np.ndarray) -> bytes:
     """RGB (H, W, 3) uint8 → baseline JFIF bytes (4:4:4).
 
-    The per-tile path: 4 jitted dispatches + the Python Huffman loop. Kept
-    as the A/B baseline for ``encode_tiles_batch`` (byte-identical output).
+    The per-tile path: 4 jitted dispatches + the Python Huffman loop — the
+    per-tile reference the tests compare ``encode_tiles_batch`` and the
+    converter against (byte-identical output).
     """
     H, W, _ = tile_rgb.shape
     assert H % 8 == 0 and W % 8 == 0
@@ -1327,8 +1329,9 @@ def decode_tile(jpg: bytes) -> np.ndarray:
     are merged in) → RGB (H, W, 3) uint8.
 
     The per-tile decode path: a per-symbol Python Huffman loop, then the
-    inverse transform the batched path uses on a batch of one — kept as
-    the A/B baseline for ``decode_tiles_batch`` (pixel-identical output).
+    inverse transform the batched path uses on a batch of one — the
+    per-tile reference the tests compare ``decode_tiles_batch`` against
+    (pixel-identical output).
     Truncated/garbage input raises ``ValueError("corrupt JPEG …")``.
     """
     with tracing.span("decode.parse", frames=1):

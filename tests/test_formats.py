@@ -7,6 +7,8 @@ import struct
 import numpy as np
 import pytest
 
+from _convert_ref import reference_level_frames, study_frames
+
 from repro.core import ConversionPipeline, RealScheduler
 from repro.core import clock
 from repro.wsi import (ConvertOptions, PSVReader, SyntheticScanner,
@@ -181,21 +183,17 @@ def test_misaligned_slide_dims_raise():
 # ---------------------------------------------------------------------------
 def test_cross_format_study_tars_are_byte_identical():
     """Same pixels as PSV and as tiled TIFF, same manifest UIDs → identical
-    study tar, on every compute path."""
+    study tar, whose levels equal the plain reference."""
     sc = SyntheticScanner(seed=21)
     psv = sc.scan(512, 512, 256)
     tif = sc.scan_tiff(512, 512, 256)
     uids = json.dumps([new_uid(), new_uid()])
-    outs = {}
-    for name, blob in (("psv", psv), ("tiff", tif)):
-        for path, kw in (("pipe", {}), ("sync", {"pipelined": False}),
-                         ("tile", {"batched": False})):
-            opt = ConvertOptions(manifest={"uids": uids}, **kw)
-            outs[(name, path)] = convert_wsi_to_dicom(
-                blob, {"slide_id": "X"}, opt)
-    ref = outs[("psv", "pipe")]
-    assert all(v == ref for v in outs.values())
-    assert len(study_levels(ref)) == 3  # study.json + 2 levels
+    outs = {name: convert_wsi_to_dicom(
+        blob, {"slide_id": "X"}, ConvertOptions(manifest={"uids": uids}))
+        for name, blob in (("psv", psv), ("tiff", tif))}
+    assert outs["tiff"] == outs["psv"]
+    assert len(study_levels(outs["psv"])) == 3  # study.json + 2 levels
+    assert study_frames(outs["psv"]) == reference_level_frames(psv, 256)
 
 
 def test_mixed_format_batch_through_event_driven_pipeline():

@@ -7,6 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _convert_ref import reference_level_frames, study_frames
+
 from repro.kernels import jpeg_transform
 from repro.kernels import ref
 from repro.wsi import (ConvertOptions, SyntheticScanner, convert_wsi_to_dicom,
@@ -120,48 +122,33 @@ def test_batched_roundtrip_decodes():
 
 
 # --------------------------------------------------------------------------
-# device-resident pyramid vs host pyramid
+# device-resident pyramid vs the plain host reference
 # --------------------------------------------------------------------------
 def test_device_pyramid_matches_host_pyramid():
     psv = SyntheticScanner(seed=5).scan(1024, 1024, 256)
-    tar_b = convert_wsi_to_dicom(psv, options=ConvertOptions(batched=True))
-    tar_p = convert_wsi_to_dicom(psv, options=ConvertOptions(batched=False))
-    lb, lp = study_levels(tar_b), study_levels(tar_p)
-    names = sorted(k for k in lb if k.endswith(".dcm"))
-    assert names == sorted(k for k in lp if k.endswith(".dcm"))
-    assert len(names) == 3  # 1024 → 512 → 256
-    for k in names:
-        _, fb = read_part10(lb[k])
-        _, fp = read_part10(lp[k])
-        assert fb == fp  # per-level frames byte-identical
+    got = study_frames(convert_wsi_to_dicom(psv))
+    assert len(got) == 3  # 1024 → 512 → 256
+    assert got == reference_level_frames(psv, 256)
 
 
 def test_batched_handles_levels_smaller_than_tile():
     """min_level_size below the tile size: the deepest levels hold no full
-    frame; both paths must agree (and not crash) all the way down."""
+    frame; the converter must agree with the reference (and not crash) all
+    the way down."""
     psv = SyntheticScanner(seed=9).scan(512, 512, 256)
-    opts = dict(min_level_size=128)
-    lb = study_levels(convert_wsi_to_dicom(
-        psv, options=ConvertOptions(batched=True, **opts)))
-    lp = study_levels(convert_wsi_to_dicom(
-        psv, options=ConvertOptions(batched=False, **opts)))
-    assert sorted(lb) == sorted(lp)
-    assert "level_2.dcm" in lb  # the 128x128 sub-tile level exists
-    for k in lb:
-        if k.endswith(".dcm"):
-            assert read_part10(lb[k])[1] == read_part10(lp[k])[1]
+    lv = study_levels(convert_wsi_to_dicom(
+        psv, options=ConvertOptions(min_level_size=128)))
+    assert "level_2.dcm" in lv  # the 128x128 sub-tile level exists
+    got = [read_part10(lv[f"level_{i}.dcm"])[1] for i in range(3)]
+    assert got[2] == []
+    assert got == reference_level_frames(psv, 128)
 
 
 def test_raw_path_device_pyramid_matches_host():
     psv = SyntheticScanner(seed=6).scan(512, 512, 256)
-    opts = dict(jpeg=False, min_level_size=256)
-    lb = study_levels(convert_wsi_to_dicom(
-        psv, options=ConvertOptions(batched=True, **opts)))
-    lp = study_levels(convert_wsi_to_dicom(
-        psv, options=ConvertOptions(batched=False, **opts)))
-    for k in lb:
-        if k.endswith(".dcm"):
-            assert read_part10(lb[k])[1] == read_part10(lp[k])[1]
+    got = study_frames(convert_wsi_to_dicom(psv, options=ConvertOptions(
+        jpeg=False, min_level_size=256)))
+    assert got == reference_level_frames(psv, 256, jpeg=False)
 
 
 # --------------------------------------------------------------------------

@@ -2,17 +2,16 @@
 coder it stands in for: byte-identical scans on hand-built edge cases and
 scanner pixels, the per-tile fallback, and a pipelined conversion that
 codes on the device."""
-import json
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _convert_ref import reference_level_frames, study_frames
+
 from repro.core import tracing
 from repro.kernels import jpeg_transform
-from repro.wsi import ConvertOptions, SyntheticScanner, convert_wsi_to_dicom
+from repro.wsi import SyntheticScanner, convert_wsi_to_dicom
 from repro.wsi import jpeg
-from repro.wsi.dicom import new_uid
 from repro.wsi.slide import PSVReader
 
 #: small tiles for the hand-built cases: 64 units a component, a 2048-byte
@@ -170,16 +169,12 @@ def test_pipelined_conversion_codes_on_the_device():
     """A 2048² slide: level 0 (four 16-tile chunks) is coded on the
     device, the 4-, 2- and 1-tile chunks of the levels below on the host;
     every frame is counted once, the device level copies back less than
-    1 B/px, and the study tar equals the sync engine's, which codes
-    everything on the host."""
+    1 B/px, and every level's frames equal the plain reference, whose
+    per-tile encoder codes everything on the host."""
     psv = SyntheticScanner(seed=17).scan(2048, 2048, 256)
-    uids = json.dumps([new_uid(), new_uid()])
-    sync = convert_wsi_to_dicom(psv, {"slide_id": "dev"}, options=(
-        ConvertOptions(manifest={"uids": uids}, pipelined=False)))
     with tracing.capture() as tracer:
-        tar = convert_wsi_to_dicom(psv, {"slide_id": "dev"}, options=(
-            ConvertOptions(manifest={"uids": uids})))
-    assert tar == sync
+        tar = convert_wsi_to_dicom(psv, {"slide_id": "dev"})
+    assert study_frames(tar) == reference_level_frames(psv, 256)
     enc = tracer.spans_named("convert.encode")
     assert [sp.attrs["frames"] for sp in enc] == [64, 16, 4, 1]
     for sp in enc:

@@ -1,8 +1,11 @@
-"""Pipelined conversion engine: byte-identity A/B vs the sync batched path,
+"""Pipelined conversion engine: every level's frames against the plain
+reference (``_convert_ref``), whole-tar determinism under shared UIDs,
 manifest-resume determinism, and real-mode multi-slide concurrency."""
 import json
 
 import pytest
+
+from _convert_ref import reference_level_frames, study_frames
 
 from repro.core import ConversionPipeline, RealScheduler
 from repro.core import clock
@@ -22,7 +25,7 @@ def _convert(psv, *, uids, **kw):
 
 
 # --------------------------------------------------------------------------
-# byte identity: pipelined vs sync batched, whole study tars
+# byte identity: levels vs the plain reference, whole tars vs each other
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("hw,min_level", [
     ((512, 512), 256),
@@ -30,13 +33,15 @@ def _convert(psv, *, uids, **kw):
     ((512, 512), 64),     # runs into sub-tile levels (0 full frames)
 ])
 def test_pipelined_tar_identical_to_sync(hw, min_level):
+    """Every level's frames equal the plain, strictly sequential reference
+    (host pyramid, per-tile coder); two conversions that share UIDs give
+    the same tar, so the wrap is deterministic too."""
     psv = SyntheticScanner(seed=11).scan(*hw, 256)
     uids = _uids()
-    sync_tar, _ = _convert(psv, uids=uids, pipelined=False,
-                           min_level_size=min_level)
-    pipe_tar, _ = _convert(psv, uids=uids, pipelined=True,
-                           min_level_size=min_level)
-    assert pipe_tar == sync_tar
+    tar, _ = _convert(psv, uids=uids, min_level_size=min_level)
+    again, _ = _convert(psv, uids=uids, min_level_size=min_level)
+    assert again == tar  # shared UIDs → the same wrapped archive
+    assert study_frames(tar) == reference_level_frames(psv, min_level)
 
 
 def test_pipelined_levels_decode_and_cover_pyramid():
@@ -71,11 +76,6 @@ def test_partial_manifest_resume_tar_identical():
     opt2 = ConvertOptions(manifest=partial)
     tar2 = convert_wsi_to_dicom(psv, {"slide_id": "AB"}, options=opt2)
     assert tar2 == tar1
-    # the sync engine resumes to the same bytes as the pipelined one
-    opt3 = ConvertOptions(pipelined=False, manifest={
-        "uids": opt1.manifest["uids"], "0": opt1.manifest["0"]})
-    tar3 = convert_wsi_to_dicom(psv, {"slide_id": "AB"}, options=opt3)
-    assert tar3 == tar1
 
 
 def test_pipelined_crash_mid_pyramid_checkpoints_finished_levels(monkeypatch):
